@@ -32,7 +32,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 
 class DeviceFaultError(RuntimeError):
-    """The injected stand-in for a device/tunnel failure."""
+    """The injected stand-in for a device failure."""
 
 
 class DeviceFaultInjector:
@@ -44,7 +44,7 @@ class DeviceFaultInjector:
     arm_hang(s):   the next device call sleeps s seconds first (a stall the
                    caller experiences as a slow flush — the breaker's
                    flush-deadline overrun path).
-    persistent:    raise on EVERY call until heal() (a dead tunnel).
+    persistent:    raise on EVERY call until heal() (a dead device).
     """
 
     def __init__(self, clock: Callable[[], float] = time.monotonic):

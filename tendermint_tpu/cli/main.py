@@ -111,6 +111,11 @@ def run_node(home: str) -> None:
     from tendermint_tpu.libs import log as tmlog
 
     tmlog.setup(cfg.base.log_level)
+    # before the node's first compile (Node.start -> crypto prewarm): without
+    # a cache every start retraces and recompiles every kernel
+    from tendermint_tpu.ops.aot_cache import configure_compile_cache
+
+    configure_compile_cache()
     with open(cfg.genesis_path()) as f:
         gen = GenesisDoc.from_json(f.read())
     pv = None

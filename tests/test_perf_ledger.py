@@ -1,11 +1,12 @@
 """Tier-1 guard for the perf-trajectory ledger (tools/perf_ledger.py).
 
-Two jobs: (1) the ledger must parse EVERY round artifact the repo has ever
-accumulated — including r01's parseless wrapper, r05's `value: -1`
-device-init stall, and the rc-124 multichip rounds — without error, and
+Two jobs: (1) the ledger must parse every SHAPE of round artifact the driver
+has ever written — a parseless wrapper, a `value: -1` device-init stall, a
+healthy round, a skipped and an rc-124 multichip round — without error, and
 flag the lost datapoints instead of silently skipping them; (2) `--check`
 must exit nonzero on a simulated headline regression, in the spirit of
-tests/test_hotpath_guard.py."""
+tests/test_hotpath_guard.py. The rounds are synthetic, written into
+tmp_path: the repo root keeps no old round records."""
 
 import glob
 import json
@@ -15,28 +16,64 @@ import pytest
 
 from tendermint_tpu.tools import perf_ledger as PL
 
-ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+_CMD = "if [ -f bench.py ]; then python bench.py; else exit 0; fi"
 
 
-def test_parses_every_repo_round_artifact():
-    """Every BENCH_r*/MULTICHIP_r* file in the repo root yields a ledger row
+def _wrapper(d, n, parsed, tail=""):
+    (d / f"BENCH_r{n:02d}.json").write_text(
+        json.dumps({"n": n, "cmd": _CMD, "rc": 0, "tail": tail, "parsed": parsed})
+    )
+
+
+def _multichip(d, n, rc=0, ok=False, skipped=False, tail=""):
+    (d / f"MULTICHIP_r{n:02d}.json").write_text(
+        json.dumps(
+            {"n_devices": 8, "rc": rc, "ok": ok, "skipped": skipped, "tail": tail}
+        )
+    )
+
+
+@pytest.fixture
+def rounds(tmp_path):
+    """One synthetic round per case the ledger must survive."""
+    _wrapper(tmp_path, 1, None)  # wrapper with parsed: null
+    _wrapper(
+        tmp_path, 4,
+        {"metric": "verify_commit_10k_latency", "value": 273.205, "unit": "ms",
+         "vs_baseline": 4.23, "extra": {}},
+        tail="[vote_storm] serial ...",
+    )  # a healthy round
+    _wrapper(
+        tmp_path, 5,
+        {"metric": "verify_commit_latency", "value": -1, "unit": "ms",
+         "vs_baseline": 0, "extra": {}},
+        tail="[init] device initialization stalled",
+    )  # value -1: the device-init stall that cost a whole round
+    _multichip(tmp_path, 1, skipped=True, tail="__GRAFT_DRYRUN_SKIP__\n")
+    _multichip(tmp_path, 3, ok=True, tail="dryrun_multichip OK: all 32 sigs verified")
+    _multichip(tmp_path, 4, rc=124)  # timeout
+    return str(tmp_path)
+
+
+def test_parses_every_round_artifact_shape(rounds):
+    """Every BENCH_r*/MULTICHIP_r* file yields a ledger row
     (parse_bench/parse_multichip never raise by design — a malformed file
     becomes a flagged lost row)."""
-    ledger = PL.load_ledger(ROOT)
+    ledger = PL.load_ledger(rounds)
     on_disk = {
         os.path.basename(p)
         for pat in ("BENCH_r*.json", "MULTICHIP_r*.json")
-        for p in glob.glob(os.path.join(ROOT, pat))
+        for p in glob.glob(os.path.join(rounds, pat))
     }
-    assert on_disk, "repo root must hold the round artifacts this test guards"
+    assert len(on_disk) == 6
     rows = {r["file"] for r in ledger["bench"] + ledger["multichip"]}
     assert rows == on_disk
     for r in ledger["bench"] + ledger["multichip"]:
         assert isinstance(r["round"], int), r["file"]
 
 
-def test_known_lost_datapoints_are_flagged():
-    ledger = PL.load_ledger(ROOT)
+def test_known_lost_datapoints_are_flagged(rounds):
+    ledger = PL.load_ledger(rounds)
     lost = set(ledger["lost_datapoints"])
     # r01: wrapper with parsed: null (no parseable bench JSON)
     assert "BENCH_r01.json" in lost
@@ -49,17 +86,17 @@ def test_known_lost_datapoints_are_flagged():
     assert "BENCH_r04.json" not in lost
 
 
-def test_multichip_diagnoses():
-    ledger = PL.load_ledger(ROOT)
+def test_multichip_diagnoses(rounds):
+    ledger = PL.load_ledger(rounds)
     by_file = {r["file"]: r for r in ledger["multichip"]}
     assert by_file["MULTICHIP_r01.json"]["diagnosis"] == "skipped"
     assert "timeout" in by_file["MULTICHIP_r04.json"]["diagnosis"]  # rc-124
     assert by_file["MULTICHIP_r04.json"]["lost"]
 
 
-def test_renders_full_repo_trajectory(tmp_path, capsys):
+def test_renders_full_trajectory(rounds, tmp_path, capsys):
     rc = PL.main([
-        "--root", ROOT,
+        "--root", rounds,
         "--json", str(tmp_path / "ledger.json"),
         "--markdown", str(tmp_path / "ledger.md"),
     ])

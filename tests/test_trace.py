@@ -193,10 +193,10 @@ def test_device_health_gauges():
     assert h["device_up"] == 1
     assert h["init_seconds"] == 1.5
     assert h["last_call_age_s"] is not None and h["last_call_age_s"] >= 0
-    trace.mark_device_call(ok=False, error="tunnel down")
+    trace.mark_device_call(ok=False, error="device down")
     h = trace.device_health()
     assert h["device_up"] == 0
-    assert h["last_error"] == "tunnel down"
+    assert h["last_error"] == "device down"
     trace.mark_device_call(ok=True)
     assert trace.device_health()["device_up"] == 1
     # the Prometheus exposition carries the same gauges
