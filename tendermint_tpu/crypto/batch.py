@@ -1318,12 +1318,23 @@ def _rlc_submit(
     set (A decoded in-kernel, cache filled at finish) and the cached-A kernel
     in steady state. Mixed ed25519+sr25519 batches always prefill the typed
     pubkey cache (both decoders) and run the mixed cached kernel with
-    separate ed/sr R-lane blocks."""
+    separate ed/sr R-lane blocks.
+
+    The whole submit is the `rlc.submit` span; its duration is the call's
+    `prep_seconds` (the flush record's `prep_ms` on this path)."""
+    with _trace.timed("rlc.submit", n=len(pubkeys)) as sub:
+        call = _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub)
+    call.prep_seconds = sub.seconds
+    return call
+
+
+def _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub) -> _RlcCall:
+    """_rlc_submit's body; `sub` is its open span, the explicit parent of
+    the hashing task handed to the prep pool."""
     from tendermint_tpu.crypto.ed25519_ref import BASE, point_compress
     from tendermint_tpu.ops import msm_jax
 
     _device_fault("rlc_submit")
-    t0 = time.perf_counter()
     # Per-flush device-traffic accounting (tests/test_flush_budget.py pins
     # budgets on the deltas): dispatches + H2D bytes this submit produces.
     msm_jax._set_submit_fused(False)
@@ -1338,11 +1349,11 @@ def _rlc_submit(
     prep_stages: dict = {}
     if staged:
         # Stage 1 (dispatch thread): cheap precheck + blob assembly only.
-        t_p = time.perf_counter()
-        precheck, a_rows, r_rows, s_rows, blobs = _precheck_rows_fast(
-            pubkeys, msgs, sigs
-        )
-        prep_stages["precheck_s"] = time.perf_counter() - t_p
+        with _trace.timed("prep.precheck", chunk=0) as st:
+            precheck, a_rows, r_rows, s_rows, blobs = _precheck_rows_fast(
+                pubkeys, msgs, sigs
+            )
+        prep_stages["precheck_s"] = st.seconds
         s_ints = hk_ints = h_rows = None
 
         # Stage 2 (prep pool): challenge hashing runs OFF the dispatch
@@ -1351,10 +1362,10 @@ def _rlc_submit(
         # .result() — the flush fails loudly and the dispatch thread never
         # wedges (tests/test_prep_pipeline.py).
         def _hash_task(blobs=blobs, rows=n):
-            ts = time.perf_counter()
-            h = native.ed25519_h_batch(*blobs)
+            with _trace.timed("prep.hash", parent=sub, chunk=0) as st:
+                h = native.ed25519_h_batch(*blobs)
             HASH_ROWS_HASHED[0] += rows
-            return h, ts, time.perf_counter()
+            return h, st.interval()
 
         hash_fut = _prep_pool().submit(_hash_task)
     elif use_native:
@@ -1422,7 +1433,17 @@ def _rlc_submit(
     included = [ckeys[i] for i in range(n) if precheck[i]]
     cached = bool(included) and all(k in _A_CACHE for k in included)
 
+    a_spans: list = []  # the A-block stage's intervals (overlap accounting)
+
     def _a_block():
+        with _trace.timed("flush.a_block") as st:
+            dev, hit = _a_block_of()
+            st.set(hit=hit)
+        a_spans.append(st.interval())
+        return dev
+
+    def _a_block_of():
+        """(device A block, whether the device A-block cache held it)."""
         import jax as _jax
 
         rows = np.flatnonzero(precheck)
@@ -1443,7 +1464,7 @@ def _rlc_submit(
             hit = _DEV_A_CACHE.pop(key, None)
             if hit is not None:
                 _DEV_A_CACHE[key] = hit  # LRU refresh
-                return hit
+                return hit, True
             store_slice = _A_STORE[:, :, cols].copy() if len(rows) else None
         bx, by, bz, bt = msm_jax.basepoint_coords()
         block = np.empty((4, 20, na), dtype=np.int32)
@@ -1461,7 +1482,7 @@ def _rlc_submit(
             while len(_DEV_A_CACHE) >= _DEV_A_MAX:
                 _DEV_A_CACHE.pop(next(iter(_DEV_A_CACHE)))
             _DEV_A_CACHE[key] = dev
-        return dev
+        return dev, False
 
     if mixed:
         ed_pos = [i for i in range(n) if types[i] != "sr25519"]
@@ -1488,7 +1509,7 @@ def _rlc_submit(
         dev = msm_jax.rlc_check_cached_mixed_submit(_a_block(), ed_r, sr_r, scalars)
         _record_submit_counters(msm_jax, counters0)
         return _RlcCall(
-            precheck, n, na, "mixed", dev, None, time.perf_counter() - t0,
+            precheck, n, na, "mixed", dev, None, 0.0,
             ed_pos=np.asarray(ed_pos, dtype=np.int64),
             sr_pos=np.asarray(sr_pos, dtype=np.int64),
             ne=ne, ns=ns, fused=msm_jax.last_submit_fused(),
@@ -1503,27 +1524,23 @@ def _rlc_submit(
         pts_r[:n][precheck] = r_rows[precheck]
 
     a_dev = None
-    a_span = None
     if staged and cached:
         # Early A-block upload: a cache-miss H2D transfer runs while the
         # prep pool is still hashing — the overlap this stage exists to
         # create (a _DEV_A_CACHE hit returns instantly and hides nothing;
         # that steady state is what the 2-chunk stream above the floor is
         # for).
-        t_a = time.perf_counter()
         a_dev = _a_block()
-        a_span = (t_a, time.perf_counter())
 
     if staged:
-        h_rows, h_t0, h_t1 = hash_fut.result()  # re-raises a prep failure
-        prep_stages["hash_s"] = h_t1 - h_t0
+        with _trace.span("flush.prep_wait", chunk=0):
+            h_rows, h_span = hash_fut.result()  # re-raises a prep failure
+        prep_stages["hash_s"] = h_span[1] - h_span[0]
         h_rows[~precheck] = 0
-        t_sc = time.perf_counter()
-        z16, w_rows, u = _rlc_scalars_fast(precheck, s_rows, h_rows)
-        prep_stages["scalars_s"] = time.perf_counter() - t_sc
-        LAST_FLUSH_DETAIL["prep_overlap_s"] = _overlap_seconds(
-            [(h_t0, h_t1)], [a_span] if a_span else []
-        )
+        with _trace.timed("prep.scalars", chunk=0) as st:
+            z16, w_rows, u = _rlc_scalars_fast(precheck, s_rows, h_rows)
+        prep_stages["scalars_s"] = st.seconds
+        LAST_FLUSH_DETAIL["prep_overlap_s"] = _overlap_seconds([h_span], a_spans)
         LAST_FLUSH_DETAIL["chunks"] = 1
         LAST_FLUSH_DETAIL["chunk_lanes"] = 2 * na
 
@@ -1547,10 +1564,10 @@ def _rlc_submit(
         # Window sort hoisted out of the submit helper: only the MSM gather
         # waits on it (same sort_windows the helper would run — identical
         # perm/ends), and the stage table gets an honest sort_s.
-        t_srt = time.perf_counter()
-        digits = msm_jax.scalars_to_bytes(scalars, 2 * na)
-        presorted = msm_jax.sort_windows(digits, zero16_from=na)
-        prep_stages["sort_s"] = time.perf_counter() - t_srt
+        with _trace.timed("prep.sort", chunk=0) as st:
+            digits = msm_jax.scalars_to_bytes(scalars, 2 * na)
+            presorted = msm_jax.sort_windows(digits, zero16_from=na)
+        prep_stages["sort_s"] = st.seconds
     if prep_stages:
         LAST_FLUSH_DETAIL["prep_stages"] = {
             k: round(v, 6) for k, v in prep_stages.items()
@@ -1579,7 +1596,7 @@ def _rlc_submit(
     _record_submit_counters(msm_jax, counters0)
     return _RlcCall(
         precheck, n, na, "cached" if cached else "plain", dev,
-        a_rows if not cached else None, time.perf_counter() - t0,
+        a_rows if not cached else None, 0.0,
         fused=msm_jax.last_submit_fused(),
     )
 
@@ -1588,15 +1605,15 @@ def _rlc_finish(call: _RlcCall) -> Optional[np.ndarray]:
     """Sync the device result (ONE packed D2H fetch); mask on success,
     None -> per-sig fallback."""
     precheck, n, na = call.precheck, call.n, call.na
-    t_sync = time.perf_counter()
     try:
         _device_fault("rlc_finish")
-        out = np.asarray(call.dev)  # [batch_ok, lane_ok...]
+        with _trace.timed("flush.sync", chunk=0) as sy:
+            out = np.asarray(call.dev)  # [batch_ok, lane_ok...]
     except Exception as e:
         _trace.mark_device_call(ok=False, error=repr(e))
         raise
     _trace.mark_device_call(ok=True)
-    LAST_FLUSH_DETAIL["transfer_s"] = time.perf_counter() - t_sync
+    LAST_FLUSH_DETAIL["transfer_s"] = sy.seconds
     LAST_FLUSH_DETAIL["prep_s"] = call.prep_seconds
     batch_ok = bool(out[0])
     ok = out[1:]
@@ -1647,7 +1664,8 @@ def _rlc_finish_many(calls: Sequence[_RlcCall]) -> List[Optional[np.ndarray]]:
 
 
 def _prep_stream_chunk(
-    pubkeys, msgs, sigs, lo: int, hi: int, na_c: int, sort: bool = True
+    pubkeys, msgs, sigs, lo: int, hi: int, na_c: int, sort: bool = True,
+    chunk: int = 0, parent=None,
 ):
     """Host prep of ONE planner chunk, plain-kernel lane layout:
     [A_lo..A_{hi-1}, B, pads -> na_c | R_lo..R_{hi-1}, pads -> na_c], with
@@ -1655,64 +1673,71 @@ def _prep_stream_chunk(
     above: per-chunk B terms sum exactly). Runs on the prep worker thread —
     it must touch no shared mutable state beyond the (locked) caches.
 
+    `chunk` is the chunk's index and `parent` the flush's span on the
+    dispatch thread: the worker's `prep.chunk` span nests under it.
+
     Returns (precheck (hi-lo,) bool, pts (2*na_c, 32) u8, scalars,
-    presorted, timing) — timing = {"span": (start, end), "stages": {...}}
-    so the caller can compute windowed prep/device overlap
-    (_overlap_seconds) and the per-stage breakdown."""
-    t0 = time.perf_counter()
+    presorted, timing) — timing = {"span": (start, end), "stages": {...}},
+    the spans' own intervals, so the caller can compute windowed
+    prep/device overlap (_overlap_seconds) and the per-stage breakdown."""
     from tendermint_tpu.crypto.ed25519_ref import BASE, point_compress
 
     from tendermint_tpu import native
 
-    pk, mg, sg = pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi]
     c = hi - lo
     stages: dict = {}
-    if native.available():
-        precheck, a_rows, r_rows, s_rows, h_rows = _precheck_and_hash_fast(
-            pk, mg, sg
-        )
-        stages["hash_s"] = time.perf_counter() - t0
-        t_sc = time.perf_counter()
-        z16, w_rows, u = _rlc_scalars_fast(precheck, s_rows, h_rows)
-        stages["scalars_s"] = time.perf_counter() - t_sc
-        scalars = np.zeros((2 * na_c, 32), dtype=np.uint8)
-        scalars[:c] = w_rows
-        scalars[c] = np.frombuffer(
-            ((L - u) % L).to_bytes(32, "little"), dtype=np.uint8
-        )
-        scalars[na_c : na_c + c, :16] = z16  # zeroed where ~precheck
-    else:
-        precheck, a_rows, r_rows, s_ints, hk_ints = _precheck_and_hash(
-            pk, mg, sg
-        )
-        stages["hash_s"] = time.perf_counter() - t0
-        t_sc = time.perf_counter()
-        zs, w_scalars, u = _rlc_scalars(precheck, s_ints, hk_ints, c)
-        stages["scalars_s"] = time.perf_counter() - t_sc
-        scalars = [0] * (2 * na_c)
-        scalars[:c] = w_scalars
-        scalars[c] = (L - u) % L
-        scalars[na_c : na_c + c] = [
-            zs[i] if precheck[i] else 0 for i in range(c)
-        ]
-    b_enc = np.frombuffer(point_compress(BASE), dtype=np.uint8)
-    pts = np.tile(b_enc, (2 * na_c, 1))
-    if precheck.any():
-        pts[:c][precheck] = a_rows[precheck]
-        pts[na_c : na_c + c][precheck] = r_rows[precheck]
-    # the window sort belongs to the PREP worker too (it is the largest
-    # single host-prep cost at chunk scale — overlapping hashing but not
-    # the sort would leave the dispatch thread sort-bound between chunks);
-    # the sharded arm sorts per shard in prepare_rlc_shards instead
-    presorted = None
-    if sort:
-        from tendermint_tpu.ops.msm_jax import scalars_to_bytes, sort_windows
+    with _trace.timed(
+        "prep.chunk", parent=parent, chunk=chunk, rows=c, lanes=2 * na_c
+    ) as whole:
+        pk, mg, sg = pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi]
+        if native.available():
+            with _trace.timed("prep.hash", chunk=chunk) as st:
+                precheck, a_rows, r_rows, s_rows, h_rows = _precheck_and_hash_fast(
+                    pk, mg, sg
+                )
+            stages["hash_s"] = st.seconds
+            with _trace.timed("prep.scalars", chunk=chunk) as st:
+                z16, w_rows, u = _rlc_scalars_fast(precheck, s_rows, h_rows)
+            stages["scalars_s"] = st.seconds
+            scalars = np.zeros((2 * na_c, 32), dtype=np.uint8)
+            scalars[:c] = w_rows
+            scalars[c] = np.frombuffer(
+                ((L - u) % L).to_bytes(32, "little"), dtype=np.uint8
+            )
+            scalars[na_c : na_c + c, :16] = z16  # zeroed where ~precheck
+        else:
+            with _trace.timed("prep.hash", chunk=chunk) as st:
+                precheck, a_rows, r_rows, s_ints, hk_ints = _precheck_and_hash(
+                    pk, mg, sg
+                )
+            stages["hash_s"] = st.seconds
+            with _trace.timed("prep.scalars", chunk=chunk) as st:
+                zs, w_scalars, u = _rlc_scalars(precheck, s_ints, hk_ints, c)
+            stages["scalars_s"] = st.seconds
+            scalars = [0] * (2 * na_c)
+            scalars[:c] = w_scalars
+            scalars[c] = (L - u) % L
+            scalars[na_c : na_c + c] = [
+                zs[i] if precheck[i] else 0 for i in range(c)
+            ]
+        b_enc = np.frombuffer(point_compress(BASE), dtype=np.uint8)
+        pts = np.tile(b_enc, (2 * na_c, 1))
+        if precheck.any():
+            pts[:c][precheck] = a_rows[precheck]
+            pts[na_c : na_c + c][precheck] = r_rows[precheck]
+        # the window sort belongs to the PREP worker too (it is the largest
+        # single host-prep cost at chunk scale — overlapping hashing but not
+        # the sort would leave the dispatch thread sort-bound between
+        # chunks); the sharded arm sorts per shard in prepare_rlc_shards
+        presorted = None
+        if sort:
+            from tendermint_tpu.ops.msm_jax import scalars_to_bytes, sort_windows
 
-        t_srt = time.perf_counter()
-        digits = scalars_to_bytes(scalars, 2 * na_c)
-        presorted = sort_windows(digits, zero16_from=na_c)
-        stages["sort_s"] = time.perf_counter() - t_srt
-    timing = {"span": (t0, time.perf_counter()), "stages": stages}
+            with _trace.timed("prep.sort", chunk=chunk) as st:
+                digits = scalars_to_bytes(scalars, 2 * na_c)
+                presorted = sort_windows(digits, zero16_from=na_c)
+            stages["sort_s"] = st.seconds
+    timing = {"span": whole.interval(), "stages": stages}
     return precheck, pts, scalars, presorted, timing
 
 
@@ -1756,7 +1781,6 @@ def _verify_batch_rlc_streamed(
     from tendermint_tpu.ops import msm_jax
 
     _device_fault("rlc_submit")
-    t0 = time.perf_counter()
     msm_jax._set_submit_fused(False)
     counters0 = dict(msm_jax.flush_counters())
     n = len(pubkeys)
@@ -1764,6 +1788,7 @@ def _verify_batch_rlc_streamed(
     if chunks is None:
         chunks = _planner_chunks(n)
     pool = _prep_pool()
+    flush_span = _trace.current()  # rlc.pipelined / rlc.streamed: the workers' parent
     prechecks: list = [None] * len(chunks)
     acc = None
     inflight: deque = deque()  # (chunk idx, unsynced lane-validity array)
@@ -1778,8 +1803,9 @@ def _verify_batch_rlc_streamed(
     def _sync_oldest():
         k, dev_ok = inflight.popleft()
         _device_fault("rlc_finish")
-        ok = np.asarray(dev_ok)  # blocks until chunk k's kernels land
-        dev_busy.append((submit_t[k], time.perf_counter()))
+        with _trace.timed("flush.sync", chunk=k) as sy:
+            ok = np.asarray(dev_ok)  # blocks until chunk k's kernels land
+        dev_busy.append((submit_t[k], sy.interval()[1]))
         pc = prechecks[k]
         c = chunks[k][1] - chunks[k][0]
         if pc.any() and not (
@@ -1788,10 +1814,12 @@ def _verify_batch_rlc_streamed(
             lanes_ok[0] = False
 
     fut = pool.submit(
-        _prep_stream_chunk, pubkeys, msgs, sigs, *chunks[0], na_c
+        _prep_stream_chunk, pubkeys, msgs, sigs, *chunks[0], na_c,
+        chunk=0, parent=flush_span,
     )
     for k in range(len(chunks)):
-        precheck, pts, scalars, presorted, timing = fut.result()
+        with _trace.span("flush.prep_wait", chunk=k):
+            precheck, pts, scalars, presorted, timing = fut.result()
         span = timing["span"]
         prep_total[0] += span[1] - span[0]
         prep_spans.append(span)
@@ -1800,7 +1828,8 @@ def _verify_batch_rlc_streamed(
         prechecks[k] = precheck
         if k + 1 < len(chunks):
             fut = pool.submit(
-                _prep_stream_chunk, pubkeys, msgs, sigs, *chunks[k + 1], na_c
+                _prep_stream_chunk, pubkeys, msgs, sigs, *chunks[k + 1], na_c,
+                chunk=k + 1, parent=flush_span,
             )
         part, dev_ok = msm_jax.rlc_partial_submit(
             pts, scalars, zero16_from=na_c, presorted=presorted
@@ -1821,15 +1850,15 @@ def _verify_batch_rlc_streamed(
             _sync_oldest()
     while inflight:
         _sync_oldest()
-    t_sync = time.perf_counter()
     try:
         _device_fault("rlc_finish")
-        batch_ok = bool(np.asarray(msm_jax.partial_identity_submit(acc)))
+        with _trace.timed("flush.sync", what="identity") as sy:
+            batch_ok = bool(np.asarray(msm_jax.partial_identity_submit(acc)))
     except Exception as e:
         _trace.mark_device_call(ok=False, error=repr(e))
         raise
     _trace.mark_device_call(ok=True)
-    dev_busy.append((t_sync, time.perf_counter()))
+    dev_busy.append(sy.interval())
     _record_submit_counters(msm_jax, counters0)
     LAST_FLUSH_DETAIL.update(
         jit_bucket=na_c,
@@ -1840,11 +1869,11 @@ def _verify_batch_rlc_streamed(
         prep_overlap_s=_overlap_seconds(prep_spans, dev_busy),
         prep_stages={k: round(v, 6) for k, v in stage_totals.items()},
         peak_lanes_in_flight=peak_lanes[0],
-        transfer_s=time.perf_counter() - t_sync,
+        transfer_s=sy.seconds,
     )
     LAST_RLC_TIMINGS.update(
         prep_ms=prep_total[0] * 1e3,
-        total_ms=(time.perf_counter() - t0) * 1e3,
+        total_ms=(sy.interval()[1] - prep_spans[0][0]) * 1e3,
         cached=False,
         mode=mode,
     )
@@ -2020,15 +2049,10 @@ def _verify_batch_pipelined(
     chunks = [(0, head), (head, n)]
     for attempt in range(2):
         try:
-            tr = _trace.tracer if _trace.tracer.enabled else None
-            if tr is not None:
-                with tr.span("rlc.pipelined", n=n):
-                    return _verify_batch_rlc_streamed(
-                        pubkeys, msgs, sigs, chunks=chunks, mode="pipelined"
-                    )
-            return _verify_batch_rlc_streamed(
-                pubkeys, msgs, sigs, chunks=chunks, mode="pipelined"
-            )
+            with _trace.span("rlc.pipelined", n=n):
+                return _verify_batch_rlc_streamed(
+                    pubkeys, msgs, sigs, chunks=chunks, mode="pipelined"
+                )
         except Exception as e:
             if attempt == 0 and msm_jax.last_submit_fused():
                 # same contract as _verify_batch_streamed: one bad Mosaic
@@ -2055,7 +2079,6 @@ def _verify_batch_streamed(
     never materializes an over-budget device shape."""
     from tendermint_tpu.ops import msm_jax
 
-    tr = _trace.tracer if _trace.tracer.enabled else None
     mask = None
     sharded_tried = False
     if _sharded_env() is not None:
@@ -2073,10 +2096,7 @@ def _verify_batch_streamed(
     if not sharded_tried or _sharded_env() is None:
         for attempt in range(2):
             try:
-                if tr is not None:
-                    with tr.span("rlc.streamed", n=len(pubkeys)):
-                        mask = _verify_batch_rlc_streamed(pubkeys, msgs, sigs)
-                else:
+                with _trace.span("rlc.streamed", n=len(pubkeys)):
                     mask = _verify_batch_rlc_streamed(pubkeys, msgs, sigs)
                 break
             except Exception as e:
@@ -2124,18 +2144,12 @@ def _verify_batch_rlc(
     (some signature failed, or an encoding was invalid)."""
     from tendermint_tpu.ops import msm_jax
 
-    tr = _trace.tracer if _trace.tracer.enabled else None
     t0 = time.perf_counter()
     for attempt in range(2):
         call = None
         try:
-            if tr is not None:
-                with tr.span("rlc.submit", n=len(pubkeys)):
-                    call = _rlc_submit(pubkeys, msgs, sigs, key_types)
-                with tr.span("rlc.finish", mode=call.mode):
-                    mask = _rlc_finish(call)
-            else:
-                call = _rlc_submit(pubkeys, msgs, sigs, key_types)
+            call = _rlc_submit(pubkeys, msgs, sigs, key_types)  # span rlc.submit
+            with _trace.span("rlc.finish", mode=call.mode):
                 mask = _rlc_finish(call)
             break
         except Exception as e:
@@ -2935,7 +2949,7 @@ def verify_batch_finish(h: BatchHandle) -> np.ndarray:
         h._mask = h._acc.flush()[start:end]
         return h._mask
     pubkeys, msgs, sigs, backend, key_types, mixed = h._args
-    tr = _trace.tracer if _trace.tracer.enabled else None  # single flag check
+    tr = _trace.tracer if _trace.tracer.enabled else None  # record_flush's event
     # total spans submit through finish (h._t0); prep happened at submit
     t0 = h._t0 if h._t0 is not None else time.perf_counter()
     # breaker deadline clock starts at FINISH: submit-to-finish includes
@@ -2949,11 +2963,9 @@ def verify_batch_finish(h: BatchHandle) -> np.ndarray:
             # timeout — once per queued handle. Abandon the in-flight result
             # and recover below on the host.
             mask = None
-        elif tr is not None:
-            with tr.span("rlc.finish", n=len(pubkeys), async_=True):
-                mask = _rlc_finish(h._call)
         else:
-            mask = _rlc_finish(h._call)
+            with _trace.span("rlc.finish", n=len(pubkeys), async_=True):
+                mask = _rlc_finish(h._call)
     except Exception as e:
         # a device failure, not a combined-check failure: count it toward
         # the breaker's trip so the per-sig fallback below can short-circuit
@@ -3102,12 +3114,14 @@ def verify_batch(
         return np.zeros(0, dtype=bool)
     memo_digests = None
     if _MEMO.capacity:
-        memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
-        t_memo = time.perf_counter()
-        hit = _MEMO.lookup(memo_digests) if len(_MEMO) else np.zeros(
-            len(memo_digests), dtype=bool
-        )
-        nh = int(hit.sum())
+        with _trace.span("verify_batch.memo", rows=len(pubkeys)) as ms:
+            memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
+            t_memo = time.perf_counter()
+            hit = _MEMO.lookup(memo_digests) if len(_MEMO) else np.zeros(
+                len(memo_digests), dtype=bool
+            )
+            nh = int(hit.sum())
+            ms.set(hits=nh)
         if nh == len(pubkeys):
             # every row already verified OK in an earlier flush (the
             # deferred-verified commit shape): no residue, no device work
@@ -3177,72 +3191,66 @@ def verify_batch(
         mask = _LANE_ROUTER(pubkeys, msgs, sigs, backend, key_types, sources)
         if mask is not None:
             return mask
-    tr = _trace.tracer if _trace.tracer.enabled else None  # single flag check
     LAST_FLUSH_DETAIL.clear()
     compile0 = _trace.compile_seconds_total()
-    t0 = time.perf_counter()
-    span = None
-    if tr is not None:
-        span = tr.span("verify_batch", n=len(pubkeys))
-        span.__enter__()
-    try:
+    with _trace.timed("verify_batch", n=len(pubkeys)) as vb:
         mask, be, path = _verify_batch_routed(
             pubkeys, msgs, sigs, backend, key_types
         )
-    except BaseException as e:
-        if span is not None:
-            span.set(error=type(e).__name__)
-            span.__exit__(None, None, None)
-        raise
-    detail = dict(LAST_FLUSH_DETAIL)
-    compile_s = _trace.compile_seconds_total() - compile0
-    quarantined = None
-    if sources is not None:
-        # provenance feed (crypto/provenance.py): count rows whose source
-        # was ALREADY quarantined when this flush ran (attribution for the
-        # quarantine lane), then advance the suspicion state machines with
-        # this flush's verdicts. Advisory: never allowed to break the path.
-        try:
-            from tendermint_tpu.crypto import provenance as _prov
+        detail = dict(LAST_FLUSH_DETAIL)
+        compile_s = _trace.compile_seconds_total() - compile0
+        quarantined = None
+        if sources is not None:
+            # provenance feed (crypto/provenance.py): count rows whose source
+            # was ALREADY quarantined when this flush ran (attribution for
+            # the quarantine lane), then advance the suspicion state machines
+            # with this flush's verdicts. Advisory: never allowed to break
+            # the path.
+            try:
+                from tendermint_tpu.crypto import provenance as _prov
 
-            scorer = _prov.default_scorer()
-            q = scorer.quarantined_sources()
-            if q:
-                quarantined = sum(1 for s in sources if s in q) or None
-            scorer.record_rows(sources, mask)
-        except Exception:
-            quarantined = None
-    _trace.record_flush(
-        backend=be,
-        path=path,
-        n=len(pubkeys),
-        total_s=time.perf_counter() - t0,
-        n_valid=int(mask.sum()),
-        prep_s=detail.get("prep_s"),
-        compile_s=compile_s if compile_s > 0 else None,
-        transfer_s=detail.get("transfer_s"),
-        jit_bucket=detail.get("jit_bucket"),
-        padding_lanes=detail.get("padding_lanes"),
-        cache_hits=detail.get("cache_hits"),
-        cache_misses=detail.get("cache_misses"),
-        rlc_fallback=detail.get("rlc_fallback", False),
-        fused=detail.get("fused"),
-        h2d_bytes=detail.get("h2d_bytes"),
-        device_dispatches=detail.get("device_dispatches"),
-        chunks=detail.get("chunks"),
-        chunk_lanes=detail.get("chunk_lanes"),
-        prep_overlap_s=detail.get("prep_overlap_s"),
-        prep_stages=detail.get("prep_stages"),
-        recovery_flushes=detail.get("recovery_flushes"),
-        quarantined=quarantined,
-        tracer_=tr,
-    )
-    if span is not None:
-        span.set(path=path, backend=be)
-        span.__exit__(None, None, None)
+                scorer = _prov.default_scorer()
+                q = scorer.quarantined_sources()
+                if q:
+                    quarantined = sum(1 for s in sources if s in q) or None
+                scorer.record_rows(sources, mask)
+            except Exception:
+                quarantined = None
+        # the flush's total closes HERE: the record's own body (flush.record)
+        # and the memo insert below are the caller's time, not the flush's
+        total_s = vb.elapsed()
+        with _trace.span("flush.record"):
+            _trace.record_flush(
+                backend=be,
+                path=path,
+                n=len(pubkeys),
+                total_s=total_s,
+                n_valid=int(mask.sum()),
+                prep_s=detail.get("prep_s"),
+                compile_s=compile_s if compile_s > 0 else None,
+                transfer_s=detail.get("transfer_s"),
+                jit_bucket=detail.get("jit_bucket"),
+                padding_lanes=detail.get("padding_lanes"),
+                cache_hits=detail.get("cache_hits"),
+                cache_misses=detail.get("cache_misses"),
+                rlc_fallback=detail.get("rlc_fallback", False),
+                fused=detail.get("fused"),
+                h2d_bytes=detail.get("h2d_bytes"),
+                device_dispatches=detail.get("device_dispatches"),
+                chunks=detail.get("chunks"),
+                chunk_lanes=detail.get("chunk_lanes"),
+                prep_overlap_s=detail.get("prep_overlap_s"),
+                prep_stages=detail.get("prep_stages"),
+                recovery_flushes=detail.get("recovery_flushes"),
+                quarantined=quarantined,
+                tracer_=_trace.tracer if vb.recording else None,
+            )
+        vb.set(path=path, backend=be)
     # memoize the rows that verified OK (never on exception — we only get
     # here when the flush produced an exact per-row mask)
-    _MEMO.insert(memo_digests, mask)
+    if memo_digests is not None:
+        with _trace.span("verify_batch.memo", rows=len(pubkeys), insert=True):
+            _MEMO.insert(memo_digests, mask)
     return mask
 
 

@@ -19,43 +19,106 @@ Three consumers:
 - node liveness and the bench's stall detector read `device_health()`
   (device init duration, last-successful-device-call age, `device_up`).
 
-Overhead contract: when `tracer.enabled` is False the instrumented hot
-paths make ZERO tracer calls beyond one flag read (they hoist
-`tracer if tracer.enabled else None` and skip everything on None), and the
-ring buffer never exceeds its configured size (deque maxlen). Configure via
-`[instrumentation] trace_enabled / trace_ring_size` (node/node.py) or the
-TMTPU_TRACE env default.
+Overhead contract: the instrumented hot paths open spans through the
+module-level `span()` / `timed()` below. With `tracer.enabled` False `span()`
+is one flag read and hands back ONE shared no-op context manager (no `Span`
+is constructed, the ring is untouched); `timed()` hands back a bare
+`perf_counter_ns` pair where the caller feeds the duration into the flush
+record either way, so a stage is timed once whether the recorder is on or
+off. The ring buffer never exceeds its configured size (deque maxlen).
+Configure via `[instrumentation] trace_enabled / trace_ring_size`
+(node/node.py) or the TMTPU_TRACE env default.
+
+Every ring event carries `t0_ns` (`time.perf_counter_ns()` at entry; for a
+point event, at the event) and `root`, the id of the outermost span open
+when it began, so the spans of one request share an identifier. Work handed
+to another thread nests under the submitting span with `span(name,
+parent=<that span>)`. While a JAX profiler session is live each span is
+mirrored as `jax.profiler.TraceAnnotation("tm:" + name)`, which puts the
+program's spans on the host plane of the same `.xplane.pb`, on the
+profiler's clock, beside the device plane.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
 DEFAULT_RING_SIZE = 4096
+ANNOTATION_PREFIX = "tm:"
+
+_perf_ns = time.perf_counter_ns
+
+# jax.profiler.TraceAnnotation once JAX is loaded (False: looked for and not
+# usable). A host-only process never imports JAX to trace.
+_ANNOTATION: Any = None
 
 
-class Span:
+def _live_annotation():
+    """TraceAnnotation while a profiler session is live, else None. The
+    check is TraceMe's own (it asks the profiler, whoever started it), 0.05
+    us a span against 0.3 us for an annotation nobody records."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as ann
+        except Exception:  # JAX still loading: ask again at the next span
+            return None
+        # an older JAX without is_enabled: no mirror, the ring is unaffected
+        _ANNOTATION = ann = ann if hasattr(ann, "is_enabled") else False
+    return ann if ann and ann.is_enabled() else None
+
+
+class _Interval:
+    """What Span and Stopwatch share: a start and an end on
+    `perf_counter_ns`, handed back in `perf_counter`'s seconds so they can
+    stand beside bare `time.perf_counter()` readings."""
+
+    __slots__ = ()
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1_ns - self.t0_ns) / 1e9
+
+    def interval(self) -> tuple:
+        return (self.t0_ns / 1e9, self.t1_ns / 1e9)
+
+    def elapsed(self) -> float:
+        """Seconds since entry, for a span that is still open."""
+        return (_perf_ns() - self.t0_ns) / 1e9
+
+
+class Span(_Interval):
     """An in-flight span; records one event into the tracer's ring on exit.
 
-    Use as a context manager (or call __enter__/__exit__ explicitly when the
-    caller must survive with tracing disabled — see crypto/batch.py).
     `set(**attrs)` attaches attributes mid-flight (e.g. the chosen path,
-    known only at the end of a flush)."""
+    known only at the end of a flush). `parent` is a Span of another thread
+    (or one already closed): this span takes its id as parent and its root."""
 
-    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id", "root",
+                 "t0_ns", "t1_ns", "_parent", "_ann")
+    recording = True
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
+    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
+                 parent: "Optional[Span]" = None):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self.span_id = tracer._next_id()
+        self._parent = parent if parent is not None and parent.recording else None
         self.parent_id: Optional[int] = None
-        self._t0 = 0.0
+        self.root = self.span_id
+        self.t0_ns = self.t1_ns = 0
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -63,21 +126,69 @@ class Span:
 
     def __enter__(self) -> "Span":
         stack = self._tracer._stack()
-        self.parent_id = stack[-1] if stack else None
-        stack.append(self.span_id)
-        self._t0 = time.perf_counter()
+        parent = self._parent or (stack[-1] if stack else None)
+        if parent is not None:
+            self.parent_id, self.root = parent.span_id, parent.root
+        stack.append(self)
+        ann = _live_annotation()
+        if ann is not None:
+            self._ann = ann(ANNOTATION_PREFIX + self.name)
+            self._ann.__enter__()
+        self.t0_ns = _perf_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        dur = time.perf_counter() - self._t0
+        self.t1_ns = _perf_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
-        if stack and stack[-1] == self.span_id:
+        if stack and stack[-1] is self:
             stack.pop()
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         self._tracer._record(
-            self.name, self.span_id, self.parent_id, dur, self.attrs
+            self.name, self.span_id, self.parent_id, self.root, self.t0_ns,
+            (self.t1_ns - self.t0_ns) / 1e9, self.attrs,
         )
+
+
+class Stopwatch(_Interval):
+    """`timed()` with the recorder off: the bare clock pair, nothing else."""
+
+    __slots__ = ("t0_ns", "t1_ns")
+    recording = False
+
+    def __init__(self):
+        self.t0_ns = self.t1_ns = 0
+
+    def set(self, **attrs) -> "Stopwatch":
+        return self
+
+    def __enter__(self) -> "Stopwatch":
+        self.t0_ns = _perf_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1_ns = _perf_ns()
+
+
+class _NoopSpan:
+    """`span()` with the recorder off: one shared object, no state."""
+
+    __slots__ = ()
+    recording = False
+
+    def set(self, **attrs) -> "_NoopSpan":
+        return self
+
+    def __enter__(self) -> "_NoopSpan":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        return None
+
+
+NOOP = _NoopSpan()
 
 
 class Tracer:
@@ -88,17 +199,28 @@ class Tracer:
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=max(1, int(ring_size)))
         self._local = threading.local()
-        self._id = 0
+        self._ids = itertools.count(1)  # next() is atomic: no lock a span
 
     # -- recording ----------------------------------------------------------
 
-    def span(self, name: str, **attrs) -> Span:
-        return Span(self, name, attrs)
+    def span(self, name: str, parent: Optional[Span] = None, **attrs) -> Span:
+        return Span(self, name, attrs, parent)
 
     def event(self, name: str, **attrs) -> None:
         """A zero-duration point event, parented to the current span."""
         stack = self._stack()
-        self._record(name, self._next_id(), stack[-1] if stack else None, None, attrs)
+        span_id = self._next_id()
+        top = stack[-1] if stack else None
+        self._record(
+            name, span_id, top.span_id if top else None,
+            top.root if top else span_id, _perf_ns(), None, attrs,
+        )
+
+    def current(self) -> Optional[Span]:
+        """This thread's innermost open span: what a task handed to another
+        thread names as its `parent`."""
+        stack = self._stack()
+        return stack[-1] if stack else None
 
     # -- introspection ------------------------------------------------------
 
@@ -138,9 +260,7 @@ class Tracer:
     # -- internals ----------------------------------------------------------
 
     def _next_id(self) -> int:
-        with self._lock:
-            self._id += 1
-            return self._id
+        return next(self._ids)
 
     def _stack(self) -> list:
         stack = getattr(self._local, "stack", None)
@@ -148,11 +268,13 @@ class Tracer:
             stack = self._local.stack = []
         return stack
 
-    def _record(self, name, span_id, parent_id, dur_s, attrs) -> None:
+    def _record(self, name, span_id, parent_id, root, t0_ns, dur_s, attrs) -> None:
         event = {
             "name": name,
             "span": span_id,
             "parent": parent_id,
+            "root": root,
+            "t0_ns": t0_ns,
             "ts": time.time(),
         }
         if dur_s is not None:
@@ -164,6 +286,33 @@ class Tracer:
 
 
 tracer = Tracer(enabled=os.environ.get("TMTPU_TRACE", "1") != "0")
+
+
+def span(name: str, parent=None, **attrs):
+    """A span of the process's recorder, or the shared no-op when it is off:
+    `with trace.span("flush.sync", chunk=k): ...` is the whole call site."""
+    t = tracer
+    if not t.enabled:
+        return NOOP
+    return Span(t, name, attrs, parent)
+
+
+def timed(name: str, parent=None, **attrs):
+    """`span()` for a stage whose duration also feeds the flush record: a
+    Span when the recorder is on, a bare Stopwatch when it is off. Either
+    way `.seconds` / `.interval()` after the block are the one timing."""
+    t = tracer
+    if not t.enabled:
+        return Stopwatch()
+    return Span(t, name, attrs, parent)
+
+
+def current():
+    """The calling thread's innermost open span (None when the recorder is
+    off or nothing is open): pass it as `parent=` to a task of another
+    thread."""
+    t = tracer
+    return t.current() if t.enabled else None
 
 
 # ---------------------------------------------------------------------------

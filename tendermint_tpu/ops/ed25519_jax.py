@@ -362,15 +362,11 @@ def verify_prepared(
         for x in (a_bytes, r_bytes, s_digits, h_digits)
     ):
         return _verify_core(a_bytes, r_bytes, s_digits, h_digits, _trace_ctx(batch))
-    from tendermint_tpu.libs.trace import tracer as _tracer
+    from tendermint_tpu.libs import trace as _trace
     from tendermint_tpu.ops import aot_cache  # lazy: avoids import cycle
 
-    if _tracer.enabled:
-        with _tracer.span("kernel.persig", lanes=int(batch[0]) if batch else 1):
-            return aot_cache.call(
-                "persig", _verify_jit, a_bytes, r_bytes, s_digits, h_digits,
-                make_ctx(batch),
-            )
-    return aot_cache.call(
-        "persig", _verify_jit, a_bytes, r_bytes, s_digits, h_digits, make_ctx(batch)
-    )
+    with _trace.span("kernel.persig", lanes=int(batch[0]) if batch else 1):
+        return aot_cache.call(
+            "persig", _verify_jit, a_bytes, r_bytes, s_digits, h_digits,
+            make_ctx(batch),
+        )

@@ -74,6 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tendermint_tpu.libs import trace as _trace
 from tendermint_tpu.ops import aot_cache
 from tendermint_tpu.ops import fe25519 as fe
 from tendermint_tpu.ops.ed25519_jax import (
@@ -179,13 +180,19 @@ def _set_submit_fused(fused: bool) -> None:
 
 def _dispatch(name: str, jit_fn, *args):
     """aot_cache.call with device-traffic accounting: every numpy leaf is a
-    host->device upload on this call; jax-array leaves are device-resident."""
-    c = _FLUSH_TLS.counters
-    c["dispatches"] += 1
-    for leaf in jax.tree_util.tree_leaves(args):
-        if isinstance(leaf, np.ndarray):
-            c["h2d_bytes"] += leaf.nbytes
-    return aot_cache.call(name, jit_fn, *args)
+    host->device upload on this call; jax-array leaves are device-resident.
+    The `dispatch` span covers the accounting, the upload and the (async)
+    launch of the AOT program `name`."""
+    with _trace.span("dispatch", program=name) as sp:
+        c = _FLUSH_TLS.counters
+        c["dispatches"] += 1
+        h2d = 0
+        for leaf in jax.tree_util.tree_leaves(args):
+            if isinstance(leaf, np.ndarray):
+                h2d += leaf.nbytes
+        c["h2d_bytes"] += h2d
+        sp.set(h2d_bytes=h2d)
+        return aot_cache.call(name, jit_fn, *args)
 
 
 def fused_for_lanes(n_lanes: int) -> bool:
@@ -766,46 +773,55 @@ def _msm_total_fused(C: SmallCtx, pts: Point, perm, ends) -> Point:
     # gather lanes directly into fused order: whole 320-byte point rows
     # (the r5 row-gather layout), chunk-wise bit-reversed via the composed
     # permutation — the only big gather the tree phase pays.
-    perm_f = jnp.take(perm, jnp.asarray(PM.brev_positions(n, ch)), axis=1)
-    rowtab = jnp.stack([c.T for c in pts], axis=1).reshape(n, 4 * fe.NLIMBS)
-    g_rows = rowtab[perm_f.reshape(-1)]  # (T*N, 80)
+    # (each stage under a jax.named_scope: metadata only, so that an XLA
+    # operation's op_name in a device trace says which stage made it)
+    with jax.named_scope("row_gather"):
+        perm_f = jnp.take(perm, jnp.asarray(PM.brev_positions(n, ch)), axis=1)
+        rowtab = jnp.stack([c.T for c in pts], axis=1).reshape(n, 4 * fe.NLIMBS)
+        g_rows = rowtab[perm_f.reshape(-1)]  # (T*N, 80)
 
     # chunk trees: ONE kernel computes levels 1..lc per chunk in VMEM
-    ctree = PM.uptree(PM.rows_to_packed(g_rows), ch)
-    ctree_rows = PM.packed_to_rows(ctree)
+    with jax.named_scope("uptree"):
+        ctree = PM.uptree(PM.rows_to_packed(g_rows), ch)
+        ctree_rows = PM.packed_to_rows(ctree)
 
     # top tree over the T*ncw chunk roots (tiny; existing limb-major path)
-    root_row = g.row_off[g.lc]
-    roots = ctree.reshape(4, fe.NLIMBS, t_ * ncw, g.rows_out, 128)[
-        :, :, :, root_row, 0
-    ]
-    roots_pt = Point(*(roots[c].reshape(fe.NLIMBS, t_, ncw) for c in range(4)))
-    top = _tree_levels(C, roots_pt)  # (20, T, Wtop+1) incl. identity lane
-    wtop1 = top.x.shape[-1]
-    top_rows = jnp.stack(
-        [jnp.moveaxis(c, 0, -1) for c in top], axis=-2
-    ).reshape(t_ * wtop1, 4 * fe.NLIMBS)
+    with jax.named_scope("top_tree"):
+        root_row = g.row_off[g.lc]
+        roots = ctree.reshape(4, fe.NLIMBS, t_ * ncw, g.rows_out, 128)[
+            :, :, :, root_row, 0
+        ]
+        roots_pt = Point(*(roots[c].reshape(fe.NLIMBS, t_, ncw) for c in range(4)))
+        top = _tree_levels(C, roots_pt)  # (20, T, Wtop+1) incl. identity lane
+        wtop1 = top.x.shape[-1]
+        top_rows = jnp.stack(
+            [jnp.moveaxis(c, 0, -1) for c in top], axis=-2
+        ).reshape(t_ * wtop1, 4 * fe.NLIMBS)
 
     # Fenwick prefix extraction: row-gather the decomposition nodes, reduce
     # them in ONE accumulating kernel (no materialized (T,256,K) tensor)
-    all_rows = jnp.concatenate([g_rows, ctree_rows, top_rows], axis=0)
-    node_idx = fused_node_indices_device(ends, n, ch)  # (NB, T, Kf)
-    kf = node_idx.shape[-1]
-    gathered = all_rows[node_idx.reshape(-1)]  # (NB*T*Kf, 80)
-    gk = jnp.moveaxis(gathered.reshape(NBUCKETS * t_, kf, 4 * fe.NLIMBS), 1, 0)
-    gk = jnp.moveaxis(gk, -1, 1).reshape(
-        kf, 4, fe.NLIMBS, NBUCKETS * t_ // 128, 128
-    )
-    prefix = PM.fenwick_reduce(gk)  # packed, v-major
+    with jax.named_scope("fenwick_gather"):
+        all_rows = jnp.concatenate([g_rows, ctree_rows, top_rows], axis=0)
+        node_idx = fused_node_indices_device(ends, n, ch)  # (NB, T, Kf)
+        kf = node_idx.shape[-1]
+        gathered = all_rows[node_idx.reshape(-1)]  # (NB*T*Kf, 80)
+        gk = jnp.moveaxis(gathered.reshape(NBUCKETS * t_, kf, 4 * fe.NLIMBS), 1, 0)
+        gk = jnp.moveaxis(gk, -1, 1).reshape(
+            kf, 4, fe.NLIMBS, NBUCKETS * t_ // 128, 128
+        )
+    with jax.named_scope("fenwick_reduce"):
+        prefix = PM.fenwick_reduce(gk)  # packed, v-major
 
     # weighted bucket sum: one fused fold kernel + the tiny (20, T) tail
-    s_coords, p255_coords = PM.bucket_fold(prefix, t_)
-    s_pt = Point(*s_coords)
-    p_last = Point(*p255_coords)
-    m = _pdbl_n(C, p_last, WINDOW_BITS)  # [256] P_255
-    m = _padd(C, m, _pneg(C, p_last))  # [255] P_255
-    w_pts = _padd(C, m, _pneg(C, s_pt))  # (20, T) per-window sums
-    return _combine_windows(C, w_pts)
+    with jax.named_scope("bucket_fold"):
+        s_coords, p255_coords = PM.bucket_fold(prefix, t_)
+        s_pt = Point(*s_coords)
+        p_last = Point(*p255_coords)
+        m = _pdbl_n(C, p_last, WINDOW_BITS)  # [256] P_255
+        m = _padd(C, m, _pneg(C, p_last))  # [255] P_255
+        w_pts = _padd(C, m, _pneg(C, s_pt))  # (20, T) per-window sums
+    with jax.named_scope("window_combine"):
+        return _combine_windows(C, w_pts)
 
 
 def _msm_check(C: SmallCtx, pts: Point, perm, ends, fused: bool) -> jnp.ndarray:
@@ -813,7 +829,9 @@ def _msm_check(C: SmallCtx, pts: Point, perm, ends, fused: bool) -> jnp.ndarray:
     unfused per-level reference. `fused` is trace-static — the two variants
     are distinct jit programs (and distinct AOT artifacts)."""
     if fused:
-        return point_is_identity(C, _msm_total_fused(C, pts, perm, ends))
+        total = _msm_total_fused(C, pts, perm, ends)
+        with jax.named_scope("identity_check"):
+            return point_is_identity(C, total)
     node_idx = fenwick_nodes_device(ends, pts.x.shape[-1])
     return _msm_is_identity(C, pts, perm, node_idx)
 
@@ -828,8 +846,9 @@ def _rlc_core(
 ) -> jnp.ndarray:
     """Returns bool (1+N,): [batch_ok, lane_ok...] packed into ONE array so
     the caller syncs in a single D2H round trip."""
-    p, ok = decompress(fctx, pts_bytes)
-    p = _pselect(ok, p, identity(fctx))
+    with jax.named_scope("decompress"):
+        p, ok = decompress(fctx, pts_bytes)
+        p = _pselect(ok, p, identity(fctx))
     bok = _msm_check(C, p, perm, ends, fused)
     return jnp.concatenate([bok[None], ok])
 
@@ -850,8 +869,9 @@ def _rlc_partial_core(
 
     Returns (coords (4, 20) int32 — the chunk's partial point in extended
     limbs, ok (N,) bool — per-lane decompress validity)."""
-    p, ok = decompress(fctx, pts_bytes)
-    p = _pselect(ok, p, identity(fctx))
+    with jax.named_scope("decompress"):
+        p, ok = decompress(fctx, pts_bytes)
+        p = _pselect(ok, p, identity(fctx))
     if fused:
         part = _msm_total_fused(C, p, perm, ends)
     else:
@@ -871,7 +891,8 @@ def _partial_fold_core(a: jnp.ndarray, b: jnp.ndarray, C: SmallCtx) -> jnp.ndarr
 def _partial_identity_core(a: jnp.ndarray, C: SmallCtx) -> jnp.ndarray:
     """Identity check on an accumulated (4, 20) partial point — the streamed
     flush's combined-check verdict."""
-    return point_is_identity(C, Point(a[0], a[1], a[2], a[3]))
+    with jax.named_scope("identity_check"):
+        return point_is_identity(C, Point(a[0], a[1], a[2], a[3]))
 
 
 def _rlc_core_cached(
@@ -885,8 +906,9 @@ def _rlc_core_cached(
 ) -> jnp.ndarray:
     """Cached-A variant: lanes = [A block | R block]; only R is decompressed.
     Returns bool (1+Nr,): [batch_ok, r_ok...]."""
-    r, r_ok = decompress(fctx, r_bytes)
-    r = _pselect(r_ok, r, identity(fctx))
+    with jax.named_scope("decompress"):
+        r, r_ok = decompress(fctx, r_bytes)
+        r = _pselect(r_ok, r, identity(fctx))
     pts = Point(
         *(
             jnp.concatenate([a, b], axis=-1)
@@ -947,10 +969,11 @@ def _rlc_core_cached_mixed(
     Returns bool (1+Ne+Ns,): [batch_ok, ed_r_ok..., sr_r_ok...]."""
     from tendermint_tpu.ops.ristretto_jax import ristretto_decode
 
-    er, er_ok = decompress(fctx_ed, ed_r_bytes)
-    er = _pselect(er_ok, er, identity(fctx_ed))
-    sr, sr_ok = ristretto_decode(fctx_sr, sr_r_bytes)
-    sr = _pselect(sr_ok, sr, identity(fctx_sr))
+    with jax.named_scope("decompress"):
+        er, er_ok = decompress(fctx_ed, ed_r_bytes)
+        er = _pselect(er_ok, er, identity(fctx_ed))
+        sr, sr_ok = ristretto_decode(fctx_sr, sr_r_bytes)
+        sr = _pselect(sr_ok, sr, identity(fctx_sr))
     pts = Point(
         *(
             jnp.concatenate([a, b, c], axis=-1)
@@ -1016,18 +1039,6 @@ def decompress_rows(rows: np.ndarray) -> Tuple[Tuple[np.ndarray, ...], np.ndarra
     return coords, np.asarray(ok)[:m]
 
 
-def _trace_span(name: str, **attrs):
-    """Flight-recorder span when tracing is on, else a no-op context
-    (libs/trace.py); the submit spans cover host sort + async dispatch."""
-    from tendermint_tpu.libs.trace import tracer
-
-    if tracer.enabled:
-        return tracer.span(name, **attrs)
-    import contextlib
-
-    return contextlib.nullcontext()
-
-
 def rlc_check_submit(
     pts_bytes: np.ndarray, scalars: Sequence[int], zero16_from: int = 0,
     presorted=None,
@@ -1042,7 +1053,7 @@ def rlc_check_submit(
     unsynced device bool (1+N,): [batch_ok, lane_ok...] — np.asarray() it
     to sync."""
     n = pts_bytes.shape[0]
-    with _trace_span("kernel.rlc_submit", variant="plain", lanes=n):
+    with _trace.span("kernel.rlc_submit", variant="plain", lanes=n):
         if presorted is not None:
             perm, ends = presorted
         else:
@@ -1075,7 +1086,7 @@ def rlc_partial_submit(
     Returns (coords (4, 20) int32 device array, ok (N,) bool device array)
     — both unsynced; np.asarray() to sync."""
     n = pts_bytes.shape[0]
-    with _trace_span("kernel.rlc_partial_submit", variant="partial", lanes=n):
+    with _trace.span("kernel.rlc_partial_submit", variant="partial", lanes=n):
         if presorted is not None:
             perm, ends = presorted
         else:
@@ -1119,7 +1130,7 @@ def rlc_check_cached_submit(
     na = a_coords[0].shape[-1]
     nr = r_bytes.shape[0]
     n = na + nr
-    with _trace_span("kernel.rlc_submit", variant="cached", lanes=n):
+    with _trace.span("kernel.rlc_submit", variant="cached", lanes=n):
         fctx = make_ctx((nr,))
         fused = fused_for_lanes(n)
         _set_submit_fused(fused)
@@ -1176,7 +1187,7 @@ def rlc_check_cached_mixed_submit(
     ne = ed_r_bytes.shape[0]
     ns = sr_r_bytes.shape[0]
     n = na + ne + ns
-    with _trace_span("kernel.rlc_submit", variant="mixed", lanes=n):
+    with _trace.span("kernel.rlc_submit", variant="mixed", lanes=n):
         digits = scalars_to_bytes(scalars, n)
         # rows >= na are the (128-bit) z-lane scalars of both R blocks
         perm, ends = sort_windows(digits, zero16_from=na)

@@ -255,6 +255,7 @@ def _padd_call(s: int, blk: int):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((4, NL, s, LANE), jnp.int32),
         interpret=_interpret(),
+        name="fe_padd",
     )
 
 
@@ -268,6 +269,7 @@ def _pdbl_call(s: int, blk: int, n: int = 1):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((4, NL, s, LANE), jnp.int32),
         interpret=_interpret(),
+        name="fe_pdbl",
     )
 
 
@@ -281,6 +283,7 @@ def _fsq_call(s: int, blk: int, n: int):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((NL, s, LANE), jnp.int32),
         interpret=_interpret(),
+        name="fe_fsq",
     )
 
 

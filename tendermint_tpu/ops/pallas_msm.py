@@ -311,6 +311,7 @@ def _uptree_call(total_rows: int, ch: int):
             (4, NL, nchunks * g.rows_out, LANE), jnp.int32
         ),
         interpret=pallas_fe._interpret(),
+        name="msm_uptree",
     )
 
 
@@ -391,6 +392,7 @@ def _fenwick_call(kf: int, s: int, blk: int):
         out_specs=pl.BlockSpec((4, NL, blk, LANE), lambda c, k: (0, 0, c, 0)),
         out_shape=jax.ShapeDtypeStruct((4, NL, s, LANE), jnp.int32),
         interpret=pallas_fe._interpret(),
+        name="msm_fenwick_reduce",
     )
 
 
@@ -495,6 +497,7 @@ def _bucket_call(s: int, t_windows: int):
         out_specs=pl.BlockSpec((4, NL, 8, LANE), lambda i: (0, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((4, NL, 8, LANE), jnp.int32),
         interpret=pallas_fe._interpret(),
+        name="msm_bucket_fold",
     )
 
 

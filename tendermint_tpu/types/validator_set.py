@@ -25,6 +25,7 @@ liveness-friendly relaxation; safety is unchanged.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from fractions import Fraction
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -34,6 +35,7 @@ from tendermint_tpu.crypto.batch import verify_batch
 from tendermint_tpu.crypto.keys import PubKey
 from tendermint_tpu.crypto.merkle import hash_from_byte_slices
 from tendermint_tpu.libs import protowire as pw
+from tendermint_tpu.libs import trace as _trace
 
 INT64_MAX = 2**63 - 1
 MAX_TOTAL_VOTING_POWER = INT64_MAX // 8
@@ -106,6 +108,31 @@ class Validator:
         w.message_field(1, pk.bytes(), always=True)
         w.varint_field(2, self.voting_power)
         return w.bytes()
+
+
+@contextlib.contextmanager
+def _commit_span(entry: str, commit, height: int, done: str = "accepted"):
+    """The root span of one Verify* call (`commit.verify`): the entry that
+    was called, the rows it gathered (set by the body once known) and, on
+    exit, the verdict: `done`, or the class of the error it raised."""
+    with _trace.span(
+        "commit.verify", entry=entry, rows=len(commit.signatures), height=height
+    ) as sp:
+        try:
+            yield sp
+        except BaseException as e:
+            sp.set(verdict=type(e).__name__)
+            raise
+        sp.set(verdict=done)
+
+
+def _sign_bytes_spanned(commit, chain_id: str, idxs) -> list:
+    """commit.vote_sign_bytes_many under its span (`commit.sign_bytes`)."""
+    with _trace.span("commit.sign_bytes", rows=len(idxs)) as sp:
+        msgs = commit.vote_sign_bytes_many(chain_id, idxs)
+        if sp.recording:  # 16 ns a row, inside the span; nothing when off
+            sp.set(bytes=sum(map(len, msgs)))
+    return msgs
 
 
 class ValidatorSet:
@@ -324,6 +351,36 @@ class ValidatorSet:
         """All signatures checked; +2/3 must be for the block.
         (reference: types/validator_set.go:662-714, serial loop replaced by one
         batched device verify)."""
+        with _commit_span("verify_commit", commit, height) as root:
+            self._check_commit_for(block_id, height, commit)
+            pubkeys, sigs, meta, key_types, idxs = [], [], [], [], []
+            with _trace.span("commit.gather") as sp:
+                for idx, cs in enumerate(commit.signatures):
+                    if cs.absent():
+                        continue
+                    val = self.validators[idx]
+                    pubkeys.append(val.pub_key.bytes())
+                    idxs.append(idx)
+                    sigs.append(cs.signature)
+                    meta.append((idx, val.voting_power, cs.for_block()))
+                    key_types.append(val.pub_key.type_name())
+                sp.set(rows=len(idxs))
+            root.set(rows=len(idxs))
+            msgs = _sign_bytes_spanned(commit, chain_id, idxs)
+            mask = verify_batch(pubkeys, msgs, sigs, key_types=key_types)
+            with _trace.span("commit.tally"):
+                tallied = 0
+                for ok, (idx, power, for_block) in zip(mask, meta):
+                    if not ok:
+                        raise CommitVerifyError(f"wrong signature (#{idx})")
+                    if for_block:
+                        tallied += power
+                needed = self.total_voting_power() * 2 // 3
+                if tallied <= needed:
+                    raise NotEnoughVotingPowerError(tallied, needed)
+
+    def _check_commit_for(self, block_id, height: int, commit) -> None:
+        """The structural checks every VerifyCommit* entry starts with."""
         if self.size() != len(commit.signatures):
             raise CommitVerifyError(
                 f"invalid commit -- wrong set size: {self.size()} vs {len(commit.signatures)}"
@@ -334,66 +391,57 @@ class ValidatorSet:
             raise CommitVerifyError(
                 f"invalid commit -- wrong block ID: want {block_id}, got {commit.block_id}"
             )
-        pubkeys, sigs, meta, key_types, idxs = [], [], [], [], []
-        for idx, cs in enumerate(commit.signatures):
-            if cs.absent():
-                continue
-            val = self.validators[idx]
-            pubkeys.append(val.pub_key.bytes())
-            idxs.append(idx)
-            sigs.append(cs.signature)
-            meta.append((idx, val.voting_power, cs.for_block()))
-            key_types.append(val.pub_key.type_name())
-        msgs = commit.vote_sign_bytes_many(chain_id, idxs)
-        mask = verify_batch(pubkeys, msgs, sigs, key_types=key_types)
-        tallied = 0
-        for ok, (idx, power, for_block) in zip(mask, meta):
-            if not ok:
-                raise CommitVerifyError(f"wrong signature (#{idx})")
-            if for_block:
-                tallied += power
-        needed = self.total_voting_power() * 2 // 3
-        if tallied <= needed:
-            raise NotEnoughVotingPowerError(tallied, needed)
 
     def begin_verify_commit_light(self, chain_id: str, block_id, height: int, commit):
         """Submit-phase of verify_commit_light: structural checks + device
         submit; returns a finish() callable that syncs, tallies, and raises
         on failure. Lets callers overlap several independent commit
         verifications' device round trips (light/verifier.py pipelines the
-        trusting+light pair this way)."""
-        from tendermint_tpu.crypto.batch import verify_batch_finish, verify_batch_submit
+        trusting+light pair this way).
 
-        if self.size() != len(commit.signatures):
-            raise CommitVerifyError(
-                f"invalid commit -- wrong set size: {self.size()} vs {len(commit.signatures)}"
-            )
-        if height != commit.height:
-            raise CommitVerifyError(f"invalid commit -- wrong height: {height} vs {commit.height}")
-        if block_id != commit.block_id:
-            raise CommitVerifyError(
-                f"invalid commit -- wrong block ID: want {block_id}, got {commit.block_id}"
-            )
-        pubkeys, sigs, powers, idxs = [], [], [], []
-        key_types = []
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val = self.validators[idx]
-            pubkeys.append(val.pub_key.bytes())
-            idxs.append(idx)
-            sigs.append(cs.signature)
-            powers.append(val.voting_power)
-            key_types.append(val.pub_key.type_name())
-        msgs = commit.vote_sign_bytes_many(chain_id, idxs)
-        handle = verify_batch_submit(pubkeys, msgs, sigs, key_types=key_types)
+        Spans: the submit phase is the call's `commit.verify` root (verdict
+        `submitted`); finish() opens `commit.finish` under it by explicit
+        parent (the verdict is finish()'s to give), since other calls'
+        submits may lie between the two."""
+        from tendermint_tpu.crypto.batch import verify_batch_submit
+
+        with _commit_span("verify_commit_light", commit, height, "submitted") as root:
+            self._check_commit_for(block_id, height, commit)
+            pubkeys, sigs, powers, idxs = [], [], [], []
+            key_types = []
+            with _trace.span("commit.gather") as sp:
+                for idx, cs in enumerate(commit.signatures):
+                    if not cs.for_block():
+                        continue
+                    val = self.validators[idx]
+                    pubkeys.append(val.pub_key.bytes())
+                    idxs.append(idx)
+                    sigs.append(cs.signature)
+                    powers.append(val.voting_power)
+                    key_types.append(val.pub_key.type_name())
+                sp.set(rows=len(idxs))
+            root.set(rows=len(idxs))
+            msgs = _sign_bytes_spanned(commit, chain_id, idxs)
+            handle = verify_batch_submit(pubkeys, msgs, sigs, key_types=key_types)
+        needed = self.total_voting_power() * 2 // 3
+        return self._light_finish(root, handle, powers, needed)
+
+    @staticmethod
+    def _light_finish(root, handle, powers, needed: int):
+        """finish() of the two light entries: sync, tally, raise."""
+        from tendermint_tpu.crypto.batch import verify_batch_finish
+
+        entry = root.attrs["entry"] if root.recording else ""
 
         def finish() -> None:
-            mask = verify_batch_finish(handle)
-            tallied = sum(p for ok, p in zip(mask, powers) if ok)
-            needed = self.total_voting_power() * 2 // 3
-            if tallied <= needed:
-                raise NotEnoughVotingPowerError(tallied, needed)
+            with _trace.span("commit.finish", parent=root, entry=entry) as fin:
+                mask = verify_batch_finish(handle)
+                with _trace.span("commit.tally"):
+                    tallied = sum(p for ok, p in zip(mask, powers) if ok)
+                    if tallied <= needed:
+                        fin.set(verdict="NotEnoughVotingPowerError")
+                        raise NotEnoughVotingPowerError(tallied, needed)
+                fin.set(verdict="accepted")
 
         return finish
 
@@ -407,41 +455,40 @@ class ValidatorSet:
     ):
         """Submit-phase of verify_commit_light_trusting; see
         begin_verify_commit_light."""
-        from tendermint_tpu.crypto.batch import verify_batch_finish, verify_batch_submit
+        from tendermint_tpu.crypto.batch import verify_batch_submit
 
         if trust_level.denominator == 0:
             raise CommitVerifyError("trustLevel has zero Denominator")
         total_mul = self.total_voting_power() * trust_level.numerator
         needed = total_mul // trust_level.denominator
-        seen: Dict[int, int] = {}
-        pubkeys, sigs, powers, idxs = [], [], [], []
-        key_types = []
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val_idx, val = self.get_by_address(cs.validator_address)
-            if val is None:
-                continue
-            if val_idx in seen:
-                raise CommitVerifyError(
-                    f"double vote from {val.address.hex()} ({seen[val_idx]} and {idx})"
-                )
-            seen[val_idx] = idx
-            pubkeys.append(val.pub_key.bytes())
-            idxs.append(idx)
-            sigs.append(cs.signature)
-            powers.append(val.voting_power)
-            key_types.append(val.pub_key.type_name())
-        msgs = commit.vote_sign_bytes_many(chain_id, idxs)
-        handle = verify_batch_submit(pubkeys, msgs, sigs, key_types=key_types)
-
-        def finish() -> None:
-            mask = verify_batch_finish(handle)
-            tallied = sum(p for ok, p in zip(mask, powers) if ok)
-            if tallied <= needed:
-                raise NotEnoughVotingPowerError(tallied, needed)
-
-        return finish
+        with _commit_span(
+            "verify_commit_light_trusting", commit, commit.height, "submitted"
+        ) as root:
+            seen: Dict[int, int] = {}
+            pubkeys, sigs, powers, idxs = [], [], [], []
+            key_types = []
+            with _trace.span("commit.gather") as sp:
+                for idx, cs in enumerate(commit.signatures):
+                    if not cs.for_block():
+                        continue
+                    val_idx, val = self.get_by_address(cs.validator_address)
+                    if val is None:
+                        continue
+                    if val_idx in seen:
+                        raise CommitVerifyError(
+                            f"double vote from {val.address.hex()} ({seen[val_idx]} and {idx})"
+                        )
+                    seen[val_idx] = idx
+                    pubkeys.append(val.pub_key.bytes())
+                    idxs.append(idx)
+                    sigs.append(cs.signature)
+                    powers.append(val.voting_power)
+                    key_types.append(val.pub_key.type_name())
+                sp.set(rows=len(idxs))
+            root.set(rows=len(idxs))
+            msgs = _sign_bytes_spanned(commit, chain_id, idxs)
+            handle = verify_batch_submit(pubkeys, msgs, sigs, key_types=key_types)
+        return self._light_finish(root, handle, powers, needed)
 
     def verify_commit_light_trusting(
         self, chain_id: str, commit, trust_level: Fraction

@@ -167,3 +167,120 @@ def test_profiler_actions_counted():
     text = M.global_registry().expose()
     # the round-trips above incremented start/stop at least once each
     assert "tendermint_profiler_actions_total" in text
+
+
+def _ev(plane, thread, name, ts, dur, scope=""):
+    return {"name": name, "scope": scope, "ts_us": float(ts), "dur_us": float(dur),
+            "pid": plane, "tid": thread, "plane": plane, "thread": thread}
+
+
+def test_idle_gaps_go_to_the_innermost_tm_span():
+    """Each idle gap of the device plane is put down to the innermost tm:
+    span open on the caller's thread at that time; a gap that outlasts a
+    span is split where the span ends; the worker's spans do not enter."""
+    host, dev = "/host:CPU", "/device:TPU:0"
+    events = [
+        _ev(host, "main", "tm:commit.verify", 0, 1000),
+        _ev(host, "main", "tm:commit.gather", 0, 300),
+        _ev(host, "main", "tm:verify_batch", 400, 500),
+        _ev(host, "main", "tm:flush.prep_wait", 400, 100),
+        _ev(host, "main", "tm:flush.sync", 600, 250),
+        _ev(host, "flush-prep_0", "tm:prep.chunk", 400, 90),
+        _ev(host, "main", "bench:call", 0, 1100),
+        _ev(dev, "XLA Ops", "%fusion.1 = ...", 550, 100),
+        _ev(dev, "XLA Ops", "%msm_uptree.3 = ...", 650, 150),
+        _ev(dev, "XLA Modules", "jit_call(1)", 550, 250),
+        _ev(dev, "Steps", "0", 0, 2000),
+    ]
+    got = profile_report.idle_by_span(events)
+    assert got["calls"] == 1
+    assert got["window_ms"] == 1.0 and got["busy_ms"] == 0.25 and got["idle_ms"] == 0.75
+    rows = {r["span"]: r for r in got["rows"]}
+    assert rows["commit.gather"]["idle_ms"] == 0.3
+    assert rows["commit.verify"]["idle_ms"] == 0.2  # 300-400 and 900-1000
+    assert rows["commit.verify"]["gaps"] == 2
+    assert rows["flush.prep_wait"]["idle_ms"] == 0.1
+    assert rows["verify_batch"]["idle_ms"] == 0.1  # 500-550 and 850-900
+    assert rows["flush.sync"]["idle_ms"] == 0.05  # 800-850, after the last op
+    assert "prep.chunk" not in rows
+    assert sum(r["idle_ms"] for r in got["rows"]) == pytest.approx(got["idle_ms"])
+    assert got["rows"][0]["span"] == "commit.gather"
+    md = profile_report.render_markdown(
+        {**profile_report.analyze(events), "idle_by_span": got, "capture": []})
+    assert "| `flush.prep_wait` | 0.100 |" in md
+    # a capture without tm: spans (an old program) or without a device: no table
+    assert profile_report.idle_by_span([e for e in events if "tm:" not in e["name"]]) == {}
+    assert profile_report.idle_by_span([e for e in events if e["plane"] == host]) == {}
+
+
+def test_classify_reads_the_named_scopes_and_kernel_names():
+    c = profile_report.classify
+    assert c("%msm_bucket_fold.46 = s32[4,20,8,128] custom-call(...)") == "bucket_fold"
+    assert c("%msm_fenwick_reduce.2 = ...") == "fenwick_reduce"
+    assert c("%msm_uptree.1 = ...") == "uptree"
+    assert c("%fe_padd.7 = ...") == "field_kernels"
+    # an XLA operation is named by its scope path, not by its HLO name
+    scope = "jit(call)/call_exported/jit(_rlc_partial_core)/%s/gather:"
+    assert c("%reshape.38 = ...", scope % "fenwick_gather") == "fenwick_gather"
+    assert c("%copy.598 = ...", scope % "row_gather") == "row_gather"
+    assert c("%fusion.1 = ...", scope % "top_tree") == "top_tree"
+    assert c("%fe_padd.9 = ...", scope % "bucket_fold/fe_padd") == "bucket_fold"
+    assert c("%fe_fsq.3 = ...", scope % "decompress/jit(pow_p58)/fe_fsq") == "decompress"
+    assert c("%fusion.9 = ...", scope % "window_combine") == "window_combine"
+    assert c("%fusion.2 = ...", scope % "identity_check") == "identity_check"
+    assert c("%fusion.5 = ...", "") == "other"
+
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+
+
+@pytest.mark.parametrize("cell", ["commit-10k", "commit-1024"])
+def test_recorded_chip_slice_gives_its_tables(cell):
+    """A slice of each benchmark cell kept on the chip (run.py --keep-trace,
+    PR 26): the program's spans sit on the host plane as tm: events inside
+    the benchmark's bench:call spans, the Pallas kernels carry their names,
+    and the report gives the stage table and the idle-gap table stored
+    beside the slice. benchmark/tracing.py's reduction of the same slice is
+    what the run itself printed: the tm: events do not move it."""
+    import json
+    import sys
+
+    path = os.path.join(DATA, cell + ".slice.xplane.pb.gz")
+    with open(os.path.join(DATA, cell + ".slice.expect.json")) as f:
+        want = json.load(f)
+    events = profile_report.load_events(path)
+    tm = [e for e in events if e["name"].startswith("tm:")]
+    calls = [e for e in events if e["name"] == "bench:call"]
+    roots = [e for e in tm if e["name"] == "tm:commit.verify"]
+    assert len(roots) == len(calls) == 8
+    for r, c in zip(sorted(roots, key=lambda e: e["ts_us"]),
+                    sorted(calls, key=lambda e: e["ts_us"])):
+        assert c["ts_us"] <= r["ts_us"]
+        assert r["ts_us"] + r["dur_us"] <= c["ts_us"] + c["dur_us"]
+    for name in ("commit.gather", "commit.sign_bytes", "verify_batch", "flush.record",
+                 "commit.tally", "dispatch", "flush.sync", "flush.prep_wait",
+                 "prep.hash", "prep.scalars", "prep.sort"):
+        assert any(e["name"] == "tm:" + name for e in tm), name
+    assert len({e["tid"] for e in tm}) == 2  # the caller's thread and the prep worker's
+    assert len(tm) / 8 == want["tm_spans_per_call"] <= (39 if cell == "commit-10k" else 29)
+    names = " ".join(e["name"][:40] for e in events if e["plane"].startswith("/device:"))
+    assert "_unknown_" not in names
+    for kernel in ("%msm_uptree", "%msm_fenwick_reduce", "%msm_bucket_fold", "%fe_fsq"):
+        assert kernel in names, kernel
+    rep = profile_report.report(path, top=5)
+    assert json.loads(json.dumps(rep["device_stages"])) == want["device_stages"]
+    assert json.loads(json.dumps(rep["idle_by_span"])) == want["idle_by_span"]
+    assert rep["idle_by_span"]["rows"][0]["span"] == "commit.sign_bytes"
+    assert "| `commit.sign_bytes` |" in profile_report.render_markdown(rep)
+
+    bench = os.path.join(os.path.dirname(DATA), "..", "benchmark")
+    sys.path.insert(0, os.path.abspath(bench))
+    try:
+        import tracing
+    finally:
+        sys.path.pop(0)
+    devices, spans = tracing.load_xplane(path)
+    assert {n for _, _, n in spans} == {"slice", "call", "flush"}  # no tm: name gets in
+    got = tracing.reduce(devices, spans)
+    for k, v in want["benchmark_reduction"].items():
+        assert json.loads(json.dumps(got[k])) == v, k

@@ -300,11 +300,18 @@ def test_debug_trace_and_verify_stats_routes(tmp_path):
                 assert flush["attrs"]["path"] == "cpu"
                 assert flush["attrs"]["backend"] == "cpu"
                 assert "dur_ms" in flush and "span" in flush
-                # its flush event is parented under it (span tree)
+                # its flush event sits under it (span tree), inside the
+                # record's own span; all three share the request's root
                 children = [
                     e for e in body["events"] if e.get("parent") == flush["span"]
                 ]
-                assert any(e["name"] == "batch_verify.flush" for e in children)
+                record = next(e for e in children if e["name"] == "flush.record")
+                assert any(
+                    e["name"] == "batch_verify.flush"
+                    and e["parent"] == record["span"]
+                    and e["root"] == flush["root"]
+                    for e in body["events"]
+                )
 
                 # ?limit=N truncates to the newest N
                 async with sess.get(

@@ -135,16 +135,92 @@ class _DisabledSentinel:
 
 
 def test_batch_path_zero_tracer_calls_when_disabled(monkeypatch):
-    """The overhead contract: with tracing off, a verify_batch flush touches
-    the tracer exactly once (the hoisted flag read) and never calls it."""
+    """The overhead contract: with tracing off, a verify_batch flush only
+    ever READS the tracer's flag (once per span site on its path: the
+    verify_batch span, flush.record, and the flush event's resolution) and
+    never calls it: no span is constructed, nothing is recorded."""
     from tendermint_tpu.crypto import batch as B
 
     pubkeys, msgs, sigs = _make_cpu_batch(4)
     sentinel = _DisabledSentinel()
     monkeypatch.setattr(trace, "tracer", sentinel)
+    built = []
+    monkeypatch.setattr(
+        trace.Span, "__init__", lambda self, *a, **kw: built.append(a)
+    )
     mask = B.verify_batch(pubkeys, msgs, sigs, backend="cpu")
     assert mask.all()
-    assert sentinel.flag_reads == 1
+    assert 1 <= sentinel.flag_reads <= 3
+    assert built == []
+
+
+def test_span_off_is_the_shared_noop(monkeypatch):
+    """trace.span with the recorder off: the ONE shared no-op, no Span
+    constructed, ring untouched; trace.timed hands back a bare clock pair
+    that still times the block."""
+    t = Tracer(ring_size=8, enabled=False)
+    monkeypatch.setattr(trace, "tracer", t)
+    built = []
+    monkeypatch.setattr(
+        trace.Span, "__init__", lambda self, *a, **kw: built.append(a)
+    )
+    a = trace.span("x", k=1)
+    b = trace.span("y")
+    assert a is b is trace.NOOP and a.recording is False
+    with a as got:
+        assert got.set(z=2) is got
+    assert trace.current() is None
+    with trace.timed("stage", chunk=0) as st:
+        pass
+    assert isinstance(st, trace.Stopwatch) and st.seconds >= 0.0
+    assert st.interval()[1] >= st.interval()[0] > 0.0
+    assert built == [] and t.dump() == []
+
+
+def test_event_has_t0_root_and_starts_before_children():
+    t = Tracer(ring_size=16)
+    with t.span("outer"):
+        with t.span("inner"):
+            t.event("leaf")
+    t.event("lonely")
+    leaf, inner, outer, lonely = t.dump()
+    assert outer["root"] == inner["root"] == leaf["root"] == outer["span"]
+    assert lonely["root"] == lonely["span"] and lonely["parent"] is None
+    assert outer["t0_ns"] <= inner["t0_ns"] <= leaf["t0_ns"] <= lonely["t0_ns"]
+    # dur_ms and t0_ns are one clock: the child ends inside the parent
+    assert inner["t0_ns"] + inner["dur_ms"] * 1e6 <= (
+        outer["t0_ns"] + outer["dur_ms"] * 1e6 + 1e3
+    )
+    assert all(isinstance(e["ts"], float) for e in t.dump())
+
+
+def test_explicit_parent_nests_pool_thread_under_submitter():
+    from concurrent.futures import ThreadPoolExecutor
+
+    t = Tracer(ring_size=16)
+
+    def work(parent):
+        with t.span("prep.chunk", parent=parent, chunk=0) as sp:
+            with t.span("prep.hash"):
+                pass
+        return sp.interval()
+
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        with t.span("commit.verify"):
+            with t.span("rlc.pipelined") as flush:
+                lo, hi = pool.submit(work, t.current()).result()
+    by = {e["name"]: e for e in t.dump()}
+    root = by["commit.verify"]["span"]
+    assert by["prep.chunk"]["parent"] == by["rlc.pipelined"]["span"] == flush.span_id
+    assert by["prep.hash"]["parent"] == by["prep.chunk"]["span"]
+    assert {e["root"] for e in t.dump()} == {root}
+    assert hi >= lo and (hi - lo) * 1e3 == pytest.approx(
+        by["prep.chunk"]["dur_ms"], abs=1e-3
+    )
+    # a span handed a closed parent (finish() of a light entry) still shares its root
+    with t.span("commit.finish", parent=flush):
+        pass
+    assert t.dump()[-1]["root"] == root
 
 
 def test_batch_path_emits_span_and_flush_event_when_enabled(monkeypatch):
@@ -155,14 +231,18 @@ def test_batch_path_emits_span_and_flush_event_when_enabled(monkeypatch):
     monkeypatch.setattr(trace, "tracer", t)
     mask = B.verify_batch(pubkeys, msgs, sigs, backend="cpu")
     assert mask.all()
-    names = [e["name"] for e in t.dump()]
-    assert "verify_batch" in names and "batch_verify.flush" in names
-    span = next(e for e in t.dump() if e["name"] == "verify_batch")
+    by = {e["name"]: e for e in t.dump()}
+    assert {"verify_batch", "flush.record", "batch_verify.flush"} <= set(by)
+    span = by["verify_batch"]
     assert span["attrs"]["n"] == 5
     assert span["attrs"]["path"] == "cpu"
-    flush = next(e for e in t.dump() if e["name"] == "batch_verify.flush")
-    # the flush event is parented INSIDE the verify_batch span (span tree)
-    assert flush["parent"] == span["span"] or flush["parent"] is None
+    # the flush event sits INSIDE the verify_batch span (span tree), under
+    # the record's own span, which starts after the flush's total closed
+    assert by["batch_verify.flush"]["parent"] == by["flush.record"]["span"]
+    assert by["flush.record"]["parent"] == span["span"]
+    assert by["batch_verify.flush"]["root"] == span["span"]
+    total_ms = by["batch_verify.flush"]["attrs"]["total_ms"]
+    assert span["t0_ns"] + total_ms * 1e6 <= by["flush.record"]["t0_ns"] + 1e3
 
 
 def test_record_flush_aggregates_stats():
@@ -219,3 +299,241 @@ def test_flush_detail_reports_bucket_and_padding():
     B.prepare_batch(pks, [b"m"] * 5, sigs)
     assert B.LAST_FLUSH_DETAIL["jit_bucket"] == 8
     assert B.LAST_FLUSH_DETAIL["padding_lanes"] == 3
+
+
+# ---------------------------------------------------------------------------
+# One span tree per verify_commit (ISSUE 26): the entry's spans, the prep
+# worker's under an explicit parent, the flush record from the spans' own
+# intervals, the tm: mirror on the profiler's clock.
+
+from test_flush_planner import _install_host_twins  # noqa: E402
+from test_prep_pipeline import needs_native, prep_cfg, small_rlc  # noqa: E402,F401
+
+CHAIN = "trace-chain"
+
+
+def _signed_commit(n, height=9, absent=()):
+    from tendermint_tpu.crypto import gen_ed25519
+    from tendermint_tpu.types.basic import BlockID, BlockIDFlag, PartSetHeader
+    from tendermint_tpu.types.block import Commit, CommitSig
+    from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+
+    rng = np.random.default_rng(26)
+    privs = [gen_ed25519(rng.integers(0, 256, 32, dtype=np.uint8).tobytes())
+             for _ in range(n)]
+    vals = ValidatorSet([Validator(p.pub_key(), 10) for p in privs])
+    by_addr = {p.pub_key().address(): p for p in privs}
+    bid = BlockID(b"\x07" * 32, PartSetHeader(1, b"\x08" * 32))
+    blank = [CommitSig(BlockIDFlag.COMMIT, v.address, 1_000 + i, b"\x00" * 64)
+             for i, v in enumerate(vals.validators)]
+    msgs = Commit(height, 0, bid, blank).vote_sign_bytes_many(CHAIN, range(n))
+    sigs = [
+        CommitSig.absent_sig() if i in absent
+        else CommitSig(BlockIDFlag.COMMIT, v.address, 1_000 + i,
+                       by_addr[v.address].sign(msgs[i]))
+        for i, v in enumerate(vals.validators)
+    ]
+    return vals, bid, Commit(height, 0, bid, sigs)
+
+
+def _device_route(monkeypatch):
+    """verify_commit's verify_batch takes the device route (host twins)."""
+    from tendermint_tpu.crypto import batch as B
+
+    _install_host_twins(monkeypatch)
+    monkeypatch.delenv("TMTPU_CRYPTO_BACKEND", raising=False)
+    monkeypatch.setattr(B, "_JAX_MIN_BATCH", 8)
+
+
+def _tree(events, root_name="commit.verify"):
+    root = next(e for e in events if e["name"] == root_name)
+    mine = [e for e in events if e["root"] == root["span"]]
+    children = sorted(e["name"] for e in mine if e["parent"] == root["span"])
+    return root, mine, children
+
+
+# events a call may leave in the ring (ISSUE 26: 40 at 10k on the 2-chunk
+# pipelined path, 30 at 1,024 on the staged single flush). Rows do not
+# enter: a span per row would pass both at once.
+BUDGET = {"rlc-pipelined": 40, "rlc": 30, "cpu": 30}
+
+
+@needs_native
+@pytest.mark.parametrize("path,n", [("rlc-pipelined", 24), ("rlc", 12), ("cpu", 6)])
+def test_verify_commit_span_tree_and_budget(small_rlc, prep_cfg, monkeypatch, path, n):
+    """One verify_commit = one tree: commit.verify with exactly gather,
+    sign_bytes, verify_batch, tally under it, every event of the call under
+    its root (the prep worker's too), and no more events than the budget."""
+    if path != "cpu":
+        _device_route(monkeypatch)
+    prep_cfg["staged"] = True
+    prep_cfg["stream"] = path == "rlc-pipelined"
+    prep_cfg["stream_floor"] = 16
+    vals, bid, commit = _signed_commit(n)
+    t = Tracer(ring_size=256)
+    monkeypatch.setattr(trace, "tracer", t)
+    vals.verify_commit(CHAIN, bid, commit.height, commit)
+    events = t.dump()
+    root, mine, children = _tree(events)
+    assert children == ["commit.gather", "commit.sign_bytes", "commit.tally", "verify_batch"]
+    assert len(mine) == len(events) <= BUDGET[path]
+    assert root["attrs"] == {"entry": "verify_commit", "rows": n, "height": 9,
+                             "verdict": "accepted"}
+    assert events[-1] is root or events[-1]["span"] == root["span"]  # written last
+    by = {}
+    for e in mine:
+        by.setdefault(e["name"], []).append(e)
+    assert by["commit.sign_bytes"][0]["attrs"]["bytes"] > 64 * n
+    assert by["verify_batch"][0]["attrs"]["path"] == path
+    assert by["flush.record"][0]["parent"] == by["verify_batch"][0]["span"]
+    for e in mine:  # a child starts inside its root
+        assert e["t0_ns"] >= root["t0_ns"]
+    if path == "rlc-pipelined":
+        flush = by["rlc.pipelined"][0]["span"]
+        assert [e["attrs"]["chunk"] for e in by["prep.chunk"]] == [0, 1]
+        assert all(e["parent"] == flush for e in by["prep.chunk"])
+        chunk_ids = {e["span"] for e in by["prep.chunk"]}
+        for name in ("prep.hash", "prep.scalars", "prep.sort"):
+            assert len(by[name]) == 2 and {e["parent"] for e in by[name]} <= chunk_ids
+        assert len(by["flush.prep_wait"]) == 2
+        assert [e["attrs"] for e in by["flush.sync"]] == [
+            {"chunk": 0}, {"chunk": 1}, {"what": "identity"}]
+    elif path == "rlc":
+        sub = by["rlc.submit"][0]["span"]
+        for name in ("prep.precheck", "prep.hash", "flush.prep_wait", "prep.scalars",
+                     "prep.sort"):
+            assert [e["parent"] for e in by[name]] == [sub], name
+        assert by["flush.sync"][0]["parent"] == by["rlc.finish"][0]["span"]
+
+
+def test_verify_commit_refusal_names_its_verdict(monkeypatch):
+    vals, bid, commit = _signed_commit(6, absent=(0, 1, 2))
+    t = Tracer(ring_size=64)
+    monkeypatch.setattr(trace, "tracer", t)
+    from tendermint_tpu.types.validator_set import NotEnoughVotingPowerError
+
+    with pytest.raises(NotEnoughVotingPowerError):
+        vals.verify_commit(CHAIN, bid, commit.height, commit)
+    root, mine, children = _tree(t.dump())
+    assert root["attrs"]["verdict"] == "NotEnoughVotingPowerError"
+    assert root["attrs"]["rows"] == 3 and "commit.tally" in children
+
+
+def test_light_entries_carry_the_commit_spans(monkeypatch):
+    """begin/finish: the submit phase is the root (`submitted`), finish()
+    nests under it by explicit parent although the root has closed."""
+    from fractions import Fraction
+
+    vals, bid, commit = _signed_commit(6)
+    t = Tracer(ring_size=64)
+    monkeypatch.setattr(trace, "tracer", t)
+    f1 = vals.begin_verify_commit_light(CHAIN, bid, commit.height, commit)
+    f2 = vals.begin_verify_commit_light_trusting(CHAIN, commit, Fraction(1, 3))
+    f1()
+    f2()
+    roots = [e for e in t.dump() if e["name"] == "commit.verify"]
+    assert [r["attrs"]["entry"] for r in roots] == [
+        "verify_commit_light", "verify_commit_light_trusting"]
+    for r in roots:
+        assert r["attrs"]["verdict"] == "submitted"
+        mine = [e for e in t.dump() if e["root"] == r["span"]]
+        names = {e["name"] for e in mine}
+        assert {"commit.gather", "commit.sign_bytes", "commit.finish", "commit.tally"} <= names
+        fin = next(e for e in mine if e["name"] == "commit.finish")
+        assert fin["parent"] == r["span"] and fin["attrs"]["verdict"] == "accepted"
+        assert fin["attrs"]["entry"] == r["attrs"]["entry"]
+
+
+@needs_native
+@pytest.mark.parametrize("path,n", [("rlc-pipelined", 24), ("rlc", 12)])
+def test_flush_record_equals_the_spans(small_rlc, prep_cfg, monkeypatch, path, n):
+    """One timing, two consumers: the flush record's prep_ms, prep_stages_ms
+    and transfer_ms ARE the spans' durations."""
+    from tendermint_tpu.crypto import batch as B
+
+    _device_route(monkeypatch)
+    prep_cfg["staged"] = True
+    prep_cfg["stream"] = path == "rlc-pipelined"
+    prep_cfg["stream_floor"] = 16
+    vals, bid, commit = _signed_commit(n)
+    t = Tracer(ring_size=256)
+    monkeypatch.setattr(trace, "tracer", t)
+    vals.verify_commit(CHAIN, bid, commit.height, commit)
+    by = {}
+    for e in t.dump():
+        by.setdefault(e["name"], []).append(e)
+    rec = by["batch_verify.flush"][0]["attrs"]
+    assert rec["path"] == path
+
+    def total(name):
+        return sum(e["dur_ms"] for e in by[name])
+
+    tol = dict(abs=2e-3)  # both are rounded to 0.1 us, stage by stage
+    whole = "prep.chunk" if path == "rlc-pipelined" else "rlc.submit"
+    assert rec["prep_ms"] == pytest.approx(total(whole), **tol)
+    for stage in ("hash", "scalars", "sort"):
+        assert rec["prep_stages_ms"][stage] == pytest.approx(total("prep." + stage), **tol)
+    if path == "rlc":
+        assert rec["prep_stages_ms"]["precheck"] == pytest.approx(
+            total("prep.precheck"), **tol)
+    assert rec["transfer_ms"] == pytest.approx(by["flush.sync"][-1]["dur_ms"], **tol)
+    # the record is not inside its own total
+    vb = by["verify_batch"][0]
+    assert vb["t0_ns"] + rec["total_ms"] * 1e6 <= by["flush.record"][0]["t0_ns"] + 1e3
+
+
+def test_flush_record_unchanged_with_recorder_off(small_rlc, prep_cfg, monkeypatch):
+    """Recorder off: the bare clock pairs still fill the flush record, and
+    no Span is constructed anywhere on the device route."""
+    _device_route(monkeypatch)
+    prep_cfg["stream"] = True
+    prep_cfg["stream_floor"] = 16
+    vals, bid, commit = _signed_commit(24)
+    t = Tracer(ring_size=64, enabled=False)
+    monkeypatch.setattr(trace, "tracer", t)
+    built = []
+    monkeypatch.setattr(trace.Span, "__init__", lambda self, *a, **kw: built.append(a))
+    trace.reset_stats()
+    vals.verify_commit(CHAIN, bid, commit.height, commit)
+    last = trace.verify_stats()["last_flush"]
+    assert last["path"] == "rlc-pipelined" and last["chunks"] == 2
+    assert last["prep_ms"] > 0 and last["transfer_ms"] >= 0 and last["total_ms"] > 0
+    assert set(last["prep_stages_ms"]) >= {"hash", "scalars", "sort"}
+    assert built == [] and t.dump() == []
+
+
+def test_tm_mirror_on_the_profilers_host_plane(tmp_path, monkeypatch):
+    """Under a jax.profiler session a verify_commit leaves tm:commit.verify
+    and its children on a host plane of the .xplane.pb, on the profiler's
+    clock; outside a session nothing is mirrored."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    vals, bid, commit = _signed_commit(6)
+    t = Tracer(ring_size=64)
+    monkeypatch.setattr(trace, "tracer", t)
+    assert trace._live_annotation() is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert trace._live_annotation() is not None
+        vals.verify_commit(CHAIN, bid, commit.height, commit)
+    finally:
+        jax.profiler.stop_trace()
+    assert trace._live_annotation() is None
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("tm:"):
+                    seen[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+    assert {"tm:commit.verify", "tm:commit.gather", "tm:commit.sign_bytes",
+            "tm:verify_batch", "tm:flush.record", "tm:commit.tally"} <= set(seen)
+    lo, hi = seen["tm:commit.verify"]
+    for name, (s, e) in seen.items():
+        assert lo <= s and e <= hi, name
+    assert not any(n.startswith("bench:") for n in seen)
