@@ -127,6 +127,11 @@ def _build() -> "ctypes.CDLL | None":
     lib.tm_ed25519_h_batch.argtypes = [u8p, u8p, u8p, i64p, ctypes.c_int64, u8p, ctypes.c_int]
     lib.tm_rlc_scalars.argtypes = [u8p, u8p, u8p, ctypes.c_int64, u8p, u8p, ctypes.c_int]
     lib.tm_sort_windows.argtypes = [u8p, ctypes.c_int64, i32p, i32p, ctypes.c_int, ctypes.c_int64]
+    lib.tm_vote_sign_bytes.argtypes = [
+        u8p, ctypes.c_int64, u8p, i64p, ctypes.c_int64, i32p, i64p,
+        u8p, ctypes.c_int64, ctypes.c_int64, u8p, i64p,
+    ]
+    lib.tm_vote_sign_bytes.restype = ctypes.c_int64
     lib.tm_sr25519_verify_one.argtypes = [u8p, u8p, ctypes.c_int64, u8p]
     lib.tm_sr25519_verify_one.restype = ctypes.c_int
     lib.tm_sr25519_verify_batch.argtypes = [u8p, u8p, i64p, u8p, ctypes.c_int64, u8p, ctypes.c_int]
@@ -191,6 +196,46 @@ def ed25519_h_batch(
         n, _u8p(out), _NTHREADS,
     )
     return out
+
+
+def vote_sign_bytes(
+    prefix: bytes, parts: list, sel: np.ndarray, ts_ns: np.ndarray, suffix: bytes
+):
+    """n length-delimited CanonicalVote rows in one pass: row i is
+    len || prefix || parts[sel[i]] || timestamp(ts_ns[i]) || suffix
+    (types/canonical.vote_sign_bytes_many states the encoding).
+
+    parts: encoded block-id parts (tag 4 + length + body, or b"" for nil);
+    sel (n,) int32 indices into it; ts_ns (n,) int64. Returns (blob, offs):
+    row i is blob[offs[i]:offs[i+1]], offs (n+1,) int64. The buffer holds n
+    rows of the longest possible length, so the loop cannot overrun it."""
+    lib = _lib()
+    assert lib is not None
+    n = len(ts_ns)
+    sel = np.ascontiguousarray(sel, dtype=np.int32)
+    ts_ns = np.ascontiguousarray(ts_ns, dtype=np.int64)
+    if sel.shape != (n,) or ts_ns.shape != (n,):
+        raise ValueError("sel and ts_ns must be one column of n rows each")
+    part_offs = np.cumsum([0] + [len(p) for p in parts], dtype=np.int64)
+    parts_arr = np.frombuffer(b"".join(parts) or b"\0", dtype=np.uint8)
+    # body: prefix, part, tag 5 + length, timestamp (<= 17), suffix; the
+    # outer length varint of a 63-bit size is <= 9 bytes
+    longest = len(prefix) + max(map(len, parts), default=0) + 2 + 17 + len(suffix)
+    out = np.empty(n * (longest + 9) or 1, dtype=np.uint8)
+    offs = np.empty(n + 1, dtype=np.int64)
+    pre = np.frombuffer(prefix or b"\0", dtype=np.uint8)
+    suf = np.frombuffer(suffix or b"\0", dtype=np.uint8)
+    i64p = ctypes.POINTER(ctypes.c_int64)
+    total = lib.tm_vote_sign_bytes(
+        _u8p(pre), len(prefix), _u8p(parts_arr), part_offs.ctypes.data_as(i64p),
+        len(parts), sel.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        ts_ns.ctypes.data_as(i64p), _u8p(suf), len(suffix), n, _u8p(out),
+        offs.ctypes.data_as(i64p),
+    )
+    if total < 0:
+        raise ValueError("sel indexes outside parts")
+    assert total <= out.size
+    return out[:total].tobytes(), offs
 
 
 def rlc_scalars(z16: np.ndarray, h32: np.ndarray, s32: np.ndarray):

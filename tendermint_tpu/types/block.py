@@ -326,18 +326,26 @@ class Commit:
         )
 
     def vote_sign_bytes_many(self, chain_id: str, val_idxs) -> list:
-        """Batched vote_sign_bytes over many signature indices — the O(N)
-        commit-verification paths build all their messages in one pass
-        (canonical.vote_sign_bytes_many; profiled ~10x the per-row builder)."""
-        return canonical.vote_sign_bytes_many(
+        """Batched vote_sign_bytes over many signature indices: the O(N)
+        commit-verification paths build all their messages in one pass."""
+        return self.vote_sign_bytes_built(chain_id, val_idxs)[0]
+
+    def vote_sign_bytes_built(self, chain_id: str, val_idxs) -> tuple:
+        """vote_sign_bytes_many and the builder that wrote the rows
+        (canonical.vote_sign_bytes_columns): a commit's rows differ in the
+        timestamp and in whether they sign the commit's block id or nil,
+        so those two columns are all that is read from the CommitSigs."""
+        sigs = self.signatures
+        rows = [sigs[i] for i in val_idxs]
+        for_block = BlockIDFlag.COMMIT
+        return canonical.vote_sign_bytes_columns(
             chain_id,
             SignedMsgType.PRECOMMIT,
             self.height,
             self.round,
-            (
-                (self.signatures[i].block_id(self.block_id), self.signatures[i].timestamp_ns)
-                for i in val_idxs
-            ),
+            (self.block_id, BlockID()),
+            [cs.block_id_flag != for_block for cs in rows],
+            [cs.timestamp_ns for cs in rows],
         )
 
     def hash(self) -> bytes:

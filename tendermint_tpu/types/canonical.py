@@ -12,6 +12,10 @@ proto/tendermint/types/canonical.pb.go MarshalToSizedBuffer):
 
 from __future__ import annotations
 
+import numpy as np
+
+from tendermint_tpu import native
+from tendermint_tpu.libs import hotstats
 from tendermint_tpu.libs import protowire as pw
 from tendermint_tpu.types.basic import BlockID, SignedMsgType, ts_seconds_nanos
 
@@ -89,6 +93,14 @@ def vote_sign_bytes(
     )
 
 
+# Below this many rows the Python loop is the cheaper builder: the native
+# call has a fixed cost (two column arrays, the part table, a dozen ctypes
+# arguments) that the loop's per-row cost only overtakes here (CHANGES.md,
+# PR 27, states the measurement). A live vote flush of a handful of rows
+# stays on the loop; a commit takes the native pass.
+NATIVE_MIN_ROWS = 20
+
+
 def vote_sign_bytes_many(
     chain_id: str,
     msg_type: SignedMsgType,
@@ -100,17 +112,66 @@ def vote_sign_bytes_many(
     round): `rows` is an iterable of (block_id, timestamp_ns).
 
     A vote storm / commit shares everything except the BlockID (a handful of
-    distinct values) and the timestamp, so the shared prefix (type, height,
-    round) and suffix (chain_id) are encoded ONCE and the per-row work is a
-    dict hit + one small timestamp encode + a join — ~10x the per-row
-    builder (profiled: sign-bytes construction was 72% of a deferred vote
-    flush). Byte-identical to vote_sign_bytes per row (differentially
-    tested)."""
-    from tendermint_tpu.libs import hotstats
+    distinct values, hundreds from Byzantine voters) and the timestamp, so
+    the rows become two columns, an index into the table of distinct block
+    ids and the timestamp, for the builders of vote_sign_bytes_columns.
+    Byte-identical to vote_sign_bytes per row (differentially tested)."""
+    t0 = _encode_clock()
+    block_ids: list = []
+    index: dict = {}
+    sel = []
+    ts_ns = []
+    for block_id, ts in rows:
+        bkey = None if block_id is None else block_id.key()
+        k = index.get(bkey)
+        if k is None:
+            k = index[bkey] = len(block_ids)
+            block_ids.append(block_id)
+        sel.append(k)
+        ts_ns.append(ts)
+    out, _ = _build_columns(chain_id, msg_type, height, round_, block_ids, sel, ts_ns)
+    _encode_account(t0, len(out))
+    return out
 
-    hs = hotstats.stats if hotstats.stats.enabled else None
-    if hs is not None:
-        t0 = hotstats.perf_counter()
+
+def vote_sign_bytes_columns(
+    chain_id: str,
+    msg_type: SignedMsgType,
+    height: int,
+    round_: int,
+    block_ids,
+    sel,
+    ts_ns,
+) -> tuple:
+    """vote_sign_bytes for row i = (block_ids[sel[i]], ts_ns[i]), everything
+    else shared -> (list of sign bytes, the builder that wrote them).
+
+    The shared prefix (type, height, round), the suffix (chain_id) and each
+    distinct block-id part are encoded ONCE. Builder "native": one C pass
+    (native.vote_sign_bytes) writes every row into one blob, sliced back
+    into bytes here. Builder "python": the same rows by a loop, where the
+    library is absent (or TMTPU_NATIVE=0), under NATIVE_MIN_ROWS rows, or
+    for a timestamp outside int64 (a hostile commit can decode to one).
+    Nothing is kept between calls: every call encodes every row."""
+    t0 = _encode_clock()
+    out, builder = _build_columns(
+        chain_id, msg_type, height, round_, block_ids, sel, ts_ns
+    )
+    _encode_account(t0, len(out))
+    return out, builder
+
+
+def _encode_clock():
+    """hotstats' `encode` stage: the start, or None while it is off."""
+    return hotstats.perf_counter() if hotstats.stats.enabled else None
+
+
+def _encode_account(t0, n: int) -> None:
+    if t0 is not None:
+        hotstats.stats.add("encode", hotstats.perf_counter() - t0, n=n)
+
+
+def _build_columns(chain_id, msg_type, height, round_, block_ids, sel, ts_ns) -> tuple:
     w = pw.Writer()
     w.varint_field(1, int(msg_type))
     w.sfixed64_field(2, height)
@@ -120,34 +181,42 @@ def vote_sign_bytes_many(
     sw.string_field(6, chain_id)
     suffix = sw.bytes()
     tag4 = pw.tag(4, pw.BYTES)
+    parts = []
+    for block_id in block_ids:
+        body = canonical_block_id_bytes(block_id)
+        parts.append(b"" if body is None else tag4 + pw.encode_varint(len(body)) + body)
+    if len(ts_ns) >= NATIVE_MIN_ROWS and native.available():
+        out = _native_rows(prefix, parts, sel, ts_ns, suffix)
+        if out is not None:
+            return out, "native"
+    return _python_rows(prefix, parts, sel, ts_ns, suffix), "python"
+
+
+def _native_rows(prefix, parts, sel, ts_ns, suffix) -> "list | None":
+    try:
+        ts_col = np.asarray(ts_ns, dtype=np.int64)
+    except OverflowError:  # a timestamp outside int64: the loop takes it
+        return None
+    blob, offs = native.vote_sign_bytes(prefix, parts, sel, ts_col, suffix)
+    offs = offs.tolist()
+    return [blob[a:b] for a, b in zip(offs, offs[1:])]
+
+
+def _python_rows(prefix, parts, sel, ts_ns, suffix) -> list:
     tag5 = pw.tag(5, pw.BYTES)
     enc = pw.encode_varint
-    bid_cache: dict = {}
-    ts_cache: dict = {}
     # Whole-row memo: a vote storm's rows mostly share (block_id, timestamp)
-    # entirely — a dict hit replaces even the final concat for those.
+    # entirely: a dict hit replaces the encode for those.
     row_cache: dict = {}
     out = []
-    for block_id, ts in rows:
-        bkey = None if block_id is None else block_id.key()
-        row = row_cache.get((bkey, ts))
+    for key in zip(sel, ts_ns):
+        row = row_cache.get(key)
         if row is None:
-            bid_part = bid_cache.get(bkey)
-            if bid_part is None:
-                body = canonical_block_id_bytes(block_id)
-                bid_part = b"" if body is None else tag4 + enc(len(body)) + body
-                bid_cache[bkey] = bid_part
-            ts_part = ts_cache.get(ts)
-            if ts_part is None:
-                tb = _timestamp_bytes(ts)
-                ts_part = tag5 + enc(len(tb)) + tb
-                ts_cache[ts] = ts_part
-            body = prefix + bid_part + ts_part + suffix
-            row = enc(len(body)) + body
-            row_cache[(bkey, ts)] = row
+            k, ts = key
+            tb = _timestamp_bytes(ts)
+            body = prefix + parts[k] + tag5 + enc(len(tb)) + tb + suffix
+            row = row_cache[key] = enc(len(body)) + body
         out.append(row)
-    if hs is not None:
-        hs.add("encode", hotstats.perf_counter() - t0, n=len(out))
     return out
 
 

@@ -240,3 +240,54 @@ def test_live_height_budgets(tmp_path, defer):
     # group commit: fsyncs scale with drains + self-generated messages, not
     # with peer votes (a per-vote-fsync regression would be ~n_votes here)
     assert d_fsync <= n_votes // 2, f"{d_fsync} fsyncs for {n_votes} votes ({d_writes} writes)"
+
+
+# ---------------------------------------------------------------------------
+# A commit's sign bytes (ISSUE 27): one native pass a call, every call, and
+# nothing remembered between calls.
+
+from test_prep_pipeline import needs_native, prep_cfg, small_rlc  # noqa: E402,F401
+from test_trace import CHAIN, _device_route, _signed_commit  # noqa: E402
+
+
+@needs_native
+def test_commit_sign_bytes_native_every_call_and_nothing_kept(small_rlc, prep_cfg, monkeypatch):
+    """Two verify_commit calls on the SAME Commit object (host twin of the
+    device path): each builds all n rows natively (a `commit.sign_bytes`
+    span with builder "native", one native call, zero per-row timestamp
+    encodes), and neither the commit, its CommitSigs nor the validator set
+    carries anything afterwards that was not there before: the benchmark's
+    ring re-presents 4 commits, and a builder that remembered them would
+    answer the second lap from memory."""
+    from tendermint_tpu import native
+    from tendermint_tpu.libs import protowire as pw
+    from tendermint_tpu.libs import trace
+    from tendermint_tpu.types import canonical
+
+    n = canonical.NATIVE_MIN_ROWS + 4
+    _device_route(monkeypatch)
+    prep_cfg["staged"] = True
+    vals, bid, commit = _signed_commit(n)
+    held = [dict(vars(commit)), [dict(vars(cs)) for cs in commit.signatures], set(vars(vals))]
+    t = trace.Tracer(ring_size=256)
+    monkeypatch.setattr(trace, "tracer", t)
+    ts_encodes, native_rows = [], []
+    real_ts, real_native = pw.encode_timestamp, native.vote_sign_bytes
+    monkeypatch.setattr(pw, "encode_timestamp", lambda *a: ts_encodes.append(a) or real_ts(*a))
+    monkeypatch.setattr(
+        native, "vote_sign_bytes", lambda *a: native_rows.append(len(a[3])) or real_native(*a))
+    for _ in range(2):
+        t.clear()
+        del ts_encodes[:], native_rows[:]
+        vals.verify_commit(CHAIN, bid, commit.height, commit)
+        [sp] = [e for e in t.dump() if e["name"] == "commit.sign_bytes"]
+        assert sp["attrs"]["builder"] == "native" and sp["attrs"]["rows"] == n
+        assert sp["attrs"]["bytes"] > 64 * n
+        assert native_rows == [n]
+        assert ts_encodes == []
+    assert dict(vars(commit)) == held[0]
+    assert [dict(vars(cs)) for cs in commit.signatures] == held[1]
+    assert set(vars(vals)) == held[2]
+    # and the builder's module holds no container a cache could live in
+    assert not [k for k, v in vars(canonical).items()
+                if isinstance(v, (dict, list, set)) and not k.startswith("__")]
