@@ -98,3 +98,14 @@ def commit_verdict(mask, present, powers, total_power: int) -> str:
     if tallied <= total_power * 2 // 3:
         return "not enough voting power"
     return "accepted"
+
+
+def verdict(mask, signers, powers, total_power, blocks) -> str:
+    """VerifyCommit in the form of a verdict rule (references/<name>.py): what
+    a configuration that names no `verdict_rule` is held to. `mask` and
+    `signers` are the item's rows in block order, `blocks` a block's height
+    and row count each; VerifyCommit speaks of one commit."""
+    if len(blocks) != 1:
+        raise ValueError(f"VerifyCommit judges one commit, the item holds {len(blocks)}: "
+                         "the configuration has to name its verdict_rule")
+    return commit_verdict(mask, signers, powers, total_power)

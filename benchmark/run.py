@@ -7,7 +7,9 @@ One process, no child. It exits non-zero, with no result line, when JAX
 picks no TPU or another number of chips than the cell asks for. It builds
 the cell's data from --seed, warms the cell's own shapes (set-up), drives
 the entry for --seconds, then holds what the timed path produced against
-the plain reference and prints the result object as its last line.
+the plain reference and prints the result object as its last line. One
+call verifies one item of the ring: a commit, or a run of as many commits
+as the mix states, judged by the verdict rule the configuration names.
 
 Harness-only flags: --rehearse N runs N validators on whatever JAX has (the
 sandbox's CPU, the program's host backend) to walk the control flow; its
@@ -25,6 +27,7 @@ T0 = time.perf_counter()  # set-up is counted from here: the process's start
 
 import argparse
 import contextlib
+import functools
 import gc
 import gzip
 import json
@@ -46,6 +49,14 @@ import spec  # noqa: E402
 import stats  # noqa: E402
 import tracing  # noqa: E402
 import work  # noqa: E402
+
+
+# What run.py itself reads of an entry driver's flush reading (README.md has
+# the whole protocol): the spans whose medians every result line carries under
+# `spans_p50`, and what it shows of the last call's reading under `flush`.
+# Besides these: `rows_valid`, `compile_ms`, `path` and `jax_path`.
+READING_SPANS = ("total_ms", "prep_ms", "transfer_ms")
+READING_SHOWN = ("backend", "path", "chunks", "chunk_lanes", "lane_bucket", "fused")
 
 
 def require_device(chips: int, rehearse: bool) -> dict:
@@ -173,14 +184,19 @@ def run_window(entry, state, ring_len, seconds, traffic, tracer, keep_trace, reh
 def judge(cell, entry, state, vals, ring, eprobes, calls, expect, seed, compiles_in_window,
           alarm):
     """What the timed path produced against the plain reference, each number
-    beside its limit. Runs after the window has closed."""
+    beside its limit. Runs after the window has closed. An item's rows are
+    one list in block order; its verdict is the configuration's rule over
+    the reference's mask of them."""
     config, traffic = cell.config, cell.traffic
-    ref_rows = [data.rows_of(config, vals, c) for c in ring]
+    rule = cell.rule()
+
+    def ref_verdict(item, mask, signers):
+        return rule(mask, signers, vals.powers, vals.total_power, data.blocks_of(item))
+
+    ref_rows = [data.rows_of(config, vals, item) for item in ring]
     ref_masks = [reference.verify_rows(pk, ms, sg) for _, pk, ms, sg in ref_rows]
-    ref_verdicts = [
-        reference.commit_verdict(m, row[0], vals.powers, vals.total_power)
-        for m, row in zip(ref_masks, ref_rows)
-    ]
+    ref_verdicts = [ref_verdict(item, m, row[0])
+                    for item, m, row in zip(ring, ref_masks, ref_rows)]
     verdict_mismatch = off_path = rows_short = 0
     for c in calls:
         c["wrong"] = c["verdict"] != ref_verdicts[c["ring"]]
@@ -210,10 +226,9 @@ def judge(cell, entry, state, vals, ring, eprobes, calls, expect, seed, compiles
             accepted += 1
     # what the entry itself has to refuse, in the reference's words
     entry_mismatch, entry_said = 0, {}
-    for j, (label, c) in enumerate(eprobes):
-        idx, pk, ms, sg = data.rows_of(config, vals, c)
-        want = reference.commit_verdict(reference.verify_rows(pk, ms, sg), idx,
-                                        vals.powers, vals.total_power)
+    for j, (label, item) in enumerate(eprobes):
+        idx, pk, ms, sg = data.rows_of(config, vals, item)
+        want = ref_verdict(item, reference.verify_rows(pk, ms, sg), idx)
         got = entry.call(state, len(ring) + j)
         entry_said[label] = {"want": want, "got": got, "path": entry.flush_reading()["jax_path"]}
         entry_mismatch += got != want or want == "accepted"
@@ -276,8 +291,8 @@ def main(argv=None) -> int:
     vals = data.make_validators(args.seed, cell.config, args.rehearse or None)
     ring = data.make_ring(args.seed, cell.config, cell.traffic, vals)
     split["data_s"] = time.perf_counter() - T0 - sum(split.values())
-    eprobes = data.entry_probes(args.seed, cell.config, cell.traffic, ring)
-    state = entry.build(cell.config, vals, ring + [c for _, c in eprobes])
+    eprobes = data.entry_probes(args.seed, cell.config, cell.traffic, ring, vals)
+    state = entry.build(cell.config, vals, ring + [item for _, item in eprobes])
     split["build_s"] = time.perf_counter() - T0 - sum(split.values())
     if args.control:
         import controls
@@ -285,7 +300,7 @@ def main(argv=None) -> int:
         if args.control in getattr(entry, "PROGRAM_CONTROLS", {}):
             entry.PROGRAM_CONTROLS[args.control]()
         else:
-            entry.install_verifier(getattr(controls, args.control))
+            entry.install_verifier(functools.partial(getattr(controls, args.control), vals))
     for i in range(int(cell.traffic["warmup_calls"])):
         entry.call(state, i % len(ring))
         entry.flush_reading()  # its first reading costs 11 ms once; not the window's to pay
@@ -310,11 +325,12 @@ def main(argv=None) -> int:
                           compiles_in_window, alarm)
     notes["judge_s"] = time.perf_counter() - t_judge
     correct = all(v <= limit for v, limit in checks.values())
-    rows = len(ring[0].present())
-    good = [c for c in calls
-            if not c["wrong"] and c["fault"] is None and not c["flush"]["compile_ms"]]
+    item_rows = [data.n_rows(item) for item in ring]
+    rows = item_rows[0]  # of one call: what the per-layer readers and work.py reckon by
+    for c in calls:  # a call is credited the signatures of its own item, or none
+        c["good"] = not c["wrong"] and c["fault"] is None and not c["flush"]["compile_ms"]
     window = stats.window_metrics(
-        [(c["start"], c["end"], c in good) for c in calls], rows)
+        [(c["start"], c["end"], item_rows[c["ring"]] * c["good"]) for c in calls])
     readings = {"sigs_per_s": window["sigs_per_s"], "verify_ms_p50": window["verify_ms_p50"],
                 "verify_ms_p95": window["verify_ms_p95"], "setup_s": setup_s}
     wanted = cell.per_layer if args.trace else cell.end_to_end
@@ -342,11 +358,12 @@ def main(argv=None) -> int:
     # the host's spans as medians, in every run: where a run that reads far off lost its time
     walls = [(c["end"] - c["start"]) * 1e3 for c in calls]
     spans = {k: stats.percentile([c["flush"][k] or 0.0 for c in calls], 50)
-             for k in ("total_ms", "prep_ms", "transfer_ms")}
+             for k in READING_SPANS}
     spans["outside_flush_ms"] = stats.percentile(
         [w - (c["flush"]["total_ms"] or 0.0) for w, c in zip(walls, calls)], 50)
     last = calls[-1]["flush"]
-    result = {"correct": correct, "attempted": len(calls), "failed": len(calls) - len(good)}
+    result = {"correct": correct, "attempted": len(calls),
+              "failed": sum(not c["good"] for c in calls)}
     result["rehearsal_readings" if args.rehearse else "metrics"] = metrics
     if args.rehearse:
         result["metrics"] = {}
@@ -354,10 +371,9 @@ def main(argv=None) -> int:
     if reduced:
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
-    result.update(workload=cell.name, seed=args.seed, samples=window["calls"],
+    result.update(workload=cell.name, seed=args.seed, rows_per_call=rows, samples=window["calls"],
                   window_s=window["window_s"], late_s_max=max(c["late_s"] for c in calls),
-                  flush={k: last[k] for k in ("backend", "path", "chunks", "chunk_lanes",
-                                              "lane_bucket", "fused")},
+                  flush={k: last[k] for k in READING_SHOWN},
                   setup_split=split, host=load, spans_p50=spans,
                   cold={"aot_seconds": at_warm["aot_seconds"],
                         "jax_seconds": at_warm["jax_seconds"],

@@ -11,10 +11,13 @@ of the repo's tier-1 tests.  python benchmark/selftest.py
    chip runs under testdata/.
 5. Off a TPU the command fails with no result line; so it does where the
    program is missing.
-6. A later PR adds a cell as files and entries only: a throwaway
-   configuration, mix and per-layer metric in a copy of this directory run
-   (rehearsed on the host backend) without a line of the harness changed.
-7. tests/test_correct.py: `correct` fails the control and the planted faults.
+6. A later PR adds a cell as files and entries only: a throwaway cell of
+   another shape than the accepted ones (a call of 6 commits, a skewed stake
+   with absent signers, a verdict rule and an entry driver of its own, from
+   tests/fixtures/, and a per-layer metric) in a copy of this directory, run
+   (rehearsed on the host) without a line of the harness changed.
+7. tests/: `correct` fails the control and the planted faults, of the
+   accepted cells (test_correct.py) and of the windowed one (test_room.py).
 """
 
 from __future__ import annotations
@@ -64,14 +67,20 @@ def test_arithmetic():
         wall = 1.0 if i in range(90, 102) else 0.1  # 12 of 200 calls stall
         stalled.append((t, t + wall, True))
         t += wall
-    a, b = stats.window_metrics(steady, 1000), stats.window_metrics(stalled, 1000)
+    def credited(calls, rows):
+        return [(s, e, rows * ok) for s, e, ok in calls]
+
+    a, b = (stats.window_metrics(credited(c, 1000)) for c in (steady, stalled))
     check(abs(a["sigs_per_s"] - 10000) < 1e-6, "the rate is work over the whole window")
     check(b["sigs_per_s"] < 0.7 * a["sigs_per_s"], "a stall lowers the rate")
     check(b["verify_ms_p95"] > 5 * a["verify_ms_p95"], "a stall raises the 95th percentile")
     check(abs(b["verify_ms_p50"] - a["verify_ms_p50"]) < 1e-6, "and leaves the median")
     wrong = [(s, e, i % 2 == 0) for i, (s, e, _) in enumerate(steady)]
-    check(abs(stats.window_metrics(wrong, 1000)["sigs_per_s"] - 5000) < 1e-6,
+    check(abs(stats.window_metrics(credited(wrong, 1000))["sigs_per_s"] - 5000) < 1e-6,
           "a call that came back wrong adds no signatures and keeps its time")
+    unequal = [(s, e, 276 if i % 2 else 230) for i, (s, e, _) in enumerate(steady)]
+    check(abs(stats.window_metrics(unequal)["sigs_per_s"] - 2530) < 1e-6,
+          "a call is credited the signatures of its own item")
     check(abs(stats.spread([1, 2, 3, 4, 5, 6]) - 3.5 / 3.5) < 1e-9,
           "spread is the quartile distance by statistics.quantiles over the median")
 
@@ -147,19 +156,70 @@ THROWAWAY_READER = '''"""Throwaway reader: the latest a call was started after i
 def read(ctx):
     return max(c["late_s"] for c in ctx.calls) * 1e3
 '''
+WINDOWED_CELL = "skewed-48.window-6"
+
+
+def add_windowed_cell(tmp: str):
+    """What a later PR does, in a copy of this directory under `tmp`: the
+    files of tests/fixtures/ laid into configs/, traffic/, references/ and
+    entries/, one reader, and four entries in BENCHMARK.json. Returns the
+    copy's BENCHMARK.json and its benchmark/ directory."""
+    here = os.path.join(tmp, "benchmark")
+    shutil.copytree(HERE, here, ignore=shutil.ignore_patterns("__pycache__", "testdata"))
+    shutil.copytree(os.path.join(HERE, "tests", "fixtures"), here, dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(here, "layer_metrics", "late.max_ms.py"), "w") as f:
+        f.write(THROWAWAY_READER)
+    bm = spec.load_benchmark(ROOT)
+    bm["configs"].append({"name": "skewed-48", "source": "selftest", "reduced": [],
+                          "file": "benchmark/configs/skewed-48.json", "why": "throwaway"})
+    bm["workloads"].append({"name": WINDOWED_CELL, "config": "skewed-48",
+                            "traffic": "window-6", "chips": 1, "why": "throwaway"})
+    bm["per_layer"].append({"name": "late.max_ms", "unit": "ms", "better": "lower",
+                            "source": "host_clock", "layer": "entry points",
+                            "moves": "verify_ms_p50", "workloads": [WINDOWED_CELL]})
+    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
+        json.dump(bm, f)
+    return bm, here
+
+
+def run_windowed_cell(tmp: str, here: str, seed: int, control: str = "", seconds: float = 1.0):
+    cmd = [sys.executable, os.path.join(here, "run.py"), "--workload", WINDOWED_CELL,
+           "--seed", str(seed), "--seconds", str(seconds), "--trace", "0", "--rehearse", "48"]
+    return _run(cmd + (["--control", control] if control else []),
+                env={"PYTHONPATH": ROOT}, cwd=tmp)
 
 
 def test_add_a_cell_as_data():
     with tempfile.TemporaryDirectory() as tmp:
-        here = os.path.join(tmp, "benchmark")
-        shutil.copytree(HERE, here, ignore=shutil.ignore_patterns("__pycache__", "testdata"))
-        shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp)
+        # what a later PR adds: five files and four entries
+        bm, here = add_windowed_cell(tmp)
+        check(spec.lint(bm, tmp, here) == [], "the copy with the added cell passes the lint")
+        changed = [f for f in sorted(os.listdir(HERE)) if f.endswith(".py")
+                   and open(os.path.join(HERE, f)).read() != open(os.path.join(here, f)).read()]
+        check(changed == [], "and the added files replace no file of the harness")
         p = _run([sys.executable, os.path.join(here, "run.py"), "--workload",
                   "commit-1024.verify-commit", "--seed", "1", "--seconds", "1", "--trace", "0",
                   "--rehearse", "48"], cwd=tmp)
         check(p.returncode != 0 and p.stdout.strip() == "",
               "with only BENCHMARK.json and benchmark/ the command fails, no result")
-        # what a later PR adds: three files and three entries
+        p = run_windowed_cell(tmp, here, seed=2500000000)
+        check(p.returncode == 0, "the added cell runs (rehearsed): " + p.stderr[-300:])
+        out = json.loads(p.stdout.strip().splitlines()[-1])
+        check(out["correct"] is True and out["failed"] == 0 and out["attempted"] > 0,
+              "a call of 6 commits under a skewed stake is judged by the cell's own rule: correct")
+        check(out["rows_per_call"] == 6 * 46,
+              "a call is credited the rows of its own item: 6 commits of 46 signers")
+        said = out["notes"]["entry_probes"]
+        check(sorted(said) == ["invalid_power", "short_power"]
+              and all(v["want"] == v["got"] != "accepted" for v in said.values()),
+              f"both entry probes are refused in the rule's words ({said['short_power']['want']})")
+        cell = spec.Cell(bm, WINDOWED_CELL, tmp, here)
+        check([m["name"] for m in cell.per_layer if "workloads" in m] == ["late.max_ms"],
+              "the cell reports the added per-layer metric and none that names other cells")
+        ctx = type("Ctx", (), {"calls": [{"late_s": 0.002}, {"late_s": 0.0005}]})()
+        check(cell.reader("late.max_ms").read(ctx) == 2.0, "and its reader is found by name")
+        # the mix's other paths, on the accepted driver: an open loop, tampered items, absence
         cfg = spec.load_json(os.path.join(here, "configs", "commit-1024.json"))
         cfg.update(name="tiny-48", validators=48, voting_power=7, absent_share=0.25,
                    chain_id="throwaway", source="selftest")
@@ -169,39 +229,27 @@ def test_add_a_cell_as_data():
         mix.update(name="open-tampered", loop="open", rate_per_s=25, tampered_one_in=2)
         with open(os.path.join(here, "traffic", "open-tampered.json"), "w") as f:
             json.dump(mix, f)
-        with open(os.path.join(here, "layer_metrics", "late.max_ms.py"), "w") as f:
-            f.write(THROWAWAY_READER)
-        bm = spec.load_benchmark(tmp)
         bm["configs"].append({"name": "tiny-48", "source": "selftest", "reduced": [],
                               "file": "benchmark/configs/tiny-48.json", "why": "throwaway"})
         bm["workloads"].append({"name": "tiny-48.open-tampered", "config": "tiny-48",
                                 "traffic": "open-tampered", "chips": 1, "why": "throwaway"})
-        bm["per_layer"].append({"name": "late.max_ms", "unit": "ms", "better": "lower",
-                                "source": "host_clock", "layer": "entry points",
-                                "moves": "verify_ms_p50",
-                                "workloads": ["tiny-48.open-tampered"]})
         with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
             json.dump(bm, f)
-        check(spec.lint(bm, tmp, here) == [], "the copy with the added cell passes the lint")
+        check(spec.lint(bm, tmp, here) == [], "a second added cell passes the lint")
         p = _run([sys.executable, os.path.join(here, "run.py"), "--workload",
                   "tiny-48.open-tampered", "--seed", "2500000000", "--seconds", "1.5",
                   "--trace", "0", "--rehearse", "48"], env={"PYTHONPATH": ROOT}, cwd=tmp)
-        check(p.returncode == 0, "the added cell runs (rehearsed): " + p.stderr[-300:])
+        check(p.returncode == 0, "an open loop of tampered commits runs (rehearsed): " + p.stderr[-300:])
         out = json.loads(p.stdout.strip().splitlines()[-1])
         check(out["correct"] is True and out["failed"] == 0,
               "its tampered commits are refused as the reference refuses them")
         check(30 <= out["attempted"] <= 40, f"its open loop kept its rate ({out['attempted']} calls)")
-        cell = spec.Cell(bm, "tiny-48.open-tampered", tmp, here)
-        check([m["name"] for m in cell.per_layer if "workloads" in m] == ["late.max_ms"],
-              "the cell reports the added per-layer metric and none that names other cells")
-        ctx = type("Ctx", (), {"calls": [{"late_s": 0.002}, {"late_s": 0.0005}]})()
-        check(cell.reader("late.max_ms").read(ctx) == 2.0, "and its reader is found by name")
 
 
 def test_correct_fails_what_is_wrong():
     p = _run([sys.executable, "-m", "pytest", os.path.join(HERE, "tests"), "-q",
               "-p", "no:cacheprovider"])
-    check(p.returncode == 0, "tests/test_correct.py: " + p.stdout.strip().splitlines()[-1])
+    check(p.returncode == 0, "tests/: " + p.stdout.strip().splitlines()[-1])
 
 
 def main() -> int:

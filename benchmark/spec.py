@@ -1,7 +1,7 @@
 """Finds everything a cell is made of by the names in BENCHMARK.json, and
 lints that file. A later PR adds a cell by adding entries there and files
-here (configs/, traffic/, entries/, layer_metrics/); nothing in this module
-knows any cell, configuration, mix or metric by name."""
+here (configs/, traffic/, entries/, references/, layer_metrics/); nothing in
+this module knows any cell, configuration, mix, rule or metric by name."""
 
 from __future__ import annotations
 
@@ -40,7 +40,7 @@ def traffic_path(here: str, name: str) -> str:
 
 
 def module_path(here: str, kind: str, name: str) -> str:
-    """entries/<name>.py or layer_metrics/<name>.py."""
+    """entries/<name>.py, references/<name>.py or layer_metrics/<name>.py."""
     return os.path.join(here, kind, name + ".py")
 
 
@@ -88,6 +88,16 @@ class Cell:
     def entry(self):
         return load_module(module_path(self.here, "entries", self.traffic["entry"]))
 
+    def rule(self):
+        """The verdict rule the deployment promises, as
+        `verdict(mask, signers, powers, total_power, blocks) -> str`: the
+        configuration's `verdict_rule`, found as references/<name>.py, or
+        where it names none, VerifyCommit (reference.verdict)."""
+        name = self.config.get("verdict_rule")
+        path = module_path(self.here, "references", name) if name else \
+            os.path.join(self.here, "reference.py")
+        return load_module(path).verdict
+
     def reader(self, metric_name: str):
         return load_module(module_path(self.here, "layer_metrics", metric_name))
 
@@ -112,7 +122,7 @@ def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
     for word in bm["command"]:
         if word.startswith("/") or ".." in word.split("/"):
             bad.append(f"command word {word!r} leaves the repo")
-    cfg_names, files = set(), set()
+    cfg_names, files, rules = set(), set(), {}
     for c in bm["configs"]:
         if set(c) != {"name", "source", "file", "reduced", "why"}:
             bad.append(f"config {c.get('name')}: keys {sorted(c)}")
@@ -124,6 +134,17 @@ def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
         files.add(c["file"])
         if not under(c["file"]) or not os.path.isfile(os.path.join(root, c["file"])):
             bad.append(f"config {c['name']}: file {c['file']} missing or outside paths")
+        else:
+            cfg = load_json(os.path.join(root, c["file"]))
+            rules[c["name"]] = cfg.get("verdict_rule")
+            if "voting_powers" in cfg and len(cfg["voting_powers"]) != cfg.get("validators"):
+                bad.append(f"config {c['name']}: {len(cfg['voting_powers'])} voting_powers "
+                           f"for {cfg.get('validators')} validators")
+            if "verdict_rule" in cfg:
+                name_ok(cfg["verdict_rule"], f"config {c['name']} verdict_rule")
+                if not os.path.isfile(module_path(here, "references", str(cfg["verdict_rule"]))):
+                    bad.append(f"config {c['name']}: no rule file "
+                               f"references/{cfg['verdict_rule']}.py")
         for k in c["reduced"]:
             name_ok(k, f"config {c['name']} reduced")
         for k in ("source", "why"):
@@ -151,9 +172,16 @@ def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
         if not os.path.isfile(tp):
             bad.append(f"workload {w['name']}: no traffic file {tp}")
         else:
-            entry = load_json(tp).get("entry", "")
-            if not os.path.isfile(module_path(here, "entries", entry)):
-                bad.append(f"traffic {w['traffic']}: no entry driver {entry!r}")
+            mix = load_json(tp)
+            if not os.path.isfile(module_path(here, "entries", mix.get("entry", ""))):
+                bad.append(f"traffic {w['traffic']}: no entry driver {mix.get('entry', '')!r}")
+            per_call = mix.get("commits_per_call", 1)
+            if type(per_call) is not int or per_call < 1:
+                bad.append(f"traffic {w['traffic']}: commits_per_call {per_call!r} is not a "
+                           "whole number from 1")
+            elif per_call > 1 and not rules.get(w["config"]):
+                bad.append(f"workload {w['name']}: a call of {per_call} commits needs the "
+                           "configuration to name its verdict_rule")
     if cfg_names - used:
         bad.append(f"configs used by no cell: {sorted(cfg_names - used)}")
     four = sum(1 for w in bm["workloads"] if w.get("chips") == 4)

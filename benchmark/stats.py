@@ -18,18 +18,18 @@ def percentile(values, q: float) -> float:
     return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
 
 
-def window_metrics(calls, rows_per_call: int) -> dict:
-    """calls: (start_s, end_s, ok) of every call of the window, in order.
-    The rate is taken over all the work that came back right and all the
-    time from the first call's start to the last call's end; the latencies
-    are of all calls, right or wrong."""
+def window_metrics(calls) -> dict:
+    """calls: (start_s, end_s, rows) of every call of the window, in order;
+    `rows` is the signatures of the call's own item where it came back
+    right, and 0 where it did not. The rate is taken over all the work that
+    came back right and all the time from the first call's start to the last
+    call's end; the latencies are of all calls, right or wrong."""
     if not calls:
         raise ValueError("the window made no call")
     elapsed = calls[-1][1] - calls[0][0]
     walls_ms = [(e - s) * 1e3 for s, e, _ in calls]
-    good = sum(1 for c in calls if c[2])
     return {
-        "sigs_per_s": good * rows_per_call / elapsed,
+        "sigs_per_s": sum(rows for _, _, rows in calls) / elapsed,
         "verify_ms_p50": percentile(walls_ms, 50),
         "verify_ms_p95": percentile(walls_ms, 95),
         "calls": len(calls),
