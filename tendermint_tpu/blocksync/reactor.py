@@ -47,6 +47,7 @@ from tendermint_tpu.blocksync.messages import (
 )
 from tendermint_tpu.blocksync.pool import BlockPool
 from tendermint_tpu.crypto.batch import verify_batch
+from tendermint_tpu.libs import trace as _trace
 from tendermint_tpu.p2p.base_reactor import Reactor
 from tendermint_tpu.p2p.conn.connection import ChannelDescriptor
 from tendermint_tpu.types.basic import BlockID
@@ -293,32 +294,54 @@ class BlocksyncReactor(Reactor):
         — later failures may just mean the set changed, and those heights
         are re-verified as the head of the next run against the then-correct
         set."""
-        pubkeys, msgs, sigs, key_types = [], [], [], []
-        spans = []  # (start, count, powers, total_power, ok_struct)
         vals = self.state.validators
-        for first, parts, second, _enc in run:
-            commit = second.last_commit
-            first_id = BlockID(first.hash(), parts.header)
-            start = len(sigs)
-            powers = []
-            if len(commit.signatures) != vals.size():
-                spans.append((start, 0, [], 1, False))
-                continue
-            idxs = []
-            for idx, cs_sig in enumerate(commit.signatures):
-                if not cs_sig.for_block():
+        # one span tree per run (docs/OBSERVABILITY.md): the lane's wait and
+        # flush, and the verify_batch under it on the dispatch thread, hang
+        # under this root by the scheduler's explicit parent
+        with _trace.span(
+            "catchup.verify_run", blocks=len(run), signers=vals.size()
+        ) as root:
+            bad = self._verify_run_rows(run, vals, degraded, root)
+            root.set(verdict="accepted" if bad is None else bad)
+        return bad
+
+    def _verify_run_rows(self, run: List[tuple], vals, degraded: bool, root) -> Optional[int]:
+        """_verify_run_batched's body under its root span: gather the rows,
+        build their sign bytes, verify them in one batch, tally by power."""
+        pubkeys, sigs, key_types = [], [], []
+        spans = []   # per block: (start, count, powers, ok_struct)
+        signed = []  # (commit, idxs) of the blocks that gave rows
+        with _trace.span("catchup.gather") as sp:
+            for first, parts, second, _enc in run:
+                commit = second.last_commit
+                start = len(sigs)
+                if len(commit.signatures) != vals.size():
+                    spans.append((start, 0, [], False))
                     continue
-                val = vals.validators[idx]
-                pubkeys.append(val.pub_key.bytes())
-                idxs.append(idx)
-                sigs.append(cs_sig.signature)
-                key_types.append(val.pub_key.type_name())
-                powers.append(val.voting_power)
-            msgs.extend(commit.vote_sign_bytes_many(self.state.chain_id, idxs))
-            ok_struct = commit.block_id == first_id and commit.height == first.header.height
-            spans.append((start, len(sigs) - start, powers, vals.total_voting_power(), ok_struct))
+                first_id = BlockID(first.hash(), parts.header)
+                idxs, powers = [], []
+                for idx, cs_sig in enumerate(commit.signatures):
+                    if not cs_sig.for_block():
+                        continue
+                    val = vals.validators[idx]
+                    pubkeys.append(val.pub_key.bytes())
+                    idxs.append(idx)
+                    sigs.append(cs_sig.signature)
+                    key_types.append(val.pub_key.type_name())
+                    powers.append(val.voting_power)
+                signed.append((commit, idxs))
+                ok_struct = commit.block_id == first_id and commit.height == first.header.height
+                spans.append((start, len(sigs) - start, powers, ok_struct))
+            sp.set(rows=len(sigs))
+        root.set(rows=len(sigs))
         if not sigs:
             return 0 if run else None
+        msgs = []
+        # ONE span a run over all its blocks' native passes: a span a block
+        # would roll the recorder's ring over in a few dozen runs
+        with _trace.span("catchup.sign_bytes", rows=len(sigs), blocks=len(signed)):
+            for commit, idxs in signed:
+                msgs.extend(commit.vote_sign_bytes_many(self.state.chain_id, idxs))
         if self.metrics is not None:
             self.metrics.super_batch_rows.observe(len(sigs))
         # key_types: sr25519 validators' sigs must verify under sr25519 rules
@@ -333,12 +356,14 @@ class BlocksyncReactor(Reactor):
             # breaker-open degrade: verify_batch routes straight to the CPU
             # path while the breaker is OPEN (crypto/batch cpu-breaker)
             mask = verify_batch(pubkeys, msgs, sigs, key_types=key_types)
-        for i, (start, count, powers, total, ok_struct) in enumerate(spans):
-            if not ok_struct:
-                return i
-            tallied = sum(p for ok, p in zip(mask[start : start + count], powers) if ok)
-            if tallied * 3 <= total * 2:
-                return i
+        with _trace.span("catchup.tally"):
+            total = vals.total_voting_power()
+            for i, (start, count, powers, ok_struct) in enumerate(spans):
+                if not ok_struct:
+                    return i
+                tallied = sum(p for ok, p in zip(mask[start : start + count], powers) if ok)
+                if tallied * 3 <= total * 2:
+                    return i
         return None
 
     def _breaker_open(self) -> bool:

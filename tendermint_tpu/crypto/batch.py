@@ -2030,7 +2030,8 @@ def _run_sharded_stream(
 
 
 def _verify_batch_pipelined(
-    pubkeys: Sequence[bytes], msgs: Sequence[bytes], sigs: Sequence[bytes]
+    pubkeys: Sequence[bytes], msgs: Sequence[bytes], sigs: Sequence[bytes],
+    whole: bool = False,
 ) -> Optional[np.ndarray]:
     """In-budget 2-chunk stream (ISSUE 18): a single flush above the stream
     floor rides the flush planner as TWO asymmetric chunks — head =
@@ -2039,14 +2040,18 @@ def _verify_batch_pipelined(
     chunks pad to the planner's ONE warm chunk bucket (planner_budget()//2
     rows), so no new shapes compile. Returns the mask when the combined
     check passes; None -> the caller recovers through the per-signature
-    ladder (never recursively through verify_batch_jax)."""
+    ladder (never recursively through verify_batch_jax).
+
+    `whole`: all n rows as ONE chunk on that same bucket — the recovery
+    ladder's combined check of a sub-range of a flush that came down this
+    path (_bisect_recover), whatever the sub-range's size."""
     from tendermint_tpu.ops import msm_jax
 
     n = len(pubkeys)
-    head = max(RLC_MIN, n // 8)
+    head = 0 if whole else max(RLC_MIN, n // 8)
     if not (head < n and n - head <= planner_chunk_rows()):
         return None  # geometry the chunk bucket can't hold: single flush
-    chunks = [(0, head), (head, n)]
+    chunks = [(0, n)] if whole else [(0, head), (head, n)]
     for attempt in range(2):
         try:
             with _trace.span("rlc.pipelined", n=n):
@@ -2524,14 +2529,20 @@ def _persig_flush(pubkeys, msgs, sigs, sharded) -> np.ndarray:
     return mask & precheck
 
 
-def _bisect_recover(pubkeys, msgs, sigs) -> np.ndarray:
+def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarray:
     """Exact-mask recovery after a combined-check failure, in
     O(bad · log(chunks)) flushes instead of one monolithic per-sig pass.
 
     The failed range splits at the largest power of two below its size —
     sub-ranges land on the SAME warm pow2 lane buckets (_bucket /
-    _LANE_BUCKETS) the fast path compiled, so recovery never compiles a
-    new shape. Each half gets one combined check (sharded when meshed);
+    _LANE_BUCKETS) a whole-flush fast path compiled. Where the fast path
+    was the 2-chunk pipelined stream (`chunk_bucket`) it compiled ONE
+    shape, the planner's chunk bucket, and every sub-range's combined
+    check rides that as one chunk: a whole-flush program per pow2 size
+    (six of them under 10,624 rows, minutes each cold, PR 29) would load or
+    compile behind the flush while every lane of the scheduler waits.
+    Either way recovery compiles no new shape for its combined checks.
+    Each half gets one combined check (sharded when meshed);
     a passing half is done (RLC pass returns the exact precheck mask, the
     same invariant the fast path rests on), a failing half recurses. When
     the first half passes, the second is KNOWN bad (the parent failed) and
@@ -2565,6 +2576,8 @@ def _bisect_recover(pubkeys, msgs, sigs) -> np.ndarray:
             if mask is not None or _sharded_runner() is not None:
                 return mask
             flushes += 1
+        if chunk_bucket and hi - lo <= planner_chunk_rows():
+            return _verify_batch_pipelined(pk, ms, sg, whole=True)
         return _verify_batch_rlc(pk, ms, sg)
 
     def _leaf(lo, hi):
@@ -2610,6 +2623,9 @@ def verify_batch_jax(
 ) -> np.ndarray:
     sharded = _sharded_runner()
     if _rlc_enabled() and len(pubkeys) >= RLC_MIN:
+        pipelined = (
+            sharded is None and _stream_enabled() and len(pubkeys) >= _stream_floor()
+        )
         if planner_engaged(len(pubkeys)):
             # over the device budget: stream fixed-bucket chunks through the
             # flush planner (single-device or sharded; includes its own
@@ -2620,7 +2636,7 @@ def verify_batch_jax(
             if mask is not None:
                 return mask  # LAST_JAX_PATH set to "rlc-sharded"
         else:
-            if _stream_enabled() and len(pubkeys) >= _stream_floor():
+            if pipelined:
                 # in-budget 2-chunk stream (ISSUE 18): the tail chunk's prep
                 # hides behind the head chunk's kernels; on combined-check
                 # failure fall through to the exact per-sig ladder below
@@ -2639,7 +2655,7 @@ def verify_batch_jax(
         # O(log chunks) flushes, not a monolithic per-sig pass.
         LAST_FLUSH_DETAIL["rlc_fallback"] = True
         if _bisect_enabled():
-            return _bisect_recover(pubkeys, msgs, sigs)
+            return _bisect_recover(pubkeys, msgs, sigs, chunk_bucket=pipelined)
         # Re-fetch the mesh runner: the RLC attempt above may have rebuilt
         # the mesh (survivor topology) or lost it entirely — the per-sig
         # fallback must not dispatch onto a dead mesh captured earlier.

@@ -60,9 +60,14 @@ Consumers integrate three ways:
 
 All three block the calling thread until the lane's flush lands (the same
 contract as calling verify_batch directly — only the WHO-flushes moved).
-A consumer is never wedged: a closed scheduler, or a verdict that misses
-`wait_timeout`, falls back to an inline verify_batch on the caller's
-thread.
+A consumer is never wedged where an inline verify could free it: a closed
+scheduler, or a verdict that misses `wait_timeout`, falls back to an inline
+verify_batch on the caller's thread. The one case that waits on is a ticket
+whose flush is already running AND whose inline copy would ride the device
+as well (`_JAX_MIN_BATCH` rows or more on the jax backend, breaker closed):
+verifying those rows a second time would only queue behind the same compile
+or the same hung device, so the consumer waits as a direct caller of
+verify_batch would, and looks again every `wait_timeout`.
 
 No reference counterpart: the reference verifies every signature serially
 at each call site; a device worth sharing is what makes scheduling it a
@@ -80,6 +85,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from tendermint_tpu.libs import trace as _trace
 from tendermint_tpu.libs.txtrace import StageStats
 
 logger = logging.getLogger("tendermint_tpu.crypto.scheduler")
@@ -109,12 +115,18 @@ class Ticket:
     slice (or re-raises the flush's error)."""
 
     __slots__ = ("lane", "rows", "enqueued_t", "flush_seq", "wait_s",
-                 "_event", "_mask", "_error")
+                 "parent", "t0_ns", "_event", "_mask", "_error")
 
     def __init__(self, lane: str, rows: int):
         self.lane = lane
         self.rows = rows
         self.enqueued_t = time.monotonic()
+        # flight recorder (libs/trace.py): the submitting thread's open span,
+        # so that `lane.wait`, `lane.flush` and the verify_batch under it
+        # hang in the submitting call's tree although they end, or run, on
+        # the dispatch thread. None with the recorder off.
+        self.parent = _trace.current()
+        self.t0_ns = time.perf_counter_ns()
         self.flush_seq: Optional[int] = None  # device flush this rode
         self.wait_s: Optional[float] = None   # queue wait (enqueue -> flush)
         self._event = threading.Event()
@@ -469,29 +481,59 @@ class VerifyScheduler:
             self.slo.observe("verify_lane_wait_votes", 0.0)
         return mask
 
-    def _wait_or_fallback(self, ticket: Ticket, rows=None) -> np.ndarray:
-        try:
-            return ticket.wait(self.wait_timeout)
-        except TimeoutError:
-            with self._cv:
-                self.fallbacks += 1
-                # dequeue the abandoned ticket: its consumer is about to
-                # verify inline, so flushing these rows later would be pure
-                # duplicate work nobody reads
-                st = self._lanes[ticket.lane]
-                for entry in list(st.queue):
-                    if entry[0] is ticket:
-                        st.queue.remove(entry)
+    def _wait_or_fallback(self, ticket: Ticket, rows) -> np.ndarray:
+        """Block for the ticket's verdict, at most `wait_timeout` wherever an
+        inline verify on the caller's thread could do better: a ticket still
+        QUEUED leaves the lane, and so does one riding a long flush (a cold
+        compile, the recovery ladder of somebody else's rows) if its inline
+        copy runs on the host (`_inline_on_host`: a light-client or CheckTx
+        ticket of a few rows). A ticket in flight whose inline copy would
+        take the device path is waited for: the second verify of its rows
+        would only queue behind the same compile and the same device."""
+        while True:
+            try:
+                return ticket.wait(self.wait_timeout)
+            except TimeoutError:
+                with self._cv:
+                    # dequeue the abandoned ticket: its consumer is about to
+                    # verify inline, so flushing these rows later would be
+                    # pure duplicate work nobody reads
+                    st = self._lanes[ticket.lane]
+                    queued = next((e for e in st.queue if e[0] is ticket), None)
+                    if queued is not None:
+                        st.queue.remove(queued)
                         st.rows -= ticket.rows
+                    in_flight = queued is None
+                    if not in_flight or self._inline_on_host(ticket.rows):
+                        self.fallbacks += 1
                         break
-            logger.warning(
-                "verify lane %s ticket (%d rows) missed the %.0fs wait "
-                "timeout; verifying inline on the caller's thread",
-                ticket.lane, ticket.rows, self.wait_timeout,
-            )
-            if rows is None:
-                raise
-            return self._inline(*rows)
+                logger.info(
+                    "verify lane %s ticket (%d rows) is in a flush that has run "
+                    "past the %.0fs wait timeout; its inline copy would ride "
+                    "the same device: waiting for it",
+                    ticket.lane, ticket.rows, self.wait_timeout,
+                )
+        logger.warning(
+            "verify lane %s ticket (%d rows) missed the %.0fs wait timeout "
+            "(%s); verifying inline on the caller's thread",
+            ticket.lane, ticket.rows, self.wait_timeout,
+            "in a flush still running" if in_flight else "still queued",
+        )
+        return self._inline(*rows)
+
+    def _inline_on_host(self, n: int) -> bool:
+        """Would an inline verify_batch of `n` rows run on the host, free of
+        the device and of whatever holds it? batch._verify_batch_routed's own
+        rule: a backend other than jax, an auto-selected jax under
+        `_JAX_MIN_BATCH` rows, or the breaker open."""
+        from tendermint_tpu.crypto import batch as _batch
+
+        be = self.backend or _batch.backend_default()
+        return (
+            be != "jax"
+            or (self.backend is None and n < _batch._JAX_MIN_BATCH)
+            or not _batch.BREAKER.allow_device()
+        )
 
     def _inline(self, pubkeys, msgs, sigs, key_types,
                 sources=None) -> np.ndarray:
@@ -695,6 +737,7 @@ class VerifyScheduler:
         from tendermint_tpu.crypto import batch as _batch
 
         t_flush = time.monotonic()
+        t_flush_ns = time.perf_counter_ns()
         pubkeys: list = []
         msgs: list = []
         sigs: list = []
@@ -728,8 +771,22 @@ class VerifyScheduler:
         )
         mask: Optional[np.ndarray] = None
         error: Optional[BaseException] = None
+        # one tree per submitting call: the wait of a ticket that was
+        # submitted under a span ends here, in that span's tree (a ticket
+        # submitted under none, e.g. one a CheckTx, leaves no event: spans are
+        # per call, never per row), and the flush, with every verify_batch
+        # under it, hangs under the oldest ticket's span
+        for ticket, _start, _end in slices:
+            if ticket.parent is not None:
+                _trace.interval("lane.wait", ticket.t0_ns, t_flush_ns,
+                                parent=ticket.parent, lane=ticket.lane, rows=ticket.rows)
         try:
-            mask = self._verify_chunked(pubkeys, msgs, sigs, kt_arg, src_arg)
+            with _trace.span(
+                "lane.flush", parent=slices[0][0].parent,
+                lanes=",".join(sorted(lanes)), rows=len(pubkeys), tickets=len(slices),
+            ) as sp:
+                mask, flushes = self._verify_chunked(pubkeys, msgs, sigs, kt_arg, src_arg)
+                sp.set(flushes=flushes)
         except BaseException as e:  # tickets re-raise; the thread survives
             error = e
             logger.exception(
@@ -771,47 +828,34 @@ class VerifyScheduler:
             ticket._resolve(mask[start:end] if mask is not None else None, error)
 
     def _verify_chunked(self, pubkeys, msgs, sigs, kt_arg,
-                        sources=None) -> np.ndarray:
+                        sources=None) -> tuple:
         """The dispatch thread's verify body: an oversized combined flush
         (catch-up super-batches, admission floods) splits into flush-planner
-        chunks (crypto/batch.planner_chunk_rows) with a PREEMPTION POINT
-        between chunks — vote rows that queued while a chunk ran flush next,
-        alone, before the following chunk. A vote flush therefore waits at
-        most ONE chunk, never a 200k-lane monolith; verdict slices stay
-        byte-identical (chunk masks concatenate in row order, and each chunk
-        rides the normal verify_batch ladder)."""
+        chunks (crypto/batch._planner_chunks: row spans of at most
+        planner_chunk_rows) with a PREEMPTION POINT between chunks — vote
+        rows that queued while a chunk ran flush next, alone, before the
+        following chunk. A vote flush therefore waits at most ONE chunk,
+        never a 200k-lane monolith; verdict slices stay byte-identical
+        (chunk masks concatenate in row order, and each chunk rides the
+        normal verify_batch ladder). Returns (mask, verify_batch calls made:
+        one a chunk, `flushes` on the `lane.flush` span)."""
         from tendermint_tpu.crypto import batch as _batch
 
-        chunk = _batch.planner_chunk_rows()
-        n = len(pubkeys)
-        if n <= chunk:
-            if sources is None:
-                return _batch.verify_batch(
-                    pubkeys, msgs, sigs, self.backend, kt_arg
-                )
-            return _batch.verify_batch(pubkeys, msgs, sigs, self.backend,
-                                       kt_arg, sources=sources)
+        chunks = _batch._planner_chunks(len(pubkeys)) or [(0, 0)]
         parts = []
-        for lo in range(0, n, chunk):
+        for lo, hi in chunks:
             if lo:
                 self._preempt_votes_between_chunks()
-            hi = min(lo + chunk, n)
-            kt_c = kt_arg[lo:hi] if kt_arg is not None else None
-            if sources is None:
-                parts.append(
-                    _batch.verify_batch(
-                        pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi],
-                        self.backend, kt_c,
-                    )
+            # an untagged flush keeps the untagged call shape: tests stub
+            # verify_batch with 5-arg fakes, and it has nothing to score
+            kw = {} if sources is None else {"sources": sources[lo:hi]}
+            parts.append(
+                _batch.verify_batch(
+                    pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi], self.backend,
+                    kt_arg[lo:hi] if kt_arg is not None else None, **kw,
                 )
-            else:
-                parts.append(
-                    _batch.verify_batch(
-                        pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi],
-                        self.backend, kt_c, sources=sources[lo:hi],
-                    )
-                )
-        return np.concatenate(parts)
+            )
+        return (parts[0] if len(parts) == 1 else np.concatenate(parts)), len(parts)
 
     def _preempt_votes_between_chunks(self) -> None:
         """Between-chunk preemption point (dispatch thread only): drain any

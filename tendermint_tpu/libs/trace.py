@@ -33,10 +33,12 @@ Every ring event carries `t0_ns` (`time.perf_counter_ns()` at entry; for a
 point event, at the event) and `root`, the id of the outermost span open
 when it began, so the spans of one request share an identifier. Work handed
 to another thread nests under the submitting span with `span(name,
-parent=<that span>)`. While a JAX profiler session is live each span is
-mirrored as `jax.profiler.TraceAnnotation("tm:" + name)`, which puts the
-program's spans on the host plane of the same `.xplane.pb`, on the
-profiler's clock, beside the device plane.
+parent=<that span>)`; an interval that starts on one thread and ends on
+another is written closed, with `interval(name, t0_ns, t1_ns, parent=...)`.
+While a JAX profiler session is live each span is mirrored as
+`jax.profiler.TraceAnnotation("tm:" + name)`, which puts the program's spans
+on the host plane of the same `.xplane.pb`, on the profiler's clock, beside
+the device plane.
 """
 
 from __future__ import annotations
@@ -305,6 +307,25 @@ def timed(name: str, parent=None, **attrs):
     if not t.enabled:
         return Stopwatch()
     return Span(t, name, attrs, parent)
+
+
+def interval(name: str, t0_ns: int, t1_ns: int, parent=None, **attrs) -> None:
+    """A closed span from two readings of `perf_counter_ns`, for an interval
+    that begins on one thread and ends on another (a ticket's wait in a
+    scheduler lane: submit on the caller's thread, flush start on the
+    dispatch thread), so no thread's stack can hold it open. It nests under
+    `parent` as `span(name, parent=...)` does; with the recorder off it is
+    one flag read. It has no `tm:` mirror: an annotation cannot be opened in
+    the past."""
+    t = tracer
+    if not t.enabled:
+        return
+    span_id = t._next_id()
+    if parent is not None and parent.recording:
+        parent_id, root = parent.span_id, parent.root
+    else:
+        parent_id, root = None, span_id
+    t._record(name, span_id, parent_id, root, t0_ns, (t1_ns - t0_ns) / 1e9, attrs)
 
 
 def current():

@@ -152,11 +152,16 @@ def test_ring_reads_the_programs_recorder(monkeypatch):
 def test_benchmark_json_lints_and_lists_the_eight():
     bm = spec.load_benchmark(ROOT)
     assert spec.lint(bm, ROOT, BENCH) == []
-    added = bm["per_layer"][-8:]
+    added = [m for m in bm["per_layer"] if m["name"] in NEW]
     assert [m["name"] for m in added] == NEW
+    cells = ["commit-10k.verify-commit", "commit-1024.verify-commit"]
     for m in added:
         assert m["source"] == "program_span" and m["moves"] == "verify_ms_p50"
-        assert m["unit"] == "ms" and m["better"] == "lower" and "workloads" not in m
-    for cell in ("commit-10k.verify-commit", "commit-1024.verify-commit"):
+        assert m["unit"] == "ms" and m["better"] == "lower"
+        # since PR 29: they pick calls by the `commit.verify` root, so they
+        # name the two cells whose entry opens it
+        assert m["workloads"] == cells
+    for cell in cells:
         mine = {m["name"] for m in spec.metrics_of(bm, "per_layer", cell)}
         assert set(NEW) <= mine
+    assert not set(NEW) & {m["name"] for m in spec.metrics_of(bm, "per_layer", "hub-175.catchup")}

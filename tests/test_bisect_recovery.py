@@ -184,6 +184,58 @@ def test_bisect_disabled_restores_naive_arm(bisect_env, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# After a pipelined flush the ladder rides the planner's one chunk bucket.
+
+
+@pytest.mark.parametrize(
+    "bad_rows", [(41,), (0, 99), (30, 31, 32, 33, 34, 35)],
+    ids=["one", "head-and-tail", "six-in-a-row"],
+)
+def test_ladder_after_a_pipelined_flush_rides_the_chunk_bucket(
+    bisect_env, monkeypatch, bad_rows
+):
+    """A flush over the stream floor compiled ONE shape, the chunk bucket
+    (rlc_partial): every combined check of its recovery ladder runs as one
+    chunk on that bucket, none on a whole-flush program (rlc_check_submit,
+    a program per pow2 sub-range size: what a catch-up run of 10,624 rows
+    paid minutes for, PR 29). The mask is the CPU reference's, the flush
+    count the ledger's."""
+    from tendermint_tpu.ops import msm_jax
+
+    witness = _FlushWitness(monkeypatch)
+    partial_lanes = []
+    real_partial = msm_jax.rlc_partial_submit
+
+    def counting_partial(pts, *a, **k):
+        partial_lanes.append(pts.shape[0])
+        return real_partial(pts, *a, **k)
+
+    monkeypatch.setattr(msm_jax, "rlc_partial_submit", counting_partial)
+    prev_cfg = dict(batch._PREP_CFG)
+    batch.configure_planner(max_flush_lanes=256)  # 127 rows a chunk
+    batch._PREP_CFG.update(stream=True, stream_floor=16)
+    try:
+        pks, msgs, sigs = _signed_rows(100)
+        sigs = list(sigs)
+        for i in bad_rows:
+            _flip(sigs, i)
+        mask = batch.verify_batch(pks, msgs, sigs, backend="jax")
+        recovery = batch.LAST_FLUSH_DETAIL.get("recovery_flushes", 0)
+    finally:
+        batch._PREP_CFG.clear()
+        batch._PREP_CFG.update(prev_cfg)
+
+    assert mask.tobytes() == batch.verify_batch_cpu(pks, msgs, sigs).tobytes()
+    assert [i for i in range(100) if not mask[i]] == list(bad_rows)
+    assert batch.LAST_JAX_PATH[0] == "rlc-bisect"
+    assert witness.combined == 0  # no whole-flush program at any sub-range size
+    assert set(partial_lanes) == {256}  # one shape, the failed flush's own
+    # the failed flush's two chunks, then one chunk a combined check
+    assert len(partial_lanes) - 2 + witness.persig == recovery
+    assert witness.persig >= 1
+
+
+# ---------------------------------------------------------------------------
 # Host arm (_bisect_recover_host): same ladder on the striped host RLC.
 
 
