@@ -405,8 +405,8 @@ def bench_config(name: str, n: int, serial_n: int | None = None, rlc: bool = Tru
         from tendermint_tpu.crypto import batch as B
 
         # prep-overlap telemetry for the flush time_rlc just timed (the
-        # pipelined 2-chunk stream above the floor, or the staged
-        # single-flush A-upload overlap below it)
+        # staged single-flush A-upload overlap below the stream floor;
+        # above it the one chunk-bucket chunk hides nothing and reads 0)
         res.update(_prep_hidden_extra(dict(B.LAST_FLUSH_DETAIL)))
 
         # pipelined slope + its raw samples (warm: time_rlc prefilled the

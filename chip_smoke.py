@@ -16,9 +16,9 @@ device phases. Every line before the last is a smoke reading of a SINGLE
 run, not a metric; the last line is the fixed result object.
 
 Compiled programs bound the run (each is minutes cold, see PERF.md):
-  steps 2-3  every valid 10,000-row flush is verify_batch's 2-chunk
-             pipelined stream on the planner's one chunk bucket
-             (rlc_partial_f @ 24,576 lanes + partial_fold + partial_ident).
+  steps 2-3  every valid 10,000-row flush is verify_batch's chunk-bucket
+             flush (path rlc-pipelined): one chunk on the planner's one
+             chunk bucket (rlc_partial_f @ 24,576 lanes + partial_ident).
              verify_commit_light / _light_trusting run inside
              crypto.batch.accumulate_flushes(), the scope light/service.py
              runs them in, so their rows take that same route; outside a

@@ -421,8 +421,9 @@ class CryptoConfig:
     # waits on the window sort).
     prep_staged: bool = True
     # prep_stream: let IN-budget flushes of >= prep_stream_floor rows ride
-    # the flush planner as a 2-chunk stream (tail prep hides behind head
-    # kernels; reuses the planner's warm chunk bucket, no new compiles).
+    # the flush planner's one warm chunk bucket as ONE chunk (no per-size
+    # program compiles for the flush or its recovery ladder); under the
+    # floor a flush takes the per-size `rlc` program's smaller bucket.
     prep_stream: bool = True
     prep_stream_floor: int = 2048
     # prep_host_stripe: stripe the HOST (no-device) RLC fallback so the

@@ -266,13 +266,12 @@ def _prep_pool():
 #                 window sort (TMTPU_PREP_STAGED=0 forces the serial path —
 #                 byte-identity is differentially pinned by tests).
 #   stream        let IN-budget flushes above `stream_floor` ride the flush
-#                 planner as a 2-chunk stream (head = max(RLC_MIN, n//8)) —
-#                 the tail chunk's hashing/scalars/sort then hide behind the
-#                 head chunk's kernels. Reuses the planner's one warm chunk
-#                 bucket: no new compiled shapes.
-#   stream_floor  minimum rows for the in-budget 2-chunk stream (default
-#                 2048: below it the extra dispatch outweighs the hidden
-#                 prep; the floor also keeps tiny test planner budgets out).
+#                 planner's one warm chunk bucket as ONE chunk
+#                 (_verify_batch_pipelined): no per-size shape compiles,
+#                 for the flush or for its recovery ladder.
+#   stream_floor  minimum rows for that chunk-bucket flush (default 2048:
+#                 below it the per-size `rlc` program's own, smaller lane
+#                 bucket is cheaper; keeps tiny test planner budgets out).
 #   host_stripe   stripe the HOST (no-device) RLC fallback so stripe k+1's
 #                 prep overlaps stripe k's Pippenger MSM. "auto" (default)
 #                 stripes only on multi-core hosts: on one core the overlap
@@ -363,7 +362,7 @@ def _overlap_seconds(spans, busy) -> float:
     intersection with the UNION of device-busy intervals. Replaces the
     `prep_s - blocked` heuristic, which undercounts whenever the dispatch
     thread blocks on the prep future while kernels are still executing
-    (exactly the 2-chunk pipelined shape)."""
+    (a streamed flush's later chunks)."""
     if not spans or not busy:
         return 0.0
     merged = []
@@ -1528,8 +1527,7 @@ def _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub) -> _RlcCall:
         # Early A-block upload: a cache-miss H2D transfer runs while the
         # prep pool is still hashing — the overlap this stage exists to
         # create (a _DEV_A_CACHE hit returns instantly and hides nothing;
-        # that steady state is what the 2-chunk stream above the floor is
-        # for).
+        # in that steady state the hashing stands before the dispatch).
         a_dev = _a_block()
 
     if staged:
@@ -1761,7 +1759,6 @@ def _verify_batch_rlc_streamed(
     pubkeys: Sequence[bytes],
     msgs: Sequence[bytes],
     sigs: Sequence[bytes],
-    chunks: "list | None" = None,
     mode: str = "streamed",
 ) -> Optional[np.ndarray]:
     """The streamed RLC combined check (see the planner note): fixed-bucket
@@ -1770,10 +1767,9 @@ def _verify_batch_rlc_streamed(
     combined check passes, None -> the caller recovers the exact per-row
     mask chunk by chunk.
 
-    `chunks` overrides the planner's row spans: the in-budget 2-chunk
-    pipelined stream (_verify_batch_pipelined, ISSUE 18) passes an
-    asymmetric [(0, head), (head, n)] split through the SAME warm chunk
-    bucket. Prep/device overlap is windowed accounting (_overlap_seconds):
+    An in-budget flush (_verify_batch_pipelined, mode "pipelined") is the
+    planner's one span [(0, n)]: no partial_fold, no prep hidden behind a
+    kernel. Prep/device overlap is windowed accounting (_overlap_seconds):
     prep-task wall spans intersected with the union of device-busy
     intervals (each chunk's submit-return through its sync-return)."""
     from collections import deque
@@ -1785,8 +1781,7 @@ def _verify_batch_rlc_streamed(
     counters0 = dict(msm_jax.flush_counters())
     n = len(pubkeys)
     na_c = planner_budget() // 2
-    if chunks is None:
-        chunks = _planner_chunks(n)
+    chunks = _planner_chunks(n)
     pool = _prep_pool()
     flush_span = _trace.current()  # rlc.pipelined / rlc.streamed: the workers' parent
     prechecks: list = [None] * len(chunks)
@@ -2030,33 +2025,30 @@ def _run_sharded_stream(
 
 
 def _verify_batch_pipelined(
-    pubkeys: Sequence[bytes], msgs: Sequence[bytes], sigs: Sequence[bytes],
-    whole: bool = False,
+    pubkeys: Sequence[bytes], msgs: Sequence[bytes], sigs: Sequence[bytes]
 ) -> Optional[np.ndarray]:
-    """In-budget 2-chunk stream (ISSUE 18): a single flush above the stream
-    floor rides the flush planner as TWO asymmetric chunks — head =
-    max(RLC_MIN, n//8) submits first, so the tail chunk's hashing/scalars/
-    sort run on the prep pool while the head chunk's kernels execute. Both
-    chunks pad to the planner's ONE warm chunk bucket (planner_budget()//2
-    rows), so no new shapes compile. Returns the mask when the combined
-    check passes; None -> the caller recovers through the per-signature
-    ladder (never recursively through verify_batch_jax).
+    """In-budget flush above the stream floor: all n rows as ONE chunk on
+    the planner's one warm chunk bucket (planner_budget()//2 rows), so no
+    per-size shape compiles — verify_batch_jax's fast path and the recovery
+    ladder's combined check of a sub-range (_bisect_recover) alike.
+    Declines (None) only over planner_chunk_rows(). Returns the mask when
+    the combined check passes; None -> the caller recovers through the
+    per-signature ladder (never recursively through verify_batch_jax).
 
-    `whole`: all n rows as ONE chunk on that same bucket — the recovery
-    ladder's combined check of a sub-range of a flush that came down this
-    path (_bisect_recover), whatever the sub-range's size."""
+    The names (`rlc.pipelined`, mode "pipelined", path `rlc-pipelined`)
+    date from ISSUE 18's head/tail split, which ran the fixed-shape chunk
+    program twice to hide 3 ms of prep (gone, ISSUE 30). The benchmark
+    reads them: a rename belongs to a `benchmark` PR."""
     from tendermint_tpu.ops import msm_jax
 
     n = len(pubkeys)
-    head = 0 if whole else max(RLC_MIN, n // 8)
-    if not (head < n and n - head <= planner_chunk_rows()):
-        return None  # geometry the chunk bucket can't hold: single flush
-    chunks = [(0, n)] if whole else [(0, head), (head, n)]
+    if planner_engaged(n):
+        return None  # more than one chunk holds: the streamed path's
     for attempt in range(2):
         try:
             with _trace.span("rlc.pipelined", n=n):
                 return _verify_batch_rlc_streamed(
-                    pubkeys, msgs, sigs, chunks=chunks, mode="pipelined"
+                    pubkeys, msgs, sigs, mode="pipelined"
                 )
         except Exception as e:
             if attempt == 0 and msm_jax.last_submit_fused():
@@ -2536,11 +2528,11 @@ def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarr
     The failed range splits at the largest power of two below its size —
     sub-ranges land on the SAME warm pow2 lane buckets (_bucket /
     _LANE_BUCKETS) a whole-flush fast path compiled. Where the fast path
-    was the 2-chunk pipelined stream (`chunk_bucket`) it compiled ONE
-    shape, the planner's chunk bucket, and every sub-range's combined
-    check rides that as one chunk: a whole-flush program per pow2 size
-    (six of them under 10,624 rows, minutes each cold, PR 29) would load or
-    compile behind the flush while every lane of the scheduler waits.
+    was the chunk-bucket flush (`chunk_bucket`, _verify_batch_pipelined)
+    it compiled ONE shape, the planner's chunk bucket, and every
+    sub-range's combined check rides that as one chunk: a whole-flush
+    program per pow2 size (six under 10,624 rows, minutes each cold, PR 29)
+    would load or compile behind the flush while every lane waits.
     Either way recovery compiles no new shape for its combined checks.
     Each half gets one combined check (sharded when meshed);
     a passing half is done (RLC pass returns the exact precheck mask, the
@@ -2576,8 +2568,8 @@ def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarr
             if mask is not None or _sharded_runner() is not None:
                 return mask
             flushes += 1
-        if chunk_bucket and hi - lo <= planner_chunk_rows():
-            return _verify_batch_pipelined(pk, ms, sg, whole=True)
+        if chunk_bucket:  # a sub-range of an in-budget flush: it fits
+            return _verify_batch_pipelined(pk, ms, sg)
         return _verify_batch_rlc(pk, ms, sg)
 
     def _leaf(lo, hi):
@@ -2637,9 +2629,9 @@ def verify_batch_jax(
                 return mask  # LAST_JAX_PATH set to "rlc-sharded"
         else:
             if pipelined:
-                # in-budget 2-chunk stream (ISSUE 18): the tail chunk's prep
-                # hides behind the head chunk's kernels; on combined-check
-                # failure fall through to the exact per-sig ladder below
+                # in-budget flush over the stream floor: one chunk on the
+                # planner's warm bucket; on combined-check failure fall
+                # through to the exact per-sig ladder below
                 mask = _verify_batch_pipelined(pubkeys, msgs, sigs)
                 if mask is not None:
                     LAST_JAX_PATH[0] = "rlc-pipelined"
@@ -3438,8 +3430,8 @@ def prewarm(
         native.prep_pool_size()
     # The two single-flush warms below must exercise the PLAIN and CACHED-A
     # kernels even when n_vals clears the in-budget stream floor — the
-    # 2-chunk stream's shapes are the planner-chunk shapes warmed further
-    # down, not these. The staged submit path itself IS active here (one
+    # chunk-bucket flush's shapes are the planner-chunk shapes warmed
+    # further down, not these. The staged submit path itself IS active here (one
     # staged mini-flush per warm call: hash on the prep pool, hoisted sort).
     stream_prev = _PREP_CFG["stream"]
     _PREP_CFG["stream"] = False
@@ -3455,9 +3447,9 @@ def prewarm(
     if planner_chunk and _rlc_enabled():
         # minimal 2-chunk streamed flush: warms the chunk-bucket partial
         # kernel (both chunks pad to the same shape), the padd fold, and
-        # the identity check — the steady-state streamed shapes, which are
-        # ALSO the in-budget pipelined stream's shapes (it reuses the same
-        # chunk bucket, so this one warm covers both paths)
+        # the identity check — the steady-state streamed shapes. The
+        # in-budget _verify_batch_pipelined is one chunk on the same
+        # bucket (partial kernel + identity check): this warm covers it
         rows = planner_chunk_rows() + 1
         verify_batch_jax([pk] * rows, [msg] * rows, [sig] * rows)
         # ISSUE 19: also warm the SURVIVOR half-mesh chunk bucket, so the

@@ -31,8 +31,10 @@ class Ring:
         self.events, self.next_id, self.now = [], 0, 1_000_000
 
     def call(self, k, rows=ROWS, verdict="accepted", whole=True, chunks=2):
-        """One verify_commit of the 2-chunk pipelined shape; call k's spans
-        last k-dependent times so that medians can be told from means."""
+        """One verify_commit whose flush has `chunks` chunks (two, so that a
+        reader's sum over chunks can be told from one chunk's; the timed
+        cells' flushes are one chunk since PR 30); call k's spans last
+        k-dependent times so that medians can be told from means."""
         self.next_id += 1
         root = self.next_id
         t0 = self.now

@@ -230,8 +230,8 @@ def test_ladder_after_a_pipelined_flush_rides_the_chunk_bucket(
     assert batch.LAST_JAX_PATH[0] == "rlc-bisect"
     assert witness.combined == 0  # no whole-flush program at any sub-range size
     assert set(partial_lanes) == {256}  # one shape, the failed flush's own
-    # the failed flush's two chunks, then one chunk a combined check
-    assert len(partial_lanes) - 2 + witness.persig == recovery
+    # the failed flush's one chunk, then one chunk a combined check
+    assert len(partial_lanes) - 1 + witness.persig == recovery
     assert witness.persig >= 1
 
 

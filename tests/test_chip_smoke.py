@@ -25,7 +25,7 @@ def _device_reading(**over):
     r = {
         "smoke": "flush", "phase": "t", "rows": 10, "backend": "jax",
         "path": "rlc-pipelined", "jax_path": "rlc-pipelined", "mode": "pipelined",
-        "lane_bucket": 12288, "chunks": 2, "fused": True, "rlc_fallback": False,
+        "lane_bucket": 12288, "chunks": 1, "fused": True, "rlc_fallback": False,
         "recovery_flushes": None, "compile_s_inside": 0.0, "wall_s_single_run": 0.1,
     }
     r.update(over)

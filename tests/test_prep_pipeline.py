@@ -1,6 +1,7 @@
 """ISSUE 18 — prep-pipeline tests: the staged single-flush submit (hashing
 on the prep pool, A-block upload early, sort hoisted), the in-budget
-2-chunk pipelined stream, and the striped host-RLC path.
+chunk-bucket flush over the stream floor (path `rlc-pipelined`: ONE chunk
+since ISSUE 30), and the striped host-RLC path.
 
 The invariants pinned here:
   - byte identity: staged == serial == CPU verdicts, bit for bit, across
@@ -9,8 +10,9 @@ The invariants pinned here:
     LOUDLY (and the pool is still usable afterwards);
   - hot-path hash budget: a clean flush challenge-hashes every row AT MOST
     once (batch.HASH_ROWS_HASHED);
-  - the pipelined path engages only inside its geometry guard, labels
-    itself "rlc-pipelined", and records 2-chunk overlap telemetry;
+  - the pipelined path takes every flush the planner's chunk holds as
+    ONE chunk on the planner's bucket, labels itself "rlc-pipelined", and
+    records chunks / chunk_lanes / padding_lanes for the benchmark;
   - the striped host-RLC path returns verdicts identical to the unstriped
     path, including exact recovery around a tampered row.
 
@@ -143,7 +145,7 @@ def test_prep_pool_exception_fails_flush_loudly(small_rlc, monkeypatch,
 @needs_native
 def test_hash_budget_at_most_once_per_row(small_rlc, monkeypatch, prep_cfg):
     """Hot-path guard: a clean flush challenge-hashes each row EXACTLY once
-    — on the staged single flush and on the pipelined 2-chunk stream."""
+    — on the staged single flush and on the chunk-bucket flush."""
     _install_host_twins(monkeypatch)
     pks, msgs, sigs = _signed_rows(24, b"\x24")
 
@@ -162,16 +164,32 @@ def test_hash_budget_at_most_once_per_row(small_rlc, monkeypatch, prep_cfg):
 
 
 # ---------------------------------------------------------------------------
-# pipelined in-budget 2-chunk stream
+# the in-budget chunk-bucket flush (path rlc-pipelined)
+
+
+def _count_submits(monkeypatch):
+    """Call counts of the three device submits of the streamed check."""
+    from tendermint_tpu.ops import msm_jax
+
+    counts = {}
+    for name in ("rlc_partial_submit", "partial_fold_submit", "partial_identity_submit"):
+        def wrapper(*a, _name=name, _real=getattr(msm_jax, name), **k):
+            counts[_name] = counts.get(_name, 0) + 1
+            return _real(*a, **k)
+
+        monkeypatch.setattr(msm_jax, name, wrapper)
+    return counts
 
 
 def test_pipelined_byte_identical_and_telemetry(small_rlc, monkeypatch,
                                                 prep_cfg):
     """Above the stream floor (and inside the planner budget) a single
-    flush rides TWO asymmetric chunks, labels itself rlc-pipelined, and
-    records chunks/prep_overlap_s/prep_stages — verdicts byte-identical to
-    the unstriped serial flush and the CPU path."""
+    flush rides ONE chunk on the planner's bucket — one partial submit, no
+    fold, one identity check — labels itself rlc-pipelined, and records
+    chunks/prep_overlap_s/prep_stages; verdicts byte-identical to the
+    unstriped serial flush and the CPU path."""
     _install_host_twins(monkeypatch)
+    counts = _count_submits(monkeypatch)
     pks, msgs, sigs = _signed_rows(24, b"\x25")
     cpu = batch.verify_batch_cpu(pks, msgs, sigs)
 
@@ -180,24 +198,72 @@ def test_pipelined_byte_identical_and_telemetry(small_rlc, monkeypatch,
     piped = batch.verify_batch(pks, msgs, sigs, backend="jax")
     assert batch.LAST_JAX_PATH[0] == "rlc-pipelined"
     det = dict(batch.LAST_FLUSH_DETAIL)
+    assert counts == {"rlc_partial_submit": 1, "partial_identity_submit": 1}
 
     prep_cfg["stream"] = False
     single = batch.verify_batch(pks, msgs, sigs, backend="jax")
 
     assert piped.tobytes() == single.tobytes() == cpu.tobytes()
     assert piped.all()
-    assert det.get("chunks") == 2
-    assert det.get("prep_overlap_s") is not None
+    assert det.get("chunks") == 1
+    assert not det.get("prep_overlap_s")  # one chunk hides behind nothing
     assert isinstance(det.get("prep_stages"), dict)
 
 
 def test_pipelined_geometry_guard_declines(small_rlc, monkeypatch, prep_cfg):
-    """A tail chunk past the planner bucket makes _verify_batch_pipelined
-    decline (return None) instead of compiling a new shape."""
+    """The guard is the planner's chunk alone: _verify_batch_pipelined
+    takes planner_chunk_rows() rows as one chunk and declines (returns
+    None) one row over it instead of compiling a new shape."""
     _install_host_twins(monkeypatch)
-    # n=40: head = max(8, 5) = 8, tail = 32 > 31-row chunk bucket
-    pks, msgs, sigs = _signed_rows(40, b"\x26")
-    assert batch._verify_batch_pipelined(pks[:40], msgs[:40], sigs[:40]) is None
+    counts = _count_submits(monkeypatch)
+    assert batch.planner_chunk_rows() == small_rlc == 31
+    pks, msgs, sigs = _signed_rows(32, b"\x26")
+    mask = batch._verify_batch_pipelined(pks[:31], msgs[:31], sigs[:31])
+    assert mask is not None and mask.all() and len(mask) == 31
+    assert batch.LAST_FLUSH_DETAIL["chunks"] == 1
+    assert counts.pop("rlc_partial_submit") == 1
+    assert batch._verify_batch_pipelined(pks, msgs, sigs) is None
+    assert "rlc_partial_submit" not in counts  # declined before any submit
+
+
+def test_pipelined_flush_record_arithmetic(small_rlc, monkeypatch, prep_cfg):
+    """What `planner.padding_pct` reads from the flush record: a flush
+    over the floor is one chunk of the budget's lanes, padded by the
+    budget less two lanes a row and the one base-point lane."""
+    _install_host_twins(monkeypatch)
+    prep_cfg["stream"] = True
+    prep_cfg["stream_floor"] = 16
+    n, budget = 24, batch.planner_budget()
+    pks, msgs, sigs = _signed_rows(n, b"\x2d")
+    assert batch.verify_batch(pks, msgs, sigs, backend="jax").all()
+    assert batch.LAST_JAX_PATH[0] == "rlc-pipelined"
+    det = dict(batch.LAST_FLUSH_DETAIL)
+    assert det["chunks"] == 1
+    assert det["chunk_lanes"] == det["peak_lanes_in_flight"] == budget == 64
+    assert det["padding_lanes"] == budget - (2 * n + 1)
+    assert not det.get("prep_overlap_s")
+
+
+def test_pipelined_takes_every_row_count_the_chunk_holds(small_rlc, monkeypatch,
+                                                         prep_cfg):
+    """Routing by rows alone: under the floor the per-size `rlc` program,
+    from the floor to planner_chunk_rows() the chunk-bucket flush as ONE
+    chunk (no size is declined), one row more the streamed path."""
+    _install_host_twins(monkeypatch)
+    prep_cfg["stream"] = True
+    prep_cfg["stream_floor"] = 12
+    top = batch.planner_chunk_rows()
+    assert top == small_rlc
+    pks, msgs, sigs = _signed_rows(top + 1, b"\x2e")
+
+    def route(n):
+        assert batch.verify_batch_jax(pks[:n], msgs[:n], sigs[:n]).all()
+        return batch.LAST_JAX_PATH[0], batch.LAST_FLUSH_DETAIL.get("chunks")
+
+    assert route(11)[0] == "rlc"
+    for n in range(12, top + 1):
+        assert route(n) == ("rlc-pipelined", 1), n
+    assert route(top + 1) == ("rlc-streamed", 2)
 
 
 def test_pipelined_bad_row_exact_recovery(small_rlc, monkeypatch, prep_cfg):

@@ -5,7 +5,7 @@ mkdir -p chiprun_out
 python tools/proof/first_flush_thread.py thread > chiprun_out/thread.thread.json; tail -c 2500 chiprun_out/thread.thread.json
 python tools/proof/first_flush_thread.py main > chiprun_out/thread.main.json; tail -c 2500 chiprun_out/thread.main.json
 # 1. one run = one tree, at the timed size, on the chip
-time python tools/proof/catchup_tree.py > chiprun_out/hub-175.tree.json; echo TREE_RC=$?; tail -c 3000 chiprun_out/hub-175.tree.json
+time python tools/proof/call_tree.py hub-175.catchup catchup.verify_run > chiprun_out/hub-175.tree.json; echo TREE_RC=$?; tail -c 3000 chiprun_out/hub-175.tree.json
 # 2. the new cell: the control on the driver's own path, then two more traced seeds
 time python benchmark/prove.py --workload hub-175.catchup --seeds 2147487201 \
   --out chiprun_out/hub-175.control.jsonl --timeout 1200 -- --control unsent_third
