@@ -416,15 +416,10 @@ class CryptoConfig:
     # prep_threads: native prep worker-pool width for challenge hashing /
     # scalar derivation / window sort (0 = host default, min(cores, 8)).
     prep_threads: int = 0
-    # prep_staged: stage _rlc_submit's host prep (hashing on the prep pool
-    # while lane assembly + the A-block upload proceed; only the MSM gather
-    # waits on the window sort).
-    prep_staged: bool = True
-    # prep_stream: let IN-budget flushes of >= prep_stream_floor rows ride
+    # prep_stream_floor: IN-budget flushes of this many rows or more ride
     # the flush planner's one warm chunk bucket as ONE chunk (no per-size
     # program compiles for the flush or its recovery ladder); under the
     # floor a flush takes the per-size `rlc` program's smaller bucket.
-    prep_stream: bool = True
     prep_stream_floor: int = 2048
     # prep_host_stripe: stripe the HOST (no-device) RLC fallback so the
     # next stripe's prep overlaps the current Pippenger MSM. "auto" stripes

@@ -34,7 +34,7 @@ import hashlib
 import os
 import threading
 import time
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -54,10 +54,11 @@ L8 = 8 * L  # full curve-group order; scalar modulus for torsion-exact RLC
 # that can raise or hang to model a sick accelerator, exercising the full
 # degradation ladder: RLC -> per-sig -> CPU -> breaker-OPEN (sticky CPU).
 #
-# The BREAKER makes persistent failure sticky: `_verify_batch_routed` gates
-# the jax path on `allow_device()`, records every device flush outcome, and
-# degrades a failed flush to the host loop instead of raising into the
-# consensus receive loop. A daemon probe thread re-arms the device path
+# The BREAKER makes persistent failure sticky: `_flush_route` gates the jax
+# path on `allow_device()`, `_verify_batch_routed` records every device
+# flush outcome and degrades a failed flush to the host loop instead of
+# raising into the consensus receive loop. A daemon probe thread re-arms the
+# device path
 # (crypto/circuit_breaker.py; config: `[crypto] breaker_*`).
 
 _DEVICE_FAULT_HOOK = None  # callable(site: str) -> None; may raise/sleep
@@ -165,7 +166,7 @@ def _lane_bucket(m: int) -> int:
 
 # Minimum batch size for the RLC path: below this the per-signature kernel's
 # latency is fine and each extra RLC shape costs a long one-time compile.
-RLC_MIN = int(os.environ.get("TMTPU_RLC_MIN", "512"))
+RLC_MIN = 512
 
 # ---------------------------------------------------------------------------
 # Streamed flush planner (ISSUE 13). The lane-bucket ladder above tops out at
@@ -257,21 +258,17 @@ def _prep_pool():
 
 
 # ---------------------------------------------------------------------------
-# Stage-overlapped host prep (ISSUE 18). Three knobs, all `[crypto]` config
-# (node/node.py configure_prep) with env overrides for differential tests:
+# Stage-overlapped host prep (ISSUE 18). Two settings, both `[crypto]` config
+# (node/node.py configure_prep) with an env override each:
 #
-#   staged        stage `_rlc_submit`'s host prep: challenge hashing runs on
-#                 the prep pool while the dispatch thread assembles lanes and
-#                 uploads the A block, and only the MSM gather waits on the
-#                 window sort (TMTPU_PREP_STAGED=0 forces the serial path —
-#                 byte-identity is differentially pinned by tests).
-#   stream        let IN-budget flushes above `stream_floor` ride the flush
+#   stream_floor  minimum rows at which an IN-budget flush rides the flush
 #                 planner's one warm chunk bucket as ONE chunk
 #                 (_verify_batch_pipelined): no per-size shape compiles,
-#                 for the flush or for its recovery ladder.
-#   stream_floor  minimum rows for that chunk-bucket flush (default 2048:
+#                 for the flush or for its recovery ladder. Default 2048:
 #                 below it the per-size `rlc` program's own, smaller lane
-#                 bucket is cheaper; keeps tiny test planner budgets out).
+#                 bucket is cheaper; keeps tiny test planner budgets out.
+#                 Tests and the benchmark's CPU rehearsal move it to reach
+#                 either side at a few rows.
 #   host_stripe   stripe the HOST (no-device) RLC fallback so stripe k+1's
 #                 prep overlaps stripe k's Pippenger MSM. "auto" (default)
 #                 stripes only on multi-core hosts: on one core the overlap
@@ -279,10 +276,12 @@ def _prep_pool():
 #                 wall (~13% on all-distinct keys; up to ~2.4x on heavily
 #                 repeated signers, where cross-stripe per-signer
 #                 coefficient collapse is lost). True/False force it.
-
-def _prep_env_flag(name: str, default: str) -> bool:
-    return os.environ.get(name, default) != "0"
-
+#
+# `_rlc_submit` stages its host prep (challenge hashing on the prep pool
+# while the dispatch thread assembles lanes and uploads the A block; only
+# the MSM gather waits on the window sort) wherever the native library is
+# present and the rows are all ed25519: it chooses by what it sees, and
+# there is no switch.
 
 def _host_stripe_env(default: str = "auto"):
     v = os.environ.get("TMTPU_HOST_STRIPE", default)
@@ -294,8 +293,6 @@ def _host_stripe_env(default: str = "auto"):
 
 
 _PREP_CFG = {
-    "staged": _prep_env_flag("TMTPU_PREP_STAGED", "1"),
-    "stream": _prep_env_flag("TMTPU_PREP_STREAM", "1"),
     "stream_floor": max(
         1, int(os.environ.get("TMTPU_PREP_STREAM_FLOOR", "2048") or 2048)
     ),
@@ -305,8 +302,6 @@ _PREP_CFG = {
 
 def configure_prep(
     prep_threads: int | None = None,
-    staged: bool | None = None,
-    stream: bool | None = None,
     stream_floor: int | None = None,
     host_stripe=None,
 ) -> None:
@@ -319,24 +314,12 @@ def configure_prep(
         from tendermint_tpu import native
 
         native.configure_prep_threads(prep_threads or None)
-    if staged is not None:
-        _PREP_CFG["staged"] = bool(staged)
-    if stream is not None:
-        _PREP_CFG["stream"] = bool(stream)
     if stream_floor is not None:
         _PREP_CFG["stream_floor"] = max(1, int(stream_floor))
     if host_stripe is not None:
         _PREP_CFG["host_stripe"] = (
             "auto" if host_stripe == "auto" else bool(host_stripe)
         )
-
-
-def _staged_enabled() -> bool:
-    return _PREP_CFG["staged"]
-
-
-def _stream_enabled() -> bool:
-    return _PREP_CFG["stream"]
 
 
 def _stream_floor() -> int:
@@ -521,11 +504,7 @@ def verified_memo_stats() -> dict:
 # not re-measured on today's chip. Live consensus accumulates votes and
 # flushes at validator-set size (types/vote_set.py), so real flushes land
 # above this threshold.
-_JAX_MIN_BATCH = int(os.environ.get("TMTPU_JAX_MIN", "256"))
-
-
-def _rlc_enabled() -> bool:
-    return os.environ.get("TMTPU_RLC", "1") != "0"
+_JAX_MIN_BATCH = 256
 
 
 def backend_default() -> str:
@@ -559,7 +538,7 @@ def backend_default() -> str:
 # cofactor-torsion defect is annihilated and an all-pass batch verifies the
 # combined equation EXACTLY; any failure falls back to the serial loop for
 # the exact per-row mask (same contract as the device RLC ladder).
-_HOST_RLC_MIN = int(os.environ.get("TMTPU_HOST_RLC_MIN", "48"))
+_HOST_RLC_MIN = 48
 
 # decompressed-pubkey cache for the host path (the admission workload
 # re-verifies few distinct signers; consensus re-verifies one valset)
@@ -641,8 +620,8 @@ def _verify_batch_cpu_rlc(pubkeys, msgs, sigs) -> Optional[np.ndarray]:
     Per-chunk coefficient collapse + the per-chunk B term keep the
     accumulated sum exactly equal to the single-MSM equation.
 
-    STRIPED (ISSUE 18): with the prep stream enabled and n above the
-    stream floor, the flush splits into stripes and stripe k+1's prep
+    STRIPED (ISSUE 18): with host striping on (_host_stripe_on) and n at
+    or above the stream floor, the flush splits into stripes and stripe k+1's prep
     (precheck, challenge hashing, scalar lifting, z sampling) runs on the
     prep pool while the dispatch thread runs stripe k's decompress +
     Pippenger MSM — the host path's equivalent of hiding prep behind
@@ -663,7 +642,7 @@ def _verify_batch_cpu_rlc(pubkeys, msgs, sigs) -> Optional[np.ndarray]:
     n = len(pubkeys)
     use_native = native.available()
     rng = np.random.default_rng()  # OS-entropy seeded per call
-    stream = _stream_enabled() and n >= _stream_floor() and _host_stripe_on()
+    stream = n >= _stream_floor() and _host_stripe_on()
     chunk = planner_chunk_rows()
     if stream:
         # stripes small enough that the first MSM starts early, large
@@ -819,8 +798,8 @@ def _bisect_recover_host(pubkeys, msgs, sigs) -> np.ndarray:
     device path (docs/ROBUSTNESS.md adversarial flush defense)."""
     n = len(pubkeys)
     out = np.zeros(n, dtype=bool)
-    leaf = max(_bisect_leaf_rows() // 4, 1)
-    max_bad = _bisect_max_bad()
+    leaf = max(_BISECT_LEAF // 4, 1)
+    max_bad = _BISECT_MAX_BAD
     flushes = 0
     bad_leaves = 0
 
@@ -1342,9 +1321,10 @@ def _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub) -> _RlcCall:
     mixed = key_types is not None and any(t == "sr25519" for t in key_types)
     from tendermint_tpu import native
 
-    use_native = not mixed and native.available()
-    staged = use_native and _staged_enabled()
-    hash_fut = None
+    # Two prep arms, chosen by what the rows and the host are: the staged
+    # native arm (all ed25519, the C library built), else pure Python
+    # (a mixed set's sr25519 challenges, or a host with no compiler).
+    staged = not mixed and native.available()
     prep_stages: dict = {}
     if staged:
         # Stage 1 (dispatch thread): cheap precheck + blob assembly only.
@@ -1353,7 +1333,6 @@ def _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub) -> _RlcCall:
                 pubkeys, msgs, sigs
             )
         prep_stages["precheck_s"] = st.seconds
-        s_ints = hk_ints = h_rows = None
 
         # Stage 2 (prep pool): challenge hashing runs OFF the dispatch
         # thread while lane assembly and the A-block upload proceed below.
@@ -1367,11 +1346,6 @@ def _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub) -> _RlcCall:
             return h, st.interval()
 
         hash_fut = _prep_pool().submit(_hash_task)
-    elif use_native:
-        precheck, a_rows, r_rows, s_rows, h_rows = _precheck_and_hash_fast(
-            pubkeys, msgs, sigs
-        )
-        s_ints = hk_ints = None
     else:
         precheck, a_rows, r_rows, s_ints, hk_ints = _precheck_and_hash(
             pubkeys, msgs, sigs, key_types if mixed else None
@@ -1417,13 +1391,9 @@ def _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub) -> _RlcCall:
     # A-lane scalars mod 8L (exact for points of any order; kills torsion
     # since z ≡ 0 mod 8 survives the reduction), B-lane scalar mod L.
     # Staged submits defer this until the A block is uploading — the hash
-    # future resolves right before the scalar math needs h (byte-identical:
-    # w = z·h is 0 wherever z is 0, so post-exclusion zeroing matches the
-    # serial path's pre-exclusion zeroing exactly).
-    if use_native and not staged:
-        z16, w_rows, u = _rlc_scalars_fast(precheck, s_rows, h_rows)
-        zs = w_scalars = None
-    elif not use_native:
+    # future resolves right before the scalar math needs h (w = z·h is 0
+    # wherever z is 0, so zeroing h after the exclusions above is exact).
+    if not staged:
         zs, w_scalars, u = _rlc_scalars(precheck, s_ints, hk_ints, n)
 
     b_enc = np.frombuffer(point_compress(BASE), dtype=np.uint8)
@@ -1542,7 +1512,6 @@ def _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub) -> _RlcCall:
         LAST_FLUSH_DETAIL["chunks"] = 1
         LAST_FLUSH_DETAIL["chunk_lanes"] = 2 * na
 
-    if use_native:
         # Scalars stay in the bytes domain end to end: the (2*na, 32) digit
         # rows feed the window sort directly (no bigint list round trip).
         scalars = np.zeros((2 * na, 32), dtype=np.uint8)
@@ -1551,14 +1520,6 @@ def _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub) -> _RlcCall:
             ((L - u) % L).to_bytes(32, "little"), dtype=np.uint8
         )
         scalars[na : na + n, :16] = z16  # already zeroed where ~precheck
-    else:
-        scalars = [0] * (2 * na)
-        scalars[:n] = w_scalars
-        scalars[n] = (L - u) % L
-        scalars[na : na + n] = [zs[i] if precheck[i] else 0 for i in range(n)]
-
-    presorted = None
-    if staged and not msm_jax._device_sort_enabled():
         # Window sort hoisted out of the submit helper: only the MSM gather
         # waits on it (same sort_windows the helper would run — identical
         # perm/ends), and the stage table gets an honest sort_s.
@@ -1566,31 +1527,29 @@ def _rlc_submit_spanned(pubkeys, msgs, sigs, key_types, sub) -> _RlcCall:
             digits = msm_jax.scalars_to_bytes(scalars, 2 * na)
             presorted = msm_jax.sort_windows(digits, zero16_from=na)
         prep_stages["sort_s"] = st.seconds
-    if prep_stages:
         LAST_FLUSH_DETAIL["prep_stages"] = {
             k: round(v, 6) for k, v in prep_stages.items()
         }
+    else:
+        scalars = [0] * (2 * na)
+        scalars[:n] = w_scalars
+        scalars[n] = (L - u) % L
+        scalars[na : na + n] = [zs[i] if precheck[i] else 0 for i in range(n)]
+        presorted = None  # the submit helper sorts
 
     if cached:
-        if a_dev is None:
-            a_dev = _a_block()
-        if presorted is not None:
-            dev = msm_jax.rlc_check_cached_submit(
-                a_dev, pts_r, scalars, presorted=presorted
-            )
-        else:
-            dev = msm_jax.rlc_check_cached_submit(a_dev, pts_r, scalars)
+        dev = msm_jax.rlc_check_cached_submit(
+            a_dev if a_dev is not None else _a_block(), pts_r, scalars,
+            presorted=presorted,
+        )
     else:
         pts_a = np.tile(b_enc, (na, 1))
         if precheck.any():
             pts_a[:n][precheck] = a_rows[precheck]
         pts_ar = np.concatenate([pts_a, pts_r], axis=0)
-        if presorted is not None:
-            dev = msm_jax.rlc_check_submit(
-                pts_ar, scalars, zero16_from=na, presorted=presorted
-            )
-        else:
-            dev = msm_jax.rlc_check_submit(pts_ar, scalars, zero16_from=na)
+        dev = msm_jax.rlc_check_submit(
+            pts_ar, scalars, zero16_from=na, presorted=presorted
+        )
     _record_submit_counters(msm_jax, counters0)
     return _RlcCall(
         precheck, n, na, "cached" if cached else "plain", dev,
@@ -2024,6 +1983,31 @@ def _run_sharded_stream(
     return None
 
 
+def _unfused_retry(e: Exception, retried: bool, was_fused: bool, failed: str) -> bool:
+    """What the three combined checks do with an exception from an attempt,
+    called from their `except`. A fused attempt's failure (e.g. a Mosaic
+    lowering rejection on this TPU generation) must not cost the RLC path:
+    stick to the unfused reference schedule and retry this flush once
+    (True). Any other failure (cache churn past capacity, device error) is
+    logged as `failed` and the caller answers None — it recovers exactly —
+    rather than propagating into the consensus receive loop (False).
+
+    A policy the callers' own loops ask, not a wrapper that runs them: what
+    JAX's lowering of a plain jit costs depends on the Python stack it is
+    first called under, and two frames added between `_verify_batch_rlc` and
+    `decompress_rows` took that program's lowering from 4.4 to 23 s on the
+    chip's host (PERF.md section 6, PR 31)."""
+    if was_fused and not retried:
+        from tendermint_tpu.ops import msm_jax
+
+        msm_jax.disable_fused(repr(e))
+        return True
+    import logging
+
+    logging.getLogger("tendermint_tpu.crypto.batch").exception(failed)
+    return False
+
+
 def _verify_batch_pipelined(
     pubkeys: Sequence[bytes], msgs: Sequence[bytes], sigs: Sequence[bytes]
 ) -> Optional[np.ndarray]:
@@ -2044,25 +2028,18 @@ def _verify_batch_pipelined(
     n = len(pubkeys)
     if planner_engaged(n):
         return None  # more than one chunk holds: the streamed path's
-    for attempt in range(2):
+    for retried in (False, True):
         try:
             with _trace.span("rlc.pipelined", n=n):
                 return _verify_batch_rlc_streamed(
                     pubkeys, msgs, sigs, mode="pipelined"
                 )
         except Exception as e:
-            if attempt == 0 and msm_jax.last_submit_fused():
-                # same contract as _verify_batch_streamed: one bad Mosaic
-                # compile costs one unfused retry, not the path
-                msm_jax.disable_fused(repr(e))
-                continue
-            import logging
-
-            logging.getLogger("tendermint_tpu.crypto.batch").exception(
-                "pipelined RLC failed; recovering per-signature"
-            )
-            return None
-    return None
+            if not _unfused_retry(
+                e, retried, msm_jax.last_submit_fused(),
+                "pipelined RLC failed; recovering per-signature",
+            ):
+                return None
 
 
 def _verify_batch_streamed(
@@ -2091,24 +2068,18 @@ def _verify_batch_streamed(
     # SIGNATURE: skip straight to exact recovery, a single-chip rerun of
     # the same combined check would just fail again.
     if not sharded_tried or _sharded_env() is None:
-        for attempt in range(2):
+        for retried in (False, True):
             try:
                 with _trace.span("rlc.streamed", n=len(pubkeys)):
                     mask = _verify_batch_rlc_streamed(pubkeys, msgs, sigs)
                 break
             except Exception as e:
-                if attempt == 0 and msm_jax.last_submit_fused():
-                    # same contract as _verify_batch_rlc: one bad Mosaic
-                    # compile costs one retry unfused, not the path
-                    msm_jax.disable_fused(repr(e))
-                    continue
-                import logging
-
-                logging.getLogger("tendermint_tpu.crypto.batch").exception(
-                    "streamed RLC failed; recovering chunk by chunk"
-                )
-                mask = None
-                break
+                if not _unfused_retry(
+                    e, retried, msm_jax.last_submit_fused(),
+                    "streamed RLC failed; recovering chunk by chunk",
+                ):
+                    mask = None
+                    break
         if mask is not None:
             LAST_JAX_PATH[0] = "rlc-streamed"
             return mask
@@ -2142,7 +2113,7 @@ def _verify_batch_rlc(
     from tendermint_tpu.ops import msm_jax
 
     t0 = time.perf_counter()
-    for attempt in range(2):
+    for retried in (False, True):
         call = None
         try:
             call = _rlc_submit(pubkeys, msgs, sigs, key_types)  # span rlc.submit
@@ -2150,28 +2121,17 @@ def _verify_batch_rlc(
                 mask = _rlc_finish(call)
             break
         except Exception as e:
-            import logging
-
             # Per-call fused flag when the submit completed; the module
             # global only for a failure inside the submit itself (a
             # concurrent thread's submit could have rewritten it since).
-            fused_attempt = (
+            was_fused = (
                 call.fused if call is not None else msm_jax.last_submit_fused()
             )
-            if attempt == 0 and fused_attempt:
-                # A fused-pipeline failure (e.g. a Mosaic lowering rejection
-                # on this TPU generation) must not cost the RLC path: stick
-                # to the unfused reference schedule and retry this flush.
-                msm_jax.disable_fused(repr(e))
-                continue
-            # Any other unexpected RLC-path failure (cache churn past
-            # capacity, device error) degrades to the always-correct
-            # per-signature fallback rather than propagating into the
-            # consensus receive loop.
-            logging.getLogger("tendermint_tpu.crypto.batch").exception(
-                "RLC fast path failed; falling back to per-signature verification"
-            )
-            return None
+            if not _unfused_retry(
+                e, retried, was_fused,
+                "RLC fast path failed; falling back to per-signature verification",
+            ):
+                return None
     LAST_RLC_TIMINGS.update(
         prep_ms=call.prep_seconds * 1e3,
         total_ms=(time.perf_counter() - t0) * 1e3,
@@ -2475,25 +2435,16 @@ def _bisect_enabled() -> bool:
     return os.environ.get("TMTPU_BISECT", "1") != "0"
 
 
-def _bisect_leaf_rows() -> int:
-    """Bisection stops splitting at this range size and recovers the leaf
-    per-signature: below a few hundred rows the per-sig kernel's one flush
-    beats two more combined checks."""
-    try:
-        return max(1, int(os.environ.get("TMTPU_BISECT_LEAF", "256")))
-    except ValueError:
-        return 256
+# Bisection stops splitting at this range size and recovers the leaf
+# per-signature: below a few hundred rows the per-sig kernel's one flush
+# beats two more combined checks.
+_BISECT_LEAF = 256
 
-
-def _bisect_max_bad() -> int:
-    """Adaptive bail: once this many poisoned leaves have been isolated the
-    flood is dense (high poison rate), so remaining ranges skip their
-    combined checks and go straight per-sig — bisection must never cost
-    more than the straight fallback by a growing factor."""
-    try:
-        return max(1, int(os.environ.get("TMTPU_BISECT_MAX_BAD", "8")))
-    except ValueError:
-        return 8
+# Adaptive bail: once this many poisoned leaves have been isolated the flood
+# is dense (high poison rate), so remaining ranges skip their combined
+# checks and go straight per-sig — bisection must never cost more than the
+# straight fallback by a growing factor.
+_BISECT_MAX_BAD = 8
 
 
 def _persig_flush(pubkeys, msgs, sigs, sharded) -> np.ndarray:
@@ -2539,7 +2490,7 @@ def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarr
     same invariant the fast path rests on), a failing half recurses. When
     the first half passes, the second is KNOWN bad (the parent failed) and
     descends without re-checking. Ranges at/below the leaf size — and
-    everything after _bisect_max_bad() poisoned leaves (dense flood:
+    everything after _BISECT_MAX_BAD poisoned leaves (dense flood:
     splitting costs more than it saves) — recover per-signature, the
     byte-identical code path the straight fallback has always used.
 
@@ -2551,8 +2502,8 @@ def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarr
     factor, not a linear one."""
     n = len(pubkeys)
     out = np.zeros(n, dtype=bool)
-    leaf = _bisect_leaf_rows()
-    max_bad = _bisect_max_bad()
+    leaf = _BISECT_LEAF
+    max_bad = _BISECT_MAX_BAD
     flushes = 0
     bad_leaves = 0
 
@@ -2610,54 +2561,126 @@ def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarr
     return out
 
 
+class _Route(NamedTuple):
+    """Where a flush's rows run. `path` is the label on the flush record
+    when the executor's first attempt answers (a failed combined check
+    relabels itself: `rlc-bisect`, `rlc-streamed-recovery`, `cpu-degraded`)."""
+
+    path: str
+    backend: str  # the record's `backend`: "cpu" | "jax"
+    async_ok: bool  # verify_batch_submit may leave the device work unsynced
+
+
+def _flush_route(
+    n: int,
+    backend: str | None = None,
+    key_types: Sequence[str] | None = None,
+    *,
+    on_device: bool = False,
+) -> _Route:
+    """THE routing rule: which executor takes an n-row flush, from what the
+    code can observe (the backend asked for, the breaker, the key types, the
+    row count against the thresholds, whether a mesh runner stands).
+    verify_batch, verify_batch_jax, verify_batch_submit, prewarm and the
+    scheduler's inline fallback all ask here; the executors only guard their
+    own geometry.
+
+    `on_device`: the caller already holds the decision "device"
+    (verify_batch_jax: whoever sent the rows there asked the breaker, and a
+    chunk of a streamed recovery must not change sides mid-flush); only the
+    row count and the mesh are asked."""
+    be = "jax" if on_device else (backend or backend_default())
+    device = on_device or (be == "jax" and BREAKER.allow_device())
+    if key_types is not None and any(t != "ed25519" for t in key_types):
+        # Mixed sets above the RLC threshold verify both key types in ONE
+        # device MSM (ed lanes via compressed-edwards decode, sr lanes via
+        # ristretto decode; reference verifies each vote by its key type,
+        # types/vote_set.go:203 — serial there, one batch here). Anything
+        # else takes the exact per-type split, whose ed25519 rows re-enter
+        # verify_batch: an over-budget mixed set streams through the
+        # planner that way and never compiles an over-budget shape; and the
+        # mixed kernel only knows these two types — a row of any other type
+        # carrying an ed25519-valid triple would diverge between paths.
+        if (
+            device
+            and n >= RLC_MIN
+            and not planner_engaged(n)
+            and all(t in ("ed25519", "sr25519") for t in key_types)
+            and _sharded_runner() is None
+        ):
+            # an auto-selected backend submits nothing asynchronously under
+            # _JAX_MIN_BATCH rows (seen only where a test lowers RLC_MIN
+            # beneath it)
+            return _Route("rlc-mixed", be, backend is not None or n >= _JAX_MIN_BATCH)
+        return _Route("mixed", be, False)
+    if not on_device:
+        # Auto-selected jax falls back to the host loop for tiny batches: a
+        # handful of signatures is faster on CPU than one device round
+        # trip, and a 1-2 validator chain should never block on a kernel
+        # compile. An EXPLICIT backend="jax" is honored regardless (tests,
+        # benches).
+        if be == "jax" and backend is None and n < _JAX_MIN_BATCH:
+            be = "cpu"
+        if be == "cpu":
+            return _Route("cpu", "cpu", False)
+        if be != "jax":
+            raise ValueError(f"unknown crypto backend {be!r}")
+        if not device:
+            # Breaker OPEN: sticky CPU degrade — no device submit, no retry
+            # storm; the probe thread re-arms the device path out of band.
+            return _Route("cpu-breaker", "cpu", False)
+    mesh = _sharded_runner() is not None
+    if n < RLC_MIN:
+        # the per-signature kernel's latency is fine at this size
+        return _Route("sharded" if mesh else "persig", "jax", False)
+    if planner_engaged(n):
+        # over the device budget: fixed-bucket chunks through the flush
+        # planner, which IS the submit/finish overlap, chunk-pipelined
+        return _Route("rlc-sharded-streamed" if mesh else "rlc-streamed", "jax", False)
+    if mesh:
+        return _Route("rlc-sharded", "jax", False)
+    # in-budget, one device: over the stream floor ONE chunk on the planner's
+    # warm bucket, under it the per-size program's own, smaller bucket
+    return _Route("rlc-pipelined" if n >= _stream_floor() else "rlc", "jax", True)
+
+
 def verify_batch_jax(
     pubkeys: Sequence[bytes], msgs: Sequence[bytes], sigs: Sequence[bytes]
 ) -> np.ndarray:
-    sharded = _sharded_runner()
-    if _rlc_enabled() and len(pubkeys) >= RLC_MIN:
-        pipelined = (
-            sharded is None and _stream_enabled() and len(pubkeys) >= _stream_floor()
-        )
-        if planner_engaged(len(pubkeys)):
-            # over the device budget: stream fixed-bucket chunks through the
-            # flush planner (single-device or sharded; includes its own
-            # chunked exact-mask recovery, so it always returns a mask)
-            return _verify_batch_streamed(pubkeys, msgs, sigs)
-        if sharded is not None:
-            mask = _verify_batch_rlc_sharded(pubkeys, msgs, sigs)
-            if mask is not None:
-                return mask  # LAST_JAX_PATH set to "rlc-sharded"
-        else:
-            if pipelined:
-                # in-budget flush over the stream floor: one chunk on the
-                # planner's warm bucket; on combined-check failure fall
-                # through to the exact per-sig ladder below
-                mask = _verify_batch_pipelined(pubkeys, msgs, sigs)
-                if mask is not None:
-                    LAST_JAX_PATH[0] = "rlc-pipelined"
-                    return mask
-            else:
-                mask = _verify_batch_rlc(pubkeys, msgs, sigs)
-                if mask is not None:
-                    LAST_JAX_PATH[0] = "rlc"
-                    return mask
-        # Combined check failed: at least one signature is bad (or an
-        # encoding was invalid) — recover the exact per-signature mask,
-        # bisecting over warm pow2 buckets so one poisoned row costs
-        # O(log chunks) flushes, not a monolithic per-sig pass.
-        LAST_FLUSH_DETAIL["rlc_fallback"] = True
-        if _bisect_enabled():
-            return _bisect_recover(pubkeys, msgs, sigs, chunk_bucket=pipelined)
-        # Re-fetch the mesh runner: the RLC attempt above may have rebuilt
-        # the mesh (survivor topology) or lost it entirely — the per-sig
-        # fallback must not dispatch onto a dead mesh captured earlier.
-        sharded = _sharded_runner()
-        mask = _persig_flush(pubkeys, msgs, sigs, sharded)
-        LAST_FLUSH_DETAIL["recovery_flushes"] = (
-            LAST_FLUSH_DETAIL.get("recovery_flushes", 0) + 1
-        )
+    """The device executor: the route's program first, then exact recovery
+    where a combined check said no."""
+    path = _flush_route(len(pubkeys), on_device=True).path
+    if path in ("persig", "sharded"):
+        return _persig_flush(pubkeys, msgs, sigs, _sharded_runner())
+    if path in ("rlc-streamed", "rlc-sharded-streamed"):
+        # includes its own chunked exact-mask recovery: always a mask
+        return _verify_batch_streamed(pubkeys, msgs, sigs)
+    if path == "rlc-sharded":
+        mask = _verify_batch_rlc_sharded(pubkeys, msgs, sigs)
+    elif path == "rlc-pipelined":
+        mask = _verify_batch_pipelined(pubkeys, msgs, sigs)
+    else:
+        mask = _verify_batch_rlc(pubkeys, msgs, sigs)
+    if mask is not None:
+        LAST_JAX_PATH[0] = path
         return mask
-    return _persig_flush(pubkeys, msgs, sigs, sharded)
+    # Combined check failed: at least one signature is bad (or an
+    # encoding was invalid) — recover the exact per-signature mask,
+    # bisecting over warm pow2 buckets so one poisoned row costs
+    # O(log chunks) flushes, not a monolithic per-sig pass.
+    LAST_FLUSH_DETAIL["rlc_fallback"] = True
+    if _bisect_enabled():
+        return _bisect_recover(
+            pubkeys, msgs, sigs, chunk_bucket=path == "rlc-pipelined"
+        )
+    # Re-fetch the mesh runner: the RLC attempt above may have rebuilt
+    # the mesh (survivor topology) or lost it entirely — the per-sig
+    # fallback must not dispatch onto a dead mesh captured earlier.
+    mask = _persig_flush(pubkeys, msgs, sigs, _sharded_runner())
+    LAST_FLUSH_DETAIL["recovery_flushes"] = (
+        LAST_FLUSH_DETAIL.get("recovery_flushes", 0) + 1
+    )
+    return mask
 
 
 def _verify_batch_mixed_exact(
@@ -2896,21 +2919,11 @@ def verify_batch_submit(
         mask = _LANE_ROUTER(pubkeys, msgs, sigs, backend, key_types)
         if mask is not None:
             return BatchHandle(mask=mask)
-    be = backend or backend_default()
     mixed = key_types is not None and any(t != "ed25519" for t in key_types)
-    eligible = (
-        be == "jax"
-        and BREAKER.allow_device()
-        and _rlc_enabled()
-        and len(pubkeys) >= max(RLC_MIN, _JAX_MIN_BATCH if backend is None else 0)
-        # over-budget row sets stream through the flush planner (which IS
-        # the submit/finish overlap, chunk-pipelined) via the eager path
-        and not planner_engaged(len(pubkeys))
-        and _sharded_runner() is None
-        and (not mixed or all(t in ("ed25519", "sr25519") for t in (key_types or [])))
-        and len(pubkeys) > 0
-    )
-    if not eligible:
+    if not (
+        len(pubkeys) > 0
+        and _flush_route(len(pubkeys), backend, key_types).async_ok
+    ):
         # the eager path's own memo wiring (verify_batch) covers these rows
         return BatchHandle(
             mask=verify_batch(pubkeys, msgs, sigs, backend, key_types)
@@ -2945,6 +2958,23 @@ def verify_batch_submit(
         call=call, args=(pubkeys, msgs, sigs, backend, key_types, mixed), t0=t0,
         digests=memo_digests,
     )
+
+
+# What a flush's executors leave in LAST_FLUSH_DETAIL for its record.
+_DETAIL_FIELDS = (
+    "prep_s", "transfer_s", "jit_bucket", "padding_lanes", "cache_hits",
+    "cache_misses", "fused", "h2d_bytes", "device_dispatches", "chunks",
+    "chunk_lanes", "prep_overlap_s", "prep_stages",
+)
+
+
+def _record_flush(detail: dict, **head) -> None:
+    """One flush record (libs/trace.record_flush): `head` is what the caller
+    knows (backend, path, counts, total), the rest is read out of the
+    flush's detail dictionary. `rlc_fallback` and `recovery_flushes` are the
+    caller's to pass: an async finish's detail may still hold an earlier
+    flush's."""
+    _trace.record_flush(**head, **{k: detail.get(k) for k in _DETAIL_FIELDS})
 
 
 def verify_batch_finish(h: BatchHandle) -> np.ndarray:
@@ -3007,25 +3037,13 @@ def verify_batch_finish(h: BatchHandle) -> np.ndarray:
     if mask is not None:
         h._mask = mask
         BREAKER.record_success(time.perf_counter() - t_fin)
-        _trace.record_flush(
+        _record_flush(
+            detail,
             backend="jax",
             path="rlc-async",
             n=len(pubkeys),
             total_s=time.perf_counter() - t0,
             n_valid=int(mask.sum()),
-            prep_s=detail.get("prep_s"),
-            transfer_s=detail.get("transfer_s"),
-            jit_bucket=detail.get("jit_bucket"),
-            padding_lanes=detail.get("padding_lanes"),
-            cache_hits=detail.get("cache_hits"),
-            cache_misses=detail.get("cache_misses"),
-            fused=detail.get("fused"),
-            h2d_bytes=detail.get("h2d_bytes"),
-            device_dispatches=detail.get("device_dispatches"),
-            chunks=detail.get("chunks"),
-            chunk_lanes=detail.get("chunk_lanes"),
-            prep_overlap_s=detail.get("prep_overlap_s"),
-            prep_stages=detail.get("prep_stages"),
             tracer_=tr,
         )
         _MEMO.insert(h._digests, mask)
@@ -3052,15 +3070,10 @@ def verify_batch_finish(h: BatchHandle) -> np.ndarray:
             tracer_=tr,
         )
     else:
-        from tendermint_tpu.ops.ed25519_jax import verify_prepared
-
-        a, r, s_bits, h_bits, precheck, n = prepare_batch(pubkeys, msgs, sigs)
-        t_dev = time.perf_counter()
         try:
-            _device_fault("persig")
-            h._mask = np.asarray(verify_prepared(a, r, s_bits, h_bits))[:n] & precheck
+            # no mesh: the route that made this handle found none standing
+            h._mask = _persig_flush(pubkeys, msgs, sigs, None)
         except Exception as e:
-            _trace.mark_device_call(ok=False, error=repr(e))
             h._mask = _degrade_flush_to_cpu(pubkeys, msgs, sigs, e)
             _trace.record_flush(
                 backend="cpu",
@@ -3072,15 +3085,15 @@ def verify_batch_finish(h: BatchHandle) -> np.ndarray:
                 tracer_=tr,
             )
             return h._mask
-        _trace.mark_device_call(ok=True)
-        BREAKER.record_success(time.perf_counter() - t_dev)
+        transfer_s = LAST_FLUSH_DETAIL.get("transfer_s")  # the leaf's device time
+        BREAKER.record_success(transfer_s)
         _trace.record_flush(
             backend="jax",
             path="persig-async",
             n=len(pubkeys),
             total_s=time.perf_counter() - t0,
             n_valid=int(h._mask.sum()),
-            transfer_s=time.perf_counter() - t_dev,
+            transfer_s=transfer_s,
             jit_bucket=LAST_FLUSH_DETAIL.get("jit_bucket"),
             padding_lanes=LAST_FLUSH_DETAIL.get("padding_lanes"),
             rlc_fallback=True,
@@ -3228,27 +3241,15 @@ def verify_batch(
         # and the memo insert below are the caller's time, not the flush's
         total_s = vb.elapsed()
         with _trace.span("flush.record"):
-            _trace.record_flush(
+            _record_flush(
+                detail,
                 backend=be,
                 path=path,
                 n=len(pubkeys),
                 total_s=total_s,
                 n_valid=int(mask.sum()),
-                prep_s=detail.get("prep_s"),
                 compile_s=compile_s if compile_s > 0 else None,
-                transfer_s=detail.get("transfer_s"),
-                jit_bucket=detail.get("jit_bucket"),
-                padding_lanes=detail.get("padding_lanes"),
-                cache_hits=detail.get("cache_hits"),
-                cache_misses=detail.get("cache_misses"),
                 rlc_fallback=detail.get("rlc_fallback", False),
-                fused=detail.get("fused"),
-                h2d_bytes=detail.get("h2d_bytes"),
-                device_dispatches=detail.get("device_dispatches"),
-                chunks=detail.get("chunks"),
-                chunk_lanes=detail.get("chunk_lanes"),
-                prep_overlap_s=detail.get("prep_overlap_s"),
-                prep_stages=detail.get("prep_stages"),
                 recovery_flushes=detail.get("recovery_flushes"),
                 quarantined=quarantined,
                 tracer_=_trace.tracer if vb.recording else None,
@@ -3265,70 +3266,37 @@ def verify_batch(
 def _verify_batch_routed(
     pubkeys, msgs, sigs, backend, key_types
 ) -> tuple:
-    """verify_batch's routing body; returns (mask, backend, path) so the
+    """verify_batch's executor dispatch; returns (mask, backend, path) so the
     flight recorder can label the flush with what actually ran."""
-    if key_types is not None and any(t != "ed25519" for t in key_types):
-        be = backend or backend_default()
-        # Mixed sets above the RLC threshold verify both key types in ONE
-        # device MSM (ed lanes via compressed-edwards decode, sr lanes via
-        # ristretto decode; reference verifies each vote by its key type,
-        # types/vote_set.go:203 — serial there, one batch here).
-        if (
-            be == "jax"
-            and BREAKER.allow_device()
-            and _rlc_enabled()
-            and len(pubkeys) >= RLC_MIN
-            # an over-budget MIXED set takes the exact per-type split below:
-            # its ed25519 rows re-enter verify_batch and stream through the
-            # planner, so no path ever compiles an over-budget shape
-            and not planner_engaged(len(pubkeys))
-            and _sharded_runner() is None
-            # the mixed kernel only knows these two types; any other row
-            # must take the exact per-type path (which marks unknown types
-            # False) — otherwise an unknown-type row carrying an
-            # ed25519-valid triple would diverge between paths
-            and all(t in ("ed25519", "sr25519") for t in key_types)
-        ):
-            mask = _verify_batch_rlc(pubkeys, msgs, sigs, key_types)
-            if mask is not None:
-                LAST_JAX_PATH[0] = "rlc-mixed"
-                for kt in ("ed25519", "sr25519"):
-                    kn = sum(1 for t in key_types if t == kt)
-                    if kn:
-                        record_backend_rows(kt, kn)
-                return mask, be, "rlc-mixed"
-            rlc_fell_back = True
-        else:
-            rlc_fell_back = False
+    route = _flush_route(len(pubkeys), backend, key_types)
+    rlc_fell_back = False
+    if route.path == "rlc-mixed":
+        mask = _verify_batch_rlc(pubkeys, msgs, sigs, key_types)
+        if mask is not None:
+            LAST_JAX_PATH[0] = "rlc-mixed"
+            for kt in ("ed25519", "sr25519"):
+                kn = sum(1 for t in key_types if t == kt)
+                if kn:
+                    record_backend_rows(kt, kn)
+            return mask, route.backend, "rlc-mixed"
+        rlc_fell_back = True
+    if rlc_fell_back or route.path == "mixed":
         mask = _verify_batch_mixed_exact(pubkeys, msgs, sigs, key_types, backend)
         if rlc_fell_back:
             # re-set AFTER mixed-exact: its per-type recursion through
             # verify_batch clears LAST_FLUSH_DETAIL for its own flush record
             LAST_FLUSH_DETAIL["rlc_fallback"] = True
-        return mask, be, "mixed"
-    be = backend or backend_default()
+        return mask, route.backend, "mixed"
     record_backend_rows("ed25519", len(pubkeys))
-    # Auto-selected jax falls back to the host loop for tiny batches: a
-    # handful of signatures is faster on CPU than one device round trip,
-    # and a 1-2 validator chain should never block on a kernel compile. An EXPLICIT backend="jax" is honored
-    # regardless (tests, benches).
-    if backend is None and be == "jax" and len(pubkeys) < _JAX_MIN_BATCH:
-        be = "cpu"
-    if be == "cpu":
-        return verify_batch_cpu(pubkeys, msgs, sigs), "cpu", "cpu"
-    if be == "jax":
-        if not BREAKER.allow_device():
-            # Breaker OPEN: sticky CPU degrade — no device submit, no retry
-            # storm; the probe thread re-arms the device path out of band.
-            return verify_batch_cpu(pubkeys, msgs, sigs), "cpu", "cpu-breaker"
-        t_dev = time.perf_counter()
-        try:
-            mask = verify_batch_jax(pubkeys, msgs, sigs)
-        except Exception as e:
-            return _degrade_flush_to_cpu(pubkeys, msgs, sigs, e), "cpu", "cpu-degraded"
-        BREAKER.record_success(time.perf_counter() - t_dev)
-        return mask, "jax", LAST_JAX_PATH[0]
-    raise ValueError(f"unknown crypto backend {be!r}")
+    if route.backend == "cpu":
+        return verify_batch_cpu(pubkeys, msgs, sigs), "cpu", route.path
+    t_dev = time.perf_counter()
+    try:
+        mask = verify_batch_jax(pubkeys, msgs, sigs)
+    except Exception as e:
+        return _degrade_flush_to_cpu(pubkeys, msgs, sigs, e), "cpu", "cpu-degraded"
+    BREAKER.record_success(time.perf_counter() - t_dev)
+    return mask, "jax", LAST_JAX_PATH[0]
 
 
 def _prewarm_bls() -> None:
@@ -3406,9 +3374,9 @@ def prewarm(
     nothing derivable ever enters the cache."""
     if bls:
         _prewarm_bls()
-    be = backend or backend_default()
-    if be != "jax" or n_vals < _JAX_MIN_BATCH:
-        return  # small valsets ride the host loop; nothing to compile
+    route = _flush_route(n_vals, backend)
+    if route.backend != "jax":
+        return  # a flush of this set rides the host loop; nothing to compile
     from tendermint_tpu.crypto.keys import gen_ed25519
 
     priv = gen_ed25519()
@@ -3429,22 +3397,21 @@ def prewarm(
     if native.available():
         native.prep_pool_size()
     # The two single-flush warms below must exercise the PLAIN and CACHED-A
-    # kernels even when n_vals clears the in-budget stream floor — the
-    # chunk-bucket flush's shapes are the planner-chunk shapes warmed
-    # further down, not these. The staged submit path itself IS active here (one
-    # staged mini-flush per warm call: hash on the prep pool, hoisted sort).
-    stream_prev = _PREP_CFG["stream"]
-    _PREP_CFG["stream"] = False
-    try:
-        # 1st call: A cache cold for the dummy key -> PLAIN kernel (the
-        # variant the first sight of any new validator set runs); fills the
-        # dummy entry.
-        verify_batch_jax(dummy, msgs, sigs)
-        # 2nd call: cache hit -> CACHED-A kernel (the steady-state variant).
-        verify_batch_jax(dummy, msgs, sigs)
-    finally:
-        _PREP_CFG["stream"] = stream_prev
-    if planner_chunk and _rlc_enabled():
+    # per-size kernels even when n_vals clears the stream floor (an async
+    # submit of that size runs them; the chunk-bucket flush's shapes are the
+    # planner-chunk shapes warmed further down): where the route is a
+    # single-device in-budget flush the `rlc` executor is called directly,
+    # elsewhere (per-signature sizes, a mesh) the route's own programs.
+    warm = (
+        _verify_batch_rlc if route.path in ("rlc", "rlc-pipelined")
+        else verify_batch_jax
+    )
+    # 1st call: A cache cold for the dummy key -> PLAIN kernel (the variant
+    # the first sight of any new validator set runs); fills the dummy entry.
+    warm(dummy, msgs, sigs)
+    # 2nd call: cache hit -> CACHED-A kernel (the steady-state variant).
+    warm(dummy, msgs, sigs)
+    if planner_chunk:
         # minimal 2-chunk streamed flush: warms the chunk-bucket partial
         # kernel (both chunks pad to the same shape), the padd fold, and
         # the identity check — the steady-state streamed shapes. The

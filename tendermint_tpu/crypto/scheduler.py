@@ -64,7 +64,7 @@ A consumer is never wedged where an inline verify could free it: a closed
 scheduler, or a verdict that misses `wait_timeout`, falls back to an inline
 verify_batch on the caller's thread. The one case that waits on is a ticket
 whose flush is already running AND whose inline copy would ride the device
-as well (`_JAX_MIN_BATCH` rows or more on the jax backend, breaker closed):
+as well (batch._flush_route's answer for its row count and backend):
 verifying those rows a second time would only queue behind the same compile
 or the same hung device, so the consumer waits as a direct caller of
 verify_batch would, and looks again every `wait_timeout`.
@@ -523,17 +523,11 @@ class VerifyScheduler:
 
     def _inline_on_host(self, n: int) -> bool:
         """Would an inline verify_batch of `n` rows run on the host, free of
-        the device and of whatever holds it? batch._verify_batch_routed's own
-        rule: a backend other than jax, an auto-selected jax under
-        `_JAX_MIN_BATCH` rows, or the breaker open."""
+        the device and of whatever holds it? The routing rule's answer
+        (batch._flush_route)."""
         from tendermint_tpu.crypto import batch as _batch
 
-        be = self.backend or _batch.backend_default()
-        return (
-            be != "jax"
-            or (self.backend is None and n < _batch._JAX_MIN_BATCH)
-            or not _batch.BREAKER.allow_device()
-        )
+        return _batch._flush_route(n, self.backend).backend != "jax"
 
     def _inline(self, pubkeys, msgs, sigs, key_types,
                 sources=None) -> np.ndarray:

@@ -108,8 +108,6 @@ class Node:
         # process-global, last-node-wins model as the planner/breaker)
         _batch.configure_prep(
             prep_threads=getattr(config.crypto, "prep_threads", None),
-            staged=getattr(config.crypto, "prep_staged", None),
-            stream=getattr(config.crypto, "prep_stream", None),
             stream_floor=getattr(config.crypto, "prep_stream_floor", None),
             host_stripe=_parse_host_stripe(
                 getattr(config.crypto, "prep_host_stripe", None)

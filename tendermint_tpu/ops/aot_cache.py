@@ -144,23 +144,15 @@ def _register_pytrees() -> None:
     _REGISTERED = True
 
 
-def enabled() -> bool:
-    # CPU included since r5: the test suite's kernel lane was retracing
-    # ~400k-eq jaxprs in every process (the dominant cost of `pytest -m
-    # kernel` — XLA compiles were already persistent-cached); export
-    # artifacts are keyed per backend so CPU and TPU never collide.
-    return os.environ.get("TMTPU_AOT", "1") != "0"
-
-
 def call(name: str, jit_fn, *args):
     """Call `jit_fn(*args)` through the AOT artifact cache.
 
     First use on a machine: traces + exports + serializes (background cost,
     same as before). Later processes: deserialize (~1 s) instead of
     retracing (~70 s). Falls back to the plain jit call on any export
-    machinery failure."""
-    if not enabled():
-        return jit_fn(*args)
+    machinery failure. On every backend, the CPU included: the test suite's
+    kernel lane was retracing ~400k-eq jaxprs in every process; artifacts
+    are keyed per backend, so CPU and TPU never collide."""
     key = (
         f"{name}-{jax.default_backend()}-{_machine_key()}-"
         f"{_src_hash()}-{_arg_key(args)}"

@@ -387,8 +387,5 @@ def g1_aggregate_bitmap_device(
     )
     fn = jax.jit(_bitmap_fold_jnp)
     name = f"bls_bitmap_fold_{m}"
-    if aot_cache.enabled():
-        out = aot_cache.call(name, fn, *args)
-    else:
-        out = fn(*args)
+    out = aot_cache.call(name, fn, *args)
     return point_to_affine_int(tuple(np.asarray(c) for c in out))

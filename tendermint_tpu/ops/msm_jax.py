@@ -919,41 +919,6 @@ def _rlc_core_cached(
     return jnp.concatenate([bok[None], r_ok])
 
 
-def sort_windows_device(digits: jnp.ndarray):
-    """In-graph per-window sort: digits (N, T) uint8 -> perm (T, N) int32,
-    ends (T, NBUCKETS) int32 — the device-side twin of sort_windows.
-
-    Why on device: the host counting sort is ~18 ms single-threaded at
-    20k lanes AND the perm it produces is 2x the wire size of the digits
-    it's derived from ((T,N) uint16 = 1.3 MB vs (N,T) uint8 = 655 KB at
-    ~20-40 MB/s H2D). Sorting in-graph removes both. Stability is NOT
-    required: bucket sums and Fenwick prefixes depend only on the SET of
-    lanes at each digit value, never on intra-bucket order."""
-    d_t = digits.T  # (T, N)
-    perm = jnp.argsort(d_t, axis=1).astype(jnp.int32)
-    sorted_d = jnp.take_along_axis(d_t, perm, axis=1)
-    vals = jnp.arange(NBUCKETS, dtype=sorted_d.dtype)
-    ends = jax.vmap(
-        lambda row: jnp.searchsorted(row, vals, side="right")
-    )(sorted_d).astype(jnp.int32)
-    return perm, ends
-
-
-def _rlc_core_cached_dsort(
-    ax, ay, az, at,  # (20, Na) predecompressed A block (incl. B lane)
-    r_bytes,  # (32, Nr) uint8
-    digits,  # (Na+Nr, T) uint8 scalar digit rows (window w = byte w)
-    fctx: FieldCtx,  # at shape (Nr,)
-    C: SmallCtx,
-    fused: bool = False,
-) -> jnp.ndarray:
-    """_rlc_core_cached with the window sort in-graph (sort_windows_device):
-    the host sends raw scalar digit rows; perm/ends/Fenwick nodes are all
-    derived on device."""
-    perm, ends = sort_windows_device(digits)
-    return _rlc_core_cached(ax, ay, az, at, r_bytes, perm, ends, fctx, C, fused)
-
-
 def _rlc_core_cached_mixed(
     ax, ay, az, at,  # (20, Na) predecoded A block (incl. B lane, both key types)
     ed_r_bytes,  # (32, Ne) uint8 — ed25519 R encodings
@@ -991,10 +956,6 @@ _rlc_jit = jax.jit(_rlc_core)
 _rlc_jit_fused = jax.jit(functools.partial(_rlc_core, fused=True))
 _rlc_cached_jit = jax.jit(_rlc_core_cached)
 _rlc_cached_jit_fused = jax.jit(functools.partial(_rlc_core_cached, fused=True))
-_rlc_cached_dsort_jit = jax.jit(_rlc_core_cached_dsort)
-_rlc_cached_dsort_jit_fused = jax.jit(
-    functools.partial(_rlc_core_cached_dsort, fused=True)
-)
 _rlc_cached_mixed_jit = jax.jit(_rlc_core_cached_mixed)
 _rlc_cached_mixed_jit_fused = jax.jit(
     functools.partial(_rlc_core_cached_mixed, fused=True)
@@ -1003,16 +964,6 @@ _rlc_partial_jit = jax.jit(_rlc_partial_core)
 _rlc_partial_jit_fused = jax.jit(functools.partial(_rlc_partial_core, fused=True))
 _partial_fold_jit = jax.jit(_partial_fold_core)
 _partial_identity_jit = jax.jit(_partial_identity_core)
-
-
-def _device_sort_enabled() -> bool:
-    # Default OFF: on an earlier runtime the in-graph argsort+searchsorted
-    # cost more than the host counting sort + extra 0.7 MB H2D it removes.
-    # The default is carried over, not re-measured on today's chip. Kept
-    # selectable for hosts where the tradeoff flips (slow host CPU). Scope: the pure-ed25519
-    # cached path only — the mixed ed25519+sr25519 kernel always uses the
-    # host sort.
-    return os.environ.get("TMTPU_DEVICE_SORT", "0") != "0"
 
 
 def basepoint_coords() -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
@@ -1123,10 +1074,8 @@ def rlc_check_cached_submit(
     presorted=None,
 ):
     """Cached-A variant of rlc_check_submit (A predecompressed, R by bytes).
-    `presorted=(perm, ends)` is honored on the HOST-sort arm only (the
-    device-sort arm derives perm/ends in-graph from raw digits and has no
-    host sort to skip). Returns an unsynced device bool (1+Nr,):
-    [batch_ok, r_ok...]."""
+    `presorted=(perm, ends)` skips the window sort here, as there. Returns
+    an unsynced device bool (1+Nr,): [batch_ok, r_ok...]."""
     na = a_coords[0].shape[-1]
     nr = r_bytes.shape[0]
     n = na + nr
@@ -1134,19 +1083,6 @@ def rlc_check_cached_submit(
         fctx = make_ctx((nr,))
         fused = fused_for_lanes(n)
         _set_submit_fused(fused)
-        if _device_sort_enabled():
-            # digits go down raw; perm/ends are derived in-graph
-            # (sort_windows_device) — no host sort, half the wire bytes.
-            digits = scalars_to_bytes(scalars, n)
-            return _dispatch(
-                "rlc_cached_ds_f" if fused else "rlc_cached_ds",
-                _rlc_cached_dsort_jit_fused if fused else _rlc_cached_dsort_jit,
-                *a_coords,
-                np.ascontiguousarray(r_bytes.T),
-                digits,
-                fctx,
-                make_small_ctx(),
-            )
         # rows >= na are the z-lane (128-bit scalars) + padding: zero digits
         # in windows 16-31, so the sort skips their count pass
         if presorted is not None:

@@ -18,7 +18,7 @@ CONTRACT with the device kernels replaced by ed25519_ref host twins
   bisect, streamed planner recovery, sharded-streamed recovery (fake
   mesh), host-RLC bisect, and the naive TMTPU_BISECT=0 fallback;
 - the host arm (_bisect_recover_host) keeps the same log-cost bound;
-- a dense flood trips the adaptive bail (TMTPU_BISECT_MAX_BAD) and the
+- a dense flood trips the adaptive bail (batch._BISECT_MAX_BAD) and the
   mask stays exact;
 - TMTPU_BISECT=0 restores the straight-to-per-sig arm (one recovery
   flush, identical mask).
@@ -47,7 +47,7 @@ def bisect_env(monkeypatch):
     way, verified-row memo off (a memo hit would skip the flush whose
     count this file pins)."""
     monkeypatch.setattr(batch, "RLC_MIN", 8)
-    monkeypatch.setenv("TMTPU_BISECT_LEAF", "8")
+    monkeypatch.setattr(batch, "_BISECT_LEAF", 8)
     prev = batch.planner_budget()
     batch.configure_planner(max_flush_lanes=1 << 16)
     batch.configure_verified_memo(0)
@@ -148,11 +148,11 @@ def test_two_bad_rows_cost_at_most_two_descents(bisect_env, monkeypatch):
 
 
 def test_dense_flood_trips_adaptive_bail_mask_exact(bisect_env, monkeypatch):
-    """A dense flood (half the rows poisoned) trips TMTPU_BISECT_MAX_BAD:
+    """A dense flood (half the rows poisoned) trips _BISECT_MAX_BAD:
     remaining ranges skip their combined checks and go straight per-sig,
     so bisection never costs more than the naive arm by a growing factor
     — and the mask stays exact."""
-    monkeypatch.setenv("TMTPU_BISECT_MAX_BAD", "2")
+    monkeypatch.setattr(batch, "_BISECT_MAX_BAD", 2)
     _FlushWitness(monkeypatch)
     pks, msgs, sigs = _signed_rows(64)
     sigs = list(sigs)
@@ -213,7 +213,7 @@ def test_ladder_after_a_pipelined_flush_rides_the_chunk_bucket(
     monkeypatch.setattr(msm_jax, "rlc_partial_submit", counting_partial)
     prev_cfg = dict(batch._PREP_CFG)
     batch.configure_planner(max_flush_lanes=256)  # 127 rows a chunk
-    batch._PREP_CFG.update(stream=True, stream_floor=16)
+    batch._PREP_CFG.update(stream_floor=16)
     try:
         pks, msgs, sigs = _signed_rows(100)
         sigs = list(sigs)

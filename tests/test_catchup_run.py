@@ -312,8 +312,6 @@ def test_the_device_route_hangs_in_the_runs_tree(small_rlc, prep_cfg, monkeypatc
     of the run that caused them; a run over the planner's chunk is several
     verify_batch calls under one lane.flush, which counts them."""
     _device_route(monkeypatch)
-    prep_cfg["staged"] = True
-    prep_cfg["stream"] = True
     prep_cfg["stream_floor"] = 16
     net = Net()
     rows = len(net.rows()[0])

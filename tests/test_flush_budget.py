@@ -40,7 +40,6 @@ A_BLOCK_BYTES = 4 * 20 * NA * 4  # uploaded once, then device-cached
 def stubbed_rlc(monkeypatch):
     monkeypatch.setattr(B, "RLC_MIN", 4)
     monkeypatch.setenv("TMTPU_SHARDED", "0")
-    monkeypatch.setenv("TMTPU_DEVICE_SORT", "0")
     monkeypatch.setattr(M.aot_cache, "call", lambda name, fn, *a: fn(*a))
 
     def cached_stub(ax, ay, az, at, r_bytes, perm, ends, fctx, C):

@@ -266,7 +266,6 @@ def test_commit_sign_bytes_native_every_call_and_nothing_kept(small_rlc, prep_cf
 
     n = canonical.NATIVE_MIN_ROWS + 4
     _device_route(monkeypatch)
-    prep_cfg["staged"] = True
     vals, bid, commit = _signed_commit(n)
     held = [dict(vars(commit)), [dict(vars(cs)) for cs in commit.signatures], set(vars(vals))]
     t = trace.Tracer(ring_size=256)

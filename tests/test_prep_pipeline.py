@@ -4,8 +4,9 @@ chunk-bucket flush over the stream floor (path `rlc-pipelined`: ONE chunk
 since ISSUE 30), and the striped host-RLC path.
 
 The invariants pinned here:
-  - byte identity: staged == serial == CPU verdicts, bit for bit, across
-    geometries and with precheck-rejected rows at stage boundaries;
+  - byte identity: staged == CPU verdicts, bit for bit, across geometries
+    and with precheck-rejected rows at stage boundaries (the unstaged
+    native arm went with its switch, ISSUE 31);
   - a prep-pool hashing failure latches in the future and fails the flush
     LOUDLY (and the pool is still usable afterwards);
   - hot-path hash budget: a clean flush challenge-hashes every row AT MOST
@@ -53,6 +54,9 @@ def small_rlc(monkeypatch, prep_cfg):
     batch.set_device_fault_hook(None)
 
 
+SINGLE_FLUSH = 1 << 30  # a stream floor no flush reaches: the per-size `rlc` program
+
+
 def _rows_with_rejects(n, seed=b"\x21"):
     """n signed rows with stage-boundary rejects mixed in: a non-canonical
     s (>= L, rejected at precheck BEFORE hashing), an invalid pubkey
@@ -81,18 +85,17 @@ def _rows_with_rejects(n, seed=b"\x21"):
 @pytest.mark.parametrize("n", [9, 16, 31], ids=["tiny", "pow2", "bucket-edge"])
 def test_staged_vs_serial_vs_cpu_byte_identical(small_rlc, monkeypatch,
                                                 prep_cfg, n):
-    """Staged submit == serial submit == CPU host path, bit for bit."""
+    """Staged submit == CPU host path, bit for bit."""
     _install_host_twins(monkeypatch)
     pks, msgs, sigs = _signed_rows(n, b"\x22")
     cpu = batch.verify_batch_cpu(pks, msgs, sigs)
 
-    prep_cfg["stream"] = False  # isolate the staged single flush
-    prep_cfg["staged"] = True
+    prep_cfg["stream_floor"] = SINGLE_FLUSH  # isolate the staged single flush
     staged = batch.verify_batch(pks, msgs, sigs, backend="jax")
-    prep_cfg["staged"] = False
-    serial = batch.verify_batch(pks, msgs, sigs, backend="jax")
+    assert batch.LAST_JAX_PATH[0] == "rlc"
+    assert "precheck_s" in batch.LAST_FLUSH_DETAIL["prep_stages"]  # the staged arm's
 
-    assert staged.tobytes() == serial.tobytes() == cpu.tobytes()
+    assert staged.tobytes() == cpu.tobytes()
     assert staged.all()
 
 
@@ -102,18 +105,15 @@ def test_staged_precheck_rejected_rows_at_stage_boundaries(small_rlc,
                                                            prep_cfg):
     """Rows rejected at each stage boundary (pre-hash precheck, A-fill
     exclusion, combined-check recovery) produce verdicts identical to the
-    serial path and the CPU referee."""
+    CPU referee."""
     _install_host_twins(monkeypatch)
     pks, msgs, sigs, expect = _rows_with_rejects(20)
     cpu = batch.verify_batch_cpu(pks, msgs, sigs)
 
-    prep_cfg["stream"] = False
-    prep_cfg["staged"] = True
+    prep_cfg["stream_floor"] = SINGLE_FLUSH
     staged = batch.verify_batch(pks, msgs, sigs, backend="jax")
-    prep_cfg["staged"] = False
-    serial = batch.verify_batch(pks, msgs, sigs, backend="jax")
 
-    assert staged.tobytes() == serial.tobytes() == cpu.tobytes()
+    assert staged.tobytes() == cpu.tobytes()
     assert staged.tobytes() == expect.tobytes()
 
 
@@ -123,8 +123,7 @@ def test_prep_pool_exception_fails_flush_loudly(small_rlc, monkeypatch,
     """A hashing failure on the prep pool latches in the future, re-raises
     at .result() on the dispatch thread, and leaves the pool usable."""
     _install_host_twins(monkeypatch)
-    prep_cfg["stream"] = False
-    prep_cfg["staged"] = True
+    prep_cfg["stream_floor"] = SINGLE_FLUSH
     pks, msgs, sigs = _signed_rows(12, b"\x23")
 
     real = native.ed25519_h_batch
@@ -149,13 +148,11 @@ def test_hash_budget_at_most_once_per_row(small_rlc, monkeypatch, prep_cfg):
     _install_host_twins(monkeypatch)
     pks, msgs, sigs = _signed_rows(24, b"\x24")
 
-    prep_cfg["stream"] = False
-    prep_cfg["staged"] = True
+    prep_cfg["stream_floor"] = SINGLE_FLUSH
     batch.HASH_ROWS_HASHED[0] = 0
     assert batch.verify_batch(pks, msgs, sigs, backend="jax").all()
     assert batch.HASH_ROWS_HASHED[0] == 24
 
-    prep_cfg["stream"] = True
     prep_cfg["stream_floor"] = 16
     batch.HASH_ROWS_HASHED[0] = 0
     assert batch.verify_batch(pks, msgs, sigs, backend="jax").all()
@@ -193,15 +190,15 @@ def test_pipelined_byte_identical_and_telemetry(small_rlc, monkeypatch,
     pks, msgs, sigs = _signed_rows(24, b"\x25")
     cpu = batch.verify_batch_cpu(pks, msgs, sigs)
 
-    prep_cfg["stream"] = True
     prep_cfg["stream_floor"] = 16
     piped = batch.verify_batch(pks, msgs, sigs, backend="jax")
     assert batch.LAST_JAX_PATH[0] == "rlc-pipelined"
     det = dict(batch.LAST_FLUSH_DETAIL)
     assert counts == {"rlc_partial_submit": 1, "partial_identity_submit": 1}
 
-    prep_cfg["stream"] = False
+    prep_cfg["stream_floor"] = SINGLE_FLUSH
     single = batch.verify_batch(pks, msgs, sigs, backend="jax")
+    assert batch.LAST_JAX_PATH[0] == "rlc"
 
     assert piped.tobytes() == single.tobytes() == cpu.tobytes()
     assert piped.all()
@@ -231,7 +228,6 @@ def test_pipelined_flush_record_arithmetic(small_rlc, monkeypatch, prep_cfg):
     over the floor is one chunk of the budget's lanes, padded by the
     budget less two lanes a row and the one base-point lane."""
     _install_host_twins(monkeypatch)
-    prep_cfg["stream"] = True
     prep_cfg["stream_floor"] = 16
     n, budget = 24, batch.planner_budget()
     pks, msgs, sigs = _signed_rows(n, b"\x2d")
@@ -250,7 +246,6 @@ def test_pipelined_takes_every_row_count_the_chunk_holds(small_rlc, monkeypatch,
     from the floor to planner_chunk_rows() the chunk-bucket flush as ONE
     chunk (no size is declined), one row more the streamed path."""
     _install_host_twins(monkeypatch)
-    prep_cfg["stream"] = True
     prep_cfg["stream_floor"] = 12
     top = batch.planner_chunk_rows()
     assert top == small_rlc
@@ -271,7 +266,6 @@ def test_pipelined_bad_row_exact_recovery(small_rlc, monkeypatch, prep_cfg):
     per-row mask (combined check fails -> per-signature ladder)."""
     _install_host_twins(monkeypatch)
     pks, msgs, sigs, expect = _rows_with_rejects(24, b"\x27")
-    prep_cfg["stream"] = True
     prep_cfg["stream_floor"] = 16
     mask = batch.verify_batch(pks, msgs, sigs, backend="jax")
     assert mask.tobytes() == expect.tobytes()
@@ -294,19 +288,18 @@ def _tiled_rows(n, base, seed=b"\x28"):
 
 
 def test_striped_host_rlc_parity_and_overlap(prep_cfg):
-    """The striped host-RLC path (stream on, n >= floor) returns verdicts
+    """The striped host-RLC path (striping on, n >= floor) returns verdicts
     identical to the unstriped host path, and records the pipelined
     overlap telemetry (prep_overlap_s, prep_stages, chunks)."""
     n = 2100  # 1024-row stripe floor -> 3 stripes
     pks, msgs, sigs = _tiled_rows(n, 128)
 
-    prep_cfg["stream"] = True
     prep_cfg["stream_floor"] = 512
     prep_cfg["host_stripe"] = True  # force: "auto" is off on 1-core hosts
     striped = batch.verify_batch_cpu(pks, msgs, sigs)
     det = dict(batch.LAST_FLUSH_DETAIL)
 
-    prep_cfg["stream"] = False
+    prep_cfg["host_stripe"] = False
     serial = batch.verify_batch_cpu(pks, msgs, sigs)
 
     assert striped.tobytes() == serial.tobytes()
@@ -323,11 +316,10 @@ def test_striped_host_rlc_bad_row_exact(prep_cfg):
     pks, msgs, sigs = _tiled_rows(n, 64, b"\x29")
     msgs[1050] = msgs[1050][:-1] + bytes([msgs[1050][-1] ^ 1])
 
-    prep_cfg["stream"] = True
     prep_cfg["stream_floor"] = 512
     prep_cfg["host_stripe"] = True
     striped = batch.verify_batch_cpu(pks, msgs, sigs)
-    prep_cfg["stream"] = False
+    prep_cfg["host_stripe"] = False
     serial = batch.verify_batch_cpu(pks, msgs, sigs)
 
     assert striped.tobytes() == serial.tobytes()
@@ -362,8 +354,21 @@ def test_config_plumbing_defaults():
 
     c = CryptoConfig()
     assert c.prep_threads == 0
-    assert c.prep_staged is True
-    assert c.prep_stream is True
     assert c.prep_stream_floor == 2048
     assert c.prep_host_stripe == "auto"
     assert c.verified_memo_rows == 65536
+
+
+def test_an_old_config_file_with_the_removed_keys_still_loads(tmp_path):
+    """`prep_staged` and `prep_stream` went (ISSUE 31): an operator's file
+    that still sets them loads, the two are ignored, its other keys hold."""
+    import json
+
+    from tendermint_tpu.config.config import Config
+
+    path = tmp_path / "config.json"
+    path.write_text(json.dumps({"crypto": {
+        "prep_staged": False, "prep_stream": False, "prep_stream_floor": 4096}}))
+    cfg = Config.load(str(path))
+    assert cfg.crypto.prep_stream_floor == 4096
+    assert not hasattr(cfg.crypto, "prep_staged") and not hasattr(cfg.crypto, "prep_stream")

@@ -367,9 +367,7 @@ def test_verify_commit_span_tree_and_budget(small_rlc, prep_cfg, monkeypatch, pa
     its root (the prep worker's too), and no more events than the budget."""
     if path != "cpu":
         _device_route(monkeypatch)
-    prep_cfg["staged"] = True
-    prep_cfg["stream"] = path == "rlc-pipelined"
-    prep_cfg["stream_floor"] = 16
+    prep_cfg["stream_floor"] = 16  # 24 rows ride the chunk bucket, 12 do not
     vals, bid, commit = _signed_commit(n)
     t = Tracer(ring_size=256)
     monkeypatch.setattr(trace, "tracer", t)
@@ -453,9 +451,7 @@ def test_flush_record_equals_the_spans(small_rlc, prep_cfg, monkeypatch, path, n
     from tendermint_tpu.crypto import batch as B
 
     _device_route(monkeypatch)
-    prep_cfg["staged"] = True
-    prep_cfg["stream"] = path == "rlc-pipelined"
-    prep_cfg["stream_floor"] = 16
+    prep_cfg["stream_floor"] = 16  # 24 rows ride the chunk bucket, 12 do not
     vals, bid, commit = _signed_commit(n)
     t = Tracer(ring_size=256)
     monkeypatch.setattr(trace, "tracer", t)
@@ -487,7 +483,6 @@ def test_flush_record_unchanged_with_recorder_off(small_rlc, prep_cfg, monkeypat
     """Recorder off: the bare clock pairs still fill the flush record, and
     no Span is constructed anywhere on the device route."""
     _device_route(monkeypatch)
-    prep_cfg["stream"] = True
     prep_cfg["stream_floor"] = 16
     vals, bid, commit = _signed_commit(24)
     t = Tracer(ring_size=64, enabled=False)
