@@ -780,17 +780,16 @@ def _msm_total_fused(C: SmallCtx, pts: Point, perm, ends) -> Point:
         rowtab = jnp.stack([c.T for c in pts], axis=1).reshape(n, 4 * fe.NLIMBS)
         g_rows = rowtab[perm_f.reshape(-1)]  # (T*N, 80)
 
-    # chunk trees: ONE kernel computes levels 1..lc per chunk in VMEM
+    # chunk trees: ONE kernel computes levels 1..lc per chunk in VMEM; rows
+    # in, rows out (the change of layout is the kernel's own, in VMEM)
     with jax.named_scope("uptree"):
-        ctree = PM.uptree(PM.rows_to_packed(g_rows), ch)
-        ctree_rows = PM.packed_to_rows(ctree)
+        ctree_rows = PM.uptree(g_rows, ch)  # (T*ncw*rows_out*128, 80)
 
     # top tree over the T*ncw chunk roots (tiny; existing limb-major path)
     with jax.named_scope("top_tree"):
         root_row = g.row_off[g.lc]
-        roots = ctree.reshape(4, fe.NLIMBS, t_ * ncw, g.rows_out, 128)[
-            :, :, :, root_row, 0
-        ]
+        roots = ctree_rows[root_row * 128 :: g.rows_out * 128].T  # (80, T*ncw)
+        roots = roots.reshape(4, fe.NLIMBS, t_ * ncw)
         roots_pt = Point(*(roots[c].reshape(fe.NLIMBS, t_, ncw) for c in range(4)))
         top = _tree_levels(C, roots_pt)  # (20, T, Wtop+1) incl. identity lane
         wtop1 = top.x.shape[-1]

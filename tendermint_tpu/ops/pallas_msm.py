@@ -1,4 +1,4 @@
-"""Fused MSM pipeline stages as Pallas TPU kernels (one packed limb layout).
+"""Fused MSM pipeline stages as Pallas TPU kernels.
 
 Why this exists (PERF.md rounds 4-6): the Pippenger MSM's curve arithmetic
 is ~10 ms of Pallas kernels at 10k validators, but the PIPELINE around it
@@ -7,11 +7,15 @@ between per-level `padd` calls, every Pallas wrapper re-packs (stack +
 reshape + pad) its inputs and unpacks its outputs, and the stride-2
 even/odd halving slices relayout each level before the kernel even starts.
 This module removes the inter-kernel traffic for the three memory-bound MSM
-stages by (a) standardizing ONE packed layout — int32[4, NL, S, 128], limb
-rows split into (sublane-group, 128-lane) tiles, the same layout
-ops/pallas_fe.py uses INSIDE its kernels — across kernel boundaries, and
-(b) fusing whole stages into single kernels that keep every intermediate
-level in VMEM:
+stages by (a) fusing whole stages into single kernels that keep every
+intermediate level in VMEM, computing on ONE packed form — per coordinate
+and limb a (sublane-group, 128-lane) plane, the form ops/pallas_fe.py uses
+INSIDE its kernels — and (b) keeping ONE layout outside the tree kernel:
+point ROWS of 4*NL words, which both big gathers read and write. The tree
+kernel changes rows to planes and back on its block in VMEM (PR 32; XLA
+made four copies of the two transposes, a quarter of the device's time);
+the packed (4, NL, S, 128) array crosses kernel boundaries only from the
+Fenwick gather on, where the data is a fifteenth the size:
 
   uptree          chunk-local pair-tree up-sweep: one kernel computes ALL
                   tree levels of a 2048-lane (or 1024-lane) chunk in VMEM
@@ -46,9 +50,10 @@ add instead of the in-kernel row convolution (the row math traces to ~8k HLO
 per point add — fine inside one Mosaic kernel, a compile-memory explosion as
 an XLA:CPU graph; PERF.md "what was tried and rejected"). Schedule equality
 between kernel body and twin is pinned by running both with a mocked integer
-add (tests/test_fused_msm.py), and the row math itself is pinned to the fe
-ops by tests/test_pallas_fe.py — so the CPU differential covers the fused
-schedule end to end without the Mosaic interpreter.
+add (tests/test_fused_msm.py; the tree kernel under the Pallas interpreter,
+its changes of layout go through VMEM refs), and the row math itself is
+pinned to the fe ops by tests/test_pallas_fe.py — so the CPU differential
+covers the fused schedule end to end.
 
 Enabled with ops/pallas_fe.py (TMTPU_PALLAS); the pipeline-level flag lives
 in ops/msm_jax.py (TMTPU_FUSED_MSM).
@@ -69,10 +74,14 @@ from tendermint_tpu.ops import fe25519 as fe
 from tendermint_tpu.ops import pallas_fe
 from tendermint_tpu.ops.pallas_fe import LANE, NL, _padd_rows
 
-# Observability counters (tests/test_flush_budget.py pins these): layout
-# conversions between the packed kernel layout and limb-major, per process.
-# The whole point of the packed pipeline is that these do NOT scale with
-# the number of point-op calls.
+NW = 4 * NL  # int32 words of a point row: coordinate-major, limb-minor
+
+# Observability counter (tests/test_flush_budget.py pins it): changes of
+# layout that XLA makes around the fused kernels, per traced MSM: the bucket
+# extract on the kernels' path (the tree kernel changes layout itself), and
+# the tree stage's two transposes where its CPU twin runs. The whole point of
+# the fused pipeline is that these do NOT scale with the number of point-op
+# calls.
 LAYOUT_CONVERSIONS = [0]
 
 
@@ -160,25 +169,6 @@ def fused_node_position(g: ChunkGeometry, lvl: int, k) -> "np.ndarray":
 
 
 # ---------------------------------------------------------------------------
-# Packed-layout conversions (the ONLY layout changes in the fused pipeline;
-# each is one XLA transpose of contiguous data, not a per-point-op repack).
-
-
-def rows_to_packed(rows: jnp.ndarray) -> jnp.ndarray:
-    """(M, 4*NL) point rows -> packed (4, NL, M//128, 128). M % 128 == 0."""
-    LAYOUT_CONVERSIONS[0] += 1
-    m = rows.shape[0]
-    return rows.T.reshape(4, NL, m // LANE, LANE)
-
-
-def packed_to_rows(packed: jnp.ndarray) -> jnp.ndarray:
-    """Packed (4, NL, R, 128) -> (R*128, 4*NL) point rows."""
-    LAYOUT_CONVERSIONS[0] += 1
-    r = packed.shape[2]
-    return packed.reshape(4 * NL, r * LANE).T
-
-
-# ---------------------------------------------------------------------------
 # fe25519-based point add for the CPU twins (same unified a=-1 formula as
 # msm_jax._padd; coordinates are 4-tuples of (NL, ...) arrays). The twins
 # must NOT use the in-kernel row convolution: it inlines to ~8k HLO per add,
@@ -256,72 +246,83 @@ def _stack_coords(coords) -> jnp.ndarray:
 # Stage 1: chunk-local pair-tree up-sweep.
 
 
-def _uptree_block(block: jnp.ndarray, g: ChunkGeometry, real: bool) -> jnp.ndarray:
-    """One chunk: (4, NL, rows_in, 128) bit-reversed level-0 lanes ->
-    (4, NL, rows_out, 128) concatenated levels 1..lc (see chunk_geometry)."""
-    cur = _read_coords(block)
-    out_rows: List = [[] for _ in range(4)]  # per coord: list of NL row-lists
-
-    def emit(coords):
-        for c in range(4):
-            out_rows[c].append(coords[c])
+def _uptree_block(cur, g: ChunkGeometry, real: bool):
+    """One chunk: cur = per coordinate the NL limb planes (rows_in, 128) of
+    the bit-reversed level-0 lanes -> the same shape of (rows_out, 128)
+    planes, the concatenated levels 1..lc (see chunk_geometry)."""
+    levels: List = []  # per level: 4-tuple of NL-lists of planes
 
     rows = g.rows_in
     while rows > 1:  # levels down to width 128: sublane folds
         rows //= 2
         cur = _fold_rows_coords(cur, rows, real)
-        emit(cur)
+        levels.append(cur)
     w = LANE // 2  # remaining levels fold within the single (1, 128) row
     while w >= 1:
         cur = _fold_lanes_coords(cur, w, real)
-        emit(cur)
+        levels.append(cur)
         w //= 2
     # assemble: concat emitted levels per (coord, limb), zero-pad to rows_out
-    used = sum(r[0].shape[0] for r in out_rows[0])
-    pad = g.rows_out - used
-    coords_out = []
-    for c in range(4):
-        limb_rows = []
-        for i in range(NL):
-            parts = [lvl[i] for lvl in out_rows[c]]
-            if pad:
-                parts.append(jnp.zeros((pad, LANE), jnp.int32))
-            limb_rows.append(jnp.concatenate(parts, axis=0))
-        coords_out.append(jnp.stack(limb_rows))
-    return jnp.stack(coords_out)
+    pad = g.rows_out - sum(lvl[0][0].shape[0] for lvl in levels)
+    tail = [jnp.zeros((pad, LANE), jnp.int32)] if pad else []
+    return tuple(
+        [jnp.concatenate([lvl[c][i] for lvl in levels] + tail, axis=0) for i in range(NL)]
+        for c in range(4)
+    )
 
 
 def _uptree_kernel(g: ChunkGeometry):
-    def kernel(x_ref, o_ref):
-        o_ref[:] = _uptree_block(x_ref[:], g, real=not pallas_fe._interpret())
+    """One chunk, rows in and rows out: x_ref (ch, NW) point rows of the
+    bit-reversed level-0 lanes -> o_ref (rows_out*128, NW) rows of levels
+    1..lc, tree position p at row p. The change of layout happens here, on
+    the block in VMEM: each 128-row tile is transposed on its own, and a
+    sublane-strided access through a scratch gathers (scatters) word w of
+    every tile into (from) the (rows, 128) plane _uptree_block folds."""
+
+    def kernel(x_ref, o_ref, lvl0_ref, tree_ref):
+        for s in range(g.rows_in):
+            lvl0_ref[s * NW : (s + 1) * NW, :] = x_ref[s * LANE : (s + 1) * LANE, :].T
+        lvl0 = tuple(
+            [lvl0_ref[pl.ds(c * NL + i, g.rows_in, stride=NW), :] for i in range(NL)]
+            for c in range(4)
+        )
+        tree = _uptree_block(lvl0, g, real=not pallas_fe._interpret())
+        for c in range(4):
+            for i in range(NL):
+                tree_ref[pl.ds(c * NL + i, g.rows_out, stride=NW), :] = tree[c][i]
+        for r in range(g.rows_out):
+            o_ref[r * LANE : (r + 1) * LANE, :] = tree_ref[r * NW : (r + 1) * NW, :].T
 
     return kernel
 
 
 @functools.lru_cache(maxsize=64)
-def _uptree_call(total_rows: int, ch: int):
+def _uptree_call(n_rows: int, ch: int):
     g = chunk_geometry(ch)
-    nchunks = total_rows // g.rows_in
+    nchunks = n_rows // ch
     return pl.pallas_call(
         _uptree_kernel(g),
         grid=(nchunks,),
-        in_specs=[pl.BlockSpec((4, NL, g.rows_in, LANE), lambda i: (0, 0, i, 0))],
-        out_specs=pl.BlockSpec((4, NL, g.rows_out, LANE), lambda i: (0, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (4, NL, nchunks * g.rows_out, LANE), jnp.int32
-        ),
+        in_specs=[pl.BlockSpec((ch, NW), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((g.rows_out * LANE, NW), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nchunks * g.rows_out * LANE, NW), jnp.int32),
+        scratch_shapes=[
+            pltpu.VMEM((g.rows_in * NW, LANE), jnp.int32),
+            pltpu.VMEM((g.rows_out * NW, LANE), jnp.int32),
+        ],
         interpret=pallas_fe._interpret(),
         name="msm_uptree",
     )
 
 
-def _uptree_jnp(lvl0_packed: jnp.ndarray, g: ChunkGeometry) -> jnp.ndarray:
-    """CPU twin of _uptree_block over ALL chunks at once: identical fold
-    schedule (slices for row folds, rolls for lane folds — garbage included,
-    so outputs match the kernel positionally), fe25519 point math."""
-    s = lvl0_packed.shape[2]
-    nchunks = s // g.rows_in
-    v = lvl0_packed.reshape(4, NL, nchunks, g.rows_in, LANE)
+def _uptree_jnp(lvl0_rows: jnp.ndarray, g: ChunkGeometry) -> jnp.ndarray:
+    """CPU twin of _uptree_kernel over ALL chunks at once, rows in and rows
+    out: identical fold schedule (slices for row folds, rolls for lane folds
+    — garbage included, so outputs match the kernel positionally), fe25519
+    point math. Its two changes of layout are XLA transposes, and counted."""
+    LAYOUT_CONVERSIONS[0] += 2
+    nchunks = lvl0_rows.shape[0] // g.ch
+    v = lvl0_rows.T.reshape(4, NL, nchunks, g.rows_in, LANE)
     cur = tuple(v[c] for c in range(4))  # (NL, nchunks, R, 128)
     levels = []
     rows = g.rows_in
@@ -346,18 +347,19 @@ def _uptree_jnp(lvl0_packed: jnp.ndarray, g: ChunkGeometry) -> jnp.ndarray:
             for c in range(4)
         ]
     )  # (4, NL, nchunks, rows_out, 128)
-    return out.reshape(4, NL, nchunks * g.rows_out, LANE)
+    return out.reshape(NW, nchunks * g.rows_out * LANE).T
 
 
-def uptree(lvl0_packed: jnp.ndarray, ch: int) -> jnp.ndarray:
-    """Packed bit-reversed level-0 lanes (4, NL, S, 128), S*128 a multiple of
-    ch -> packed chunk trees (4, NL, (S*128//ch)*rows_out, 128)."""
+def uptree(lvl0_rows: jnp.ndarray, ch: int) -> jnp.ndarray:
+    """Point rows (M, NW) of the bit-reversed level-0 lanes, M a multiple of
+    ch -> the chunk trees as rows ((M//ch)*rows_out*128, NW): the layout the
+    row gather writes and the Fenwick gather reads, on both sides."""
     g = chunk_geometry(ch)
-    s = lvl0_packed.shape[2]
-    assert s % g.rows_in == 0
+    m = lvl0_rows.shape[0]
+    assert m % ch == 0 and lvl0_rows.shape[1] == NW
     if pallas_fe.enabled():
-        return _uptree_call(s, ch)(lvl0_packed)
-    return _uptree_jnp(lvl0_packed, g)
+        return _uptree_call(m, ch)(lvl0_rows)
+    return _uptree_jnp(lvl0_rows, g)
 
 
 # ---------------------------------------------------------------------------

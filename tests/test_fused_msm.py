@@ -157,31 +157,60 @@ def _mock_padd_fe(p, q):
     return tuple(a + b for a, b in zip(p, q))
 
 
-@pytest.mark.parametrize("ch", [1024, 2048])
-def test_uptree_kernel_body_schedule_equals_twin(monkeypatch, ch):
+def _uptree_kernel_interpreted(monkeypatch, x: np.ndarray, ch: int) -> np.ndarray:
+    """The kernel body itself (its transposes, strided scratch accesses and
+    fold schedule) under the Pallas interpreter, with the mocked add; built
+    past the builder's cache, which would keep the mock."""
     import jax.numpy as jnp
 
+    monkeypatch.setenv("TMTPU_PALLAS", "interpret")
     monkeypatch.setattr(PM, "_padd_rows", _mock_padd_rows)
+    return np.asarray(PM._uptree_call.__wrapped__(x.shape[0], ch)(jnp.asarray(x)))
+
+
+@pytest.mark.parametrize("ch", [1024, 2048])
+def test_uptree_kernel_body_schedule_equals_twin(monkeypatch, ch):
+    """Rows in, rows out: the kernel body and _uptree_jnp agree position by
+    position, padding rows and roll-fold garbage included."""
+    import jax.numpy as jnp
+
     monkeypatch.setattr(PM, "_padd_fe", _mock_padd_fe)
     g = PM.chunk_geometry(ch)
     rng = np.random.default_rng(5)
     nchunks = 2
-    x = rng.integers(0, 1 << 20, size=(4, PM.NL, nchunks * g.rows_in, 128)).astype(
-        np.int32
-    )
+    x = rng.integers(0, 1 << 20, size=(nchunks * ch, PM.NW)).astype(np.int32)
     twin = np.asarray(PM._uptree_jnp(jnp.asarray(x), g))
-    blocks = [
-        np.asarray(
-            PM._uptree_block(
-                jnp.asarray(x[:, :, c * g.rows_in : (c + 1) * g.rows_in]),
-                g,
-                real=False,
-            )
-        )
-        for c in range(nchunks)
-    ]
-    body = np.concatenate(blocks, axis=2)
+    body = _uptree_kernel_interpreted(monkeypatch, x, ch)
+    assert twin.shape == body.shape == (nchunks * g.rows_out * 128, PM.NW)
     assert (twin == body).all()
+
+
+@pytest.mark.parametrize("ch", [1024, 2048])
+def test_uptree_rows_keep_every_word_in_place(monkeypatch, ch):
+    """A table whose rows differ in every word (word w of row m holds
+    m*80 + w), held to the integer model one word column at a time: under
+    the mocked add the columns are independent, so a transposition that
+    swapped limbs, coordinates or tiles cannot pass by symmetry."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(PM, "_padd_fe", _mock_padd_fe)
+    g = PM.chunk_geometry(ch)
+    nchunks = 2
+    x = np.arange(nchunks * ch * PM.NW, dtype=np.int32).reshape(nchunks * ch, PM.NW)
+    want = np.concatenate(
+        [
+            np.stack(
+                [
+                    _mock_uptree_chunk(x[c * ch : (c + 1) * ch, w], g)
+                    for w in range(PM.NW)
+                ],
+                axis=1,
+            )
+            for c in range(nchunks)
+        ]
+    )
+    assert (_uptree_kernel_interpreted(monkeypatch, x, ch) == want).all()
+    assert (np.asarray(PM._uptree_jnp(jnp.asarray(x), g)) == want).all()
 
 
 def test_bucket_kernel_body_schedule_equals_twin(monkeypatch):

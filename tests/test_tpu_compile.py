@@ -66,7 +66,10 @@ KERNELS = {
     "pdbl(192,16,1)": (pallas_fe._pdbl_call, (192, 16, 1), [_point(192)]),
     "fsq(192,16,16)": (pallas_fe._fsq_call, (192, 16, 16), [(NL, 192, LANE)]),
     "fenwick(12,64,8)": (pallas_msm._fenwick_call, (12, 64, 8), [(12,) + _point(64)]),
-    "uptree(6144,2048)": (pallas_msm._uptree_call, (6144, 2048), [_point(6144)]),
+    # rows in, rows out (32 windows x 24,576 lanes of the large cells' chunk
+    # bucket; x 3,072 lanes of the small cell's flush, the other chunk geometry)
+    "uptree(786432,2048)": (pallas_msm._uptree_call, (786432, 2048), [(786432, pallas_msm.NW)]),
+    "uptree(98304,1024)": (pallas_msm._uptree_call, (98304, 1024), [(98304, pallas_msm.NW)]),
     "bucket(64,32)": (pallas_msm._bucket_call, (64, 32), [_point(64)]),
 }
 
