@@ -56,29 +56,49 @@ def configure(traffic: dict) -> None:
     _vc.configure(traffic)
 
 
-def _lane_verifies_twice(rows: int = 512, flush_s: float = 0.2) -> bool:
+def _lane_verifies_twice(rows: int = 512, flush_s: float = 0.2, tries: int = 5) -> bool:
     """Asked of the program itself, on no device: a catch-up ticket of a
     device's size whose flush outlasts the lane's `wait_timeout` (here 0.05 s
     against a stand-in flush of 0.2 s; in the cell 30 s against a cold compile
-    or a recovery ladder). True where the caller's thread verified the rows
-    again."""
+    or a recovery ladder). True where the rows were verified twice: by the
+    lane's flush and again on the caller's thread.
+
+    On a loaded host the dispatch thread may not have taken the ticket when
+    the 0.05 s run out: the caller then takes it back off the queue and
+    verifies it inline, ONCE, which is the lane's rule for a ticket still
+    queued and says nothing of one in flight. That try is asked again (the
+    count of flushes tells the two apart), so that the answer does not hang
+    on how fast a thread wakes. The lane's own words about the probe's
+    tickets (a warning where one is verified inline) are the probe's, not
+    the run's, and stay out of the run's count of warnings."""
     from tendermint_tpu.config.config import SchedulerConfig
-    from tendermint_tpu.crypto import batch
+    from tendermint_tpu.crypto import batch, scheduler
     from tendermint_tpu.crypto.scheduler import VerifyScheduler
 
+    flushes = []
+
     def flush(pubkeys, *a, **kw):
+        flushes.append(len(pubkeys))
         time.sleep(flush_s)
         return np.ones(len(pubkeys), dtype=bool)
 
-    sched = VerifyScheduler(SchedulerConfig(wait_timeout=0.05, catchup_max_wait=0.0),
-                            backend="jax")
-    program, batch.verify_batch = batch.verify_batch, flush
-    try:
-        sched.verify_rows("catchup", [b"k"] * rows, [b"m"] * rows, [b"s"] * rows)
-    finally:
-        batch.verify_batch = program
-        sched.close()
-    return sched.fallbacks > 0
+    for _ in range(tries):
+        del flushes[:]
+        sched = VerifyScheduler(SchedulerConfig(wait_timeout=0.05, catchup_max_wait=0.0),
+                                backend="jax")
+        program, batch.verify_batch = batch.verify_batch, flush
+        scheduler.logger.disabled = True
+        try:
+            sched.verify_rows("catchup", [b"k"] * rows, [b"m"] * rows, [b"s"] * rows)
+        finally:
+            scheduler.logger.disabled = False
+            batch.verify_batch = program
+            sched.close()
+        if len(flushes) > 1:
+            return True
+        if not sched.fallbacks:  # the timeout struck with the flush in flight, and the caller waited
+            break
+    return False
 
 
 def _verify_batch(pubkeys, msgs, sigs, *a, **kw):

@@ -343,7 +343,7 @@ def main(argv=None) -> int:
         ctx = types.SimpleNamespace(calls=calls, trace=reduced, rows=rows, setup_s=setup_s,
                                     compile_at_warm=at_warm, compile_after=after, work=work,
                                     peaks=peaks.get(device["kind"]), window=window,
-                                    config=cell.config)
+                                    config=cell.config, traffic=cell.traffic)
         readings = {}
         for m in wanted:
             v = cell.reader(m["name"]).read(ctx)

@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
-"""Checks of the benchmark itself, on the CPU, in about a minute; not part
-of the repo's tier-1 tests.  python benchmark/selftest.py
+"""Checks of the benchmark itself, on the CPU, in a few minutes. Its tests/
+are also collected into the repo's tier-1 (tests/test_benchmark_selftests.py).
+    python benchmark/selftest.py
 
 1. BENCHMARK.json is within the contract's limits and every file it names
    by name exists (spec.lint).
@@ -17,7 +18,10 @@ of the repo's tier-1 tests.  python benchmark/selftest.py
    tests/fixtures/, and a per-layer metric) in a copy of this directory, run
    (rehearsed on the host) without a line of the harness changed.
 7. tests/: `correct` fails the control and the planted faults, of the
-   accepted cells (test_correct.py) and of the windowed one (test_room.py).
+   accepted cells (test_correct.py, test_hub175.py), of the windowed fixture
+   (test_room.py) and of the chain fixture (test_chain.py: a header and a
+   validator set of its own per height, the `broken_link` probe), whose
+   generator is also held against the program's own hashes and light client.
 """
 
 from __future__ import annotations
@@ -159,35 +163,56 @@ def read(ctx):
 WINDOWED_CELL = "skewed-48.window-6"
 
 
-def add_windowed_cell(tmp: str):
+CHAIN_CELL = "chain-16.sequence-12"
+
+
+def add_fixture_cell(tmp: str, config: str, traffic: str):
     """What a later PR does, in a copy of this directory under `tmp`: the
     files of tests/fixtures/ laid into configs/, traffic/, references/ and
-    entries/, one reader, and four entries in BENCHMARK.json. Returns the
-    copy's BENCHMARK.json and its benchmark/ directory."""
+    entries/, one reader, and four entries in BENCHMARK.json for the cell
+    `<config>.<traffic>`. Returns the copy's BENCHMARK.json and its
+    benchmark/ directory."""
     here = os.path.join(tmp, "benchmark")
     shutil.copytree(HERE, here, ignore=shutil.ignore_patterns("__pycache__", "testdata"))
     shutil.copytree(os.path.join(HERE, "tests", "fixtures"), here, dirs_exist_ok=True,
                     ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(here, "layer_metrics", "late.max_ms.py"), "w") as f:
         f.write(THROWAWAY_READER)
+    cell = f"{config}.{traffic}"
     bm = spec.load_benchmark(ROOT)
-    bm["configs"].append({"name": "skewed-48", "source": "selftest", "reduced": [],
-                          "file": "benchmark/configs/skewed-48.json", "why": "throwaway"})
-    bm["workloads"].append({"name": WINDOWED_CELL, "config": "skewed-48",
-                            "traffic": "window-6", "chips": 1, "why": "throwaway"})
+    bm["configs"].append({"name": config, "source": "selftest", "reduced": [],
+                          "file": f"benchmark/configs/{config}.json", "why": "throwaway"})
+    bm["workloads"].append({"name": cell, "config": config, "traffic": traffic, "chips": 1,
+                            "why": "throwaway"})
     bm["per_layer"].append({"name": "late.max_ms", "unit": "ms", "better": "lower",
                             "source": "host_clock", "layer": "entry points",
-                            "moves": "verify_ms_p50", "workloads": [WINDOWED_CELL]})
+                            "moves": "verify_ms_p50", "workloads": [cell]})
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(bm, f)
     return bm, here
 
 
-def run_windowed_cell(tmp: str, here: str, seed: int, control: str = "", seconds: float = 1.0):
-    cmd = [sys.executable, os.path.join(here, "run.py"), "--workload", WINDOWED_CELL,
-           "--seed", str(seed), "--seconds", str(seconds), "--trace", "0", "--rehearse", "48"]
+def add_windowed_cell(tmp: str):
+    """The windowed, skewed fixture (PR 28) as the cell WINDOWED_CELL."""
+    return add_fixture_cell(tmp, *WINDOWED_CELL.split("."))
+
+
+def add_chain_cell(tmp: str):
+    """The fixture chain (a header and a validator set of its own per height)
+    as the cell CHAIN_CELL."""
+    return add_fixture_cell(tmp, *CHAIN_CELL.split("."))
+
+
+def run_fixture_cell(tmp: str, here: str, cell: str, rows: int, seed: int, control: str = "",
+                     seconds: float = 1.0):
+    cmd = [sys.executable, os.path.join(here, "run.py"), "--workload", cell, "--seed", str(seed),
+           "--seconds", str(seconds), "--trace", "0", "--rehearse", str(rows)]
     return _run(cmd + (["--control", control] if control else []),
                 env={"PYTHONPATH": ROOT}, cwd=tmp)
+
+
+def run_windowed_cell(tmp: str, here: str, seed: int, control: str = "", seconds: float = 1.0):
+    return run_fixture_cell(tmp, here, WINDOWED_CELL, 48, seed, control, seconds)
 
 
 def test_add_a_cell_as_data():

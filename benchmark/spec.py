@@ -102,6 +102,29 @@ class Cell:
         return load_module(module_path(self.here, "layer_metrics", metric_name))
 
 
+def _chain_faults(cfg: dict) -> list:
+    """What a configuration's chain keys (`headers`,
+    `validator_changes_per_height`, `fresh_voting_powers`) have to be."""
+    bad = []
+    if type(cfg.get("headers", False)) is not bool:
+        bad.append(f"headers {cfg['headers']!r} is not true or false")
+    k = cfg.get("validator_changes_per_height", 0)
+    n = cfg.get("validators")
+    if type(k) is not int or k < 0 or (type(n) is int and k > n):
+        bad.append(f"validator_changes_per_height {k!r} is not a whole number from 0 to validators")
+    elif k and cfg.get("headers") is not True:
+        bad.append("validator_changes_per_height needs headers: without them a set has no hash "
+                   "to be committed to")
+    if "fresh_voting_powers" in cfg:
+        fresh = cfg["fresh_voting_powers"]
+        if not (isinstance(fresh, list) and fresh
+                and all(type(p) is int and p > 0 for p in fresh)):
+            bad.append("fresh_voting_powers is not a list of whole numbers over 0")
+        elif not (type(k) is int and k > 0):
+            bad.append("fresh_voting_powers needs validator_changes_per_height over 0")
+    return bad
+
+
 def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
     """What selftest.py holds BENCHMARK.json to; returns the faults found."""
     bad = []
@@ -122,7 +145,7 @@ def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
     for word in bm["command"]:
         if word.startswith("/") or ".." in word.split("/"):
             bad.append(f"command word {word!r} leaves the repo")
-    cfg_names, files, rules = set(), set(), {}
+    cfg_names, files, rules, chains = set(), set(), {}, set()
     for c in bm["configs"]:
         if set(c) != {"name", "source", "file", "reduced", "why"}:
             bad.append(f"config {c.get('name')}: keys {sorted(c)}")
@@ -140,6 +163,9 @@ def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
             if "voting_powers" in cfg and len(cfg["voting_powers"]) != cfg.get("validators"):
                 bad.append(f"config {c['name']}: {len(cfg['voting_powers'])} voting_powers "
                            f"for {cfg.get('validators')} validators")
+            bad += [f"config {c['name']}: {fault}" for fault in _chain_faults(cfg)]
+            if cfg.get("headers"):
+                chains.add(c["name"])
             if "verdict_rule" in cfg:
                 name_ok(cfg["verdict_rule"], f"config {c['name']} verdict_rule")
                 if not os.path.isfile(module_path(here, "references", str(cfg["verdict_rule"]))):
@@ -182,6 +208,14 @@ def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
             elif per_call > 1 and not rules.get(w["config"]):
                 bad.append(f"workload {w['name']}: a call of {per_call} commits needs the "
                            "configuration to name its verdict_rule")
+            if w["config"] in chains:
+                if not rules.get(w["config"]):
+                    bad.append(f"workload {w['name']}: a chain of signed headers needs the "
+                               "configuration to name its verdict_rule")
+                first = mix.get("first_height", 1)
+                if type(first) is not int or first < 2:
+                    bad.append(f"traffic {w['traffic']}: first_height {first!r}: a chain's trusted "
+                               "root stands one height under it, so it is 2 or more")
     if cfg_names - used:
         bad.append(f"configs used by no cell: {sorted(cfg_names - used)}")
     four = sum(1 for w in bm["workloads"] if w.get("chips") == 4)

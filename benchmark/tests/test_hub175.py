@@ -83,7 +83,9 @@ def test_the_accepted_cells_report_what_they_did(accepted):
         assert by[name]["workloads"] == ACCEPTED
     names = {m["name"] for m in spec.Cell(BM, accepted).per_layer}
     assert set(PR26) <= names and not names & set(NEW + FLUSH)
-    assert len(names) == (18 if accepted.startswith("commit-10k") else 17)
+    assert len(names) == 17  # since PR 33 the same in both: `prep.hidden_pct` (10k only) is retired
+    assert "prep.hidden_pct" not in by
+    assert not os.path.exists(os.path.join(HERE, "layer_metrics", "prep.hidden_pct.py"))
 
 
 def test_the_configuration_states_what_the_issue_names():
@@ -102,7 +104,8 @@ def test_the_mix_states_what_the_issue_names():
     want = {"entry": "blocksync_run", "loop": "closed", "callers": 1, "ring_commits": 4,
             "commits_per_call": 64, "first_height": 1000, "tampered_one_in": 0,
             "verified_memo_rows": 0, "warmup_calls": 2, "probes": 8,
-            "short_power_absent_share": 0.4, "invalid_power_probe": 1, "trace_calls": 8}
+            "short_power_absent_share": 0.4, "invalid_power_probe": 1, "trace_calls": 8,
+            "root_span": "catchup.verify_run", "root_first_span": "catchup.gather"}
     got = {k: v for k, v in TRAFFIC.items() if k != "name" and not k.startswith("why_")}
     assert got == want
 
@@ -269,7 +272,9 @@ def test_each_reader_reads_a_recorded_ring_and_nothing_from_an_empty_one():
 def _device_run_events(root: int, rows: int, t0: int) -> list:
     """One catch-up run as the device path records it (children before their
     root): the reactor's three spans, the lane's two, and under `lane.flush`
-    the ordinary flush of two chunks."""
+    the ordinary flush. Since PR 30 a run's flush is ONE chunk; this ring has
+    the prep spans and the dispatch of two, as a streamed flush writes them,
+    so that the readers' sums over a call's chunks are held to something."""
     def ev(name, start_ms, dur_ms, **attrs):
         return {"name": name, "span": root + len(out) + 1, "root": root, "attrs": attrs,
                 "t0_ns": t0 + int(start_ms * 1e6), "dur_ms": dur_ms}
@@ -314,6 +319,35 @@ def test_the_flush_of_a_run_is_read_from_the_runs_tree(monkeypatch):
         "catchup.flush.record_ms": 0.125}
 
 
+PROGRAM_SPAN_READERS = ["prep.hash_ms", "prep.scalars_ms", "prep.sort_ms", "prep.wait_ms",
+                        "prep.first_dispatch_ms", "flush.record_ms"]
+
+
+@pytest.mark.parametrize("name", PROGRAM_SPAN_READERS)
+def test_a_readers_root_is_the_cells(monkeypatch, name):
+    """program_spans.py's readers pick a cell's calls by the root span its
+    traffic file states: on a catch-up ring, under `catchup.json`'s root,
+    each reads what its `catchup.*` twin reads; under the default root
+    (`commit.verify`, a mix that states none) it finds no call."""
+    import types
+
+    import program_spans
+
+    cell = spec.Cell(BM, CELL)
+    events = [e for k in range(40) for e in _device_run_events(1000 * (k + 1), 10624, k * 10**8)]
+    monkeypatch.setattr(program_spans, "ring", lambda: events)
+    assert (TRAFFIC["root_span"], TRAFFIC["root_first_span"]) == ("catchup.verify_run",
+                                                                  "catchup.gather")
+    stated = cell.reader(name).read(types.SimpleNamespace(rows=10624, traffic=TRAFFIC))
+    twin = cell.reader("catchup." + name).read(types.SimpleNamespace(rows=10624))
+    assert stated == twin and isinstance(stated, float) and stated > 0
+    unstated = spec.load_json(os.path.join(HERE, "traffic", "verify-commit.json"))
+    assert "root_span" not in unstated
+    assert cell.reader(name).read(types.SimpleNamespace(rows=10624, traffic=unstated)) is None
+    assert cell.reader(name).read(types.SimpleNamespace(rows=10624)) is None  # no mix at all
+    assert cell.reader(name).read(types.SimpleNamespace(rows=7, traffic=TRAFFIC)) is None
+
+
 def test_under_thirty_whole_runs_the_metrics_are_left_out():
     out = record(12)
     assert out["roots"] == 12 and out["recorded"] == dict.fromkeys(NEW)
@@ -334,3 +368,41 @@ def test_a_program_whose_lane_verifies_a_run_twice_fails_cleanly_before_any_data
     assert entry._lane_verifies_twice() is True
     with pytest.raises(SystemExit, match="cannot run hub-175.catchup soundly"):
         entry.configure(TRAFFIC)
+
+
+def test_a_dispatch_thread_that_wakes_late_is_not_taken_for_a_lane_that_verifies_twice(monkeypatch):
+    """On a loaded host the lane's dispatch thread may take the probe's ticket
+    later than the probe's 0.05 s: the caller then takes it back off the queue
+    and verifies it inline, once, which is the lane's rule for a QUEUED ticket.
+    The driver's question is about a ticket in flight, so it asks again and
+    answers from a try in which the timeout struck in flight; nothing of it
+    reaches the run's count of warnings. (PR 32's tier-1 run failed here:
+    the probe took the late thread for the fault and ended the child.)"""
+    import logging
+
+    sys.path.insert(0, ROOT)
+    from tendermint_tpu.crypto.scheduler import VerifyScheduler
+
+    entry = spec.Cell(BM, CELL).entry()
+    plan, late = VerifyScheduler._plan_locked, []
+
+    def wakes_late(self):
+        if not late and any(st.queue for st in self._lanes.values()):
+            late.append(True)
+            return [], set(), False, 0.12  # looks again 0.12 s on
+        return plan(self)
+
+    said = []
+    handler = logging.Handler(level=logging.WARNING)
+    handler.emit = lambda record: said.append(record.getMessage())
+    logging.getLogger("tendermint_tpu").addHandler(handler)
+    monkeypatch.setattr(VerifyScheduler, "_plan_locked", wakes_late)
+    try:
+        assert entry._lane_verifies_twice() is False and late == [True]
+        del late[:]
+        # and the parent's rule is still found, also behind a late first try
+        monkeypatch.setattr(VerifyScheduler, "_inline_on_host", lambda self, n: True)
+        assert entry._lane_verifies_twice() is True and late == [True]
+    finally:
+        logging.getLogger("tendermint_tpu").removeHandler(handler)
+    assert said == []
