@@ -319,16 +319,7 @@ class BlocksyncReactor(Reactor):
                     spans.append((start, 0, [], False))
                     continue
                 first_id = BlockID(first.hash(), parts.header)
-                idxs, powers = [], []
-                for idx, cs_sig in enumerate(commit.signatures):
-                    if not cs_sig.for_block():
-                        continue
-                    val = vals.validators[idx]
-                    pubkeys.append(val.pub_key.bytes())
-                    idxs.append(idx)
-                    sigs.append(cs_sig.signature)
-                    key_types.append(val.pub_key.type_name())
-                    powers.append(val.voting_power)
+                idxs, powers = vals.for_block_rows(commit, pubkeys, sigs, key_types)
                 signed.append((commit, idxs))
                 ok_struct = commit.block_id == first_id and commit.height == first.header.height
                 spans.append((start, len(sigs) - start, powers, ok_struct))

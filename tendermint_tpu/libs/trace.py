@@ -126,6 +126,15 @@ class Span(_Interval):
         self.attrs.update(attrs)
         return self
 
+    def began_at(self, t0_ns: int) -> "Span":
+        """Moves an open span's start back to an earlier reading of
+        `perf_counter_ns`: for a root whose first stage ran across awaits,
+        where no thread's stack may hold a span open (another task's spans
+        would nest under it, and close out of order). The stage itself is
+        written with `interval(..., parent=root)`."""
+        self.t0_ns = t0_ns
+        return self
+
     def __enter__(self) -> "Span":
         stack = self._tracer._stack()
         parent = self._parent or (stack[-1] if stack else None)
@@ -181,6 +190,9 @@ class _NoopSpan:
     recording = False
 
     def set(self, **attrs) -> "_NoopSpan":
+        return self
+
+    def began_at(self, t0_ns: int) -> "_NoopSpan":
         return self
 
     def __enter__(self) -> "_NoopSpan":

@@ -7,7 +7,10 @@ light/detector.go), replacePrimaryWithWitness (:1018).
 
 All commit verification inside is batched over the validator axis (see
 light/verifier.py) — a bisection over a 10k-validator chain is a handful of
-device batches, not hundreds of thousands of serial verifies.
+device batches, not hundreds of thousands of serial verifies. Sequential
+verification batches over heights as well: the headers between the trusted
+one and the target are fetched and verified in runs, every signature of a
+run in one flush (_verify_sequential).
 """
 
 from __future__ import annotations
@@ -18,6 +21,9 @@ import time
 from dataclasses import dataclass
 from typing import List, Optional
 
+from tendermint_tpu.crypto import batch as _batch
+from tendermint_tpu.crypto import scheduler as _scheduler
+from tendermint_tpu.libs import trace as _trace
 from tendermint_tpu.light import verifier
 from tendermint_tpu.light.provider import Provider, ProviderError
 from tendermint_tpu.light.store import LightStore
@@ -37,6 +43,23 @@ SKIPPING = "skipping"
 
 DEFAULT_MAX_CLOCK_DRIFT_NS = 10 * NANOS  # reference: light/client.go:40
 DEFAULT_PRUNING_SIZE = 1000  # reference: light/client.go:36
+
+# Sequential verification gathers the headers between the trusted one and the
+# target in runs and verifies a run's signatures in ONE flush. A run is
+# bounded in rows, at this many of the flush planner's chunks (36,861 rows on
+# the planner's default budget: 368 headers of a 100-validator chain): over
+# the budget, so that the flush is streamed and a chunk's host prep can run
+# while the chunk before it is on the device (two fifths of it did, PERF.md
+# section 5), with a run's rows and fetched light blocks still a few MB. Three
+# is the least that holds the 333 headers light-seq-100.sequence verifies a
+# call; no other value has been tried on the chip (PERF.md section 7).
+# blocksync/reactor.py's VERIFY_BATCH_BLOCKS is the same bound for blocks.
+VERIFY_RUN_CHUNKS = 3
+
+
+def verify_run_rows() -> int:
+    """The most signature rows one run of sequential verification gathers."""
+    return VERIFY_RUN_CHUNKS * _batch.planner_chunk_rows()
 
 
 class ErrConflictingHeaders(LightError):
@@ -215,22 +238,103 @@ class Client:
         self, trusted: LightBlock, target: LightBlock, now_ns: int
     ) -> None:
         """Verify every height between trusted and target
-        (reference: light/client.go:553 verifySequential)."""
-        current = trusted
-        for h in range(trusted.height + 1, target.height + 1):
-            inter = target if h == target.height else await self._fetch_from_primary(h)
+        (reference: light/client.go:553 verifySequential), in runs: the light
+        blocks of a run are fetched, checked and verified together
+        (_verify_run), with what one verify_adjacent a height means. A run
+        holds as many headers as verify_run_rows() allows, and at least one;
+        a run of one header is the one-header call, verify_adjacent, spans and all.
+
+        A run is verified and saved on an executor thread, as the block-sync
+        reactor's is: checks, flush and store are some 0.5 s of a 333-header
+        run, and the flush may wait on the scheduler's lane, none of which the
+        event loop is to be held for. A fetch that fails ends the run before
+        it: what was fetched is verified and saved, then the fetch's error is
+        raised, as when a header was verified before the next was fetched. The
+        first header a new primary serves (_fetch_from_primary promoted a
+        witness) is a run of its own: a forged chain from it is refused on one
+        header's host verify, not on a run's fetches and the recovery ladder."""
+        bound = verify_run_rows()
+        loop = asyncio.get_running_loop()
+        current, carried = trusted, None
+        while current.height < target.height:
+            t_fetch = time.perf_counter_ns()
+            run, rows, fetch_error = [], 0, None
+            while current.height + len(run) < target.height:
+                h = current.height + len(run) + 1
+                if carried is not None:
+                    (lb, alone), carried = carried, None
+                elif h == target.height:
+                    lb, alone = target, False
+                else:
+                    primary = self.primary
+                    try:
+                        lb = await self._fetch_from_primary(h)
+                    except Exception as e:
+                        fetch_error = e
+                        break
+                    alone = self.primary is not primary
+                n = len(lb.signed_header.commit.signatures)
+                if run and (alone or rows + n > bound):
+                    carried = (lb, alone)  # the next run's first
+                    break
+                run.append(lb)
+                rows += n
+                if alone:
+                    break
+            if run:
+                fetched = (t_fetch, time.perf_counter_ns())
+                await loop.run_in_executor(
+                    None, self._verify_run, current, run, target, now_ns, fetched
+                )
+                current = run[-1]
+            if fetch_error is not None:
+                raise fetch_error
+
+    def _verify_run(
+        self, trusted: LightBlock, run: List[LightBlock], target: LightBlock,
+        now_ns: int, fetched: tuple,
+    ) -> None:
+        """One run of sequential verification: verifier.verify_adjacent_run
+        over the fetched light blocks, then the store is given the headers
+        that were verified, in order (the target is verify_light_block's to
+        save, after the witnesses), and the failure of the first height that
+        was not, if any, is raised: nothing at or past it is ever saved.
+
+        Called on an executor thread (_verify_sequential). The run's rows
+        ride the scheduler's light lane where a default scheduler stands (a
+        node: state sync), a plain accumulator where none does (the standalone
+        client), as light/service.py's windows do. One span tree a run: root
+        `light.verify_run`; `light.fetch` ran across awaits, so it is written
+        from its two clock readings."""
+        if len(run) == 1:
+            lb = run[0]
             verifier.verify_adjacent(
-                self.chain_id,
-                current.signed_header,
-                inter.signed_header,
-                inter.validator_set,
-                self.trust_options.period_ns,
-                now_ns,
-                self.max_clock_drift_ns,
+                self.chain_id, trusted.signed_header, lb.signed_header, lb.validator_set,
+                self.trust_options.period_ns, now_ns, self.max_clock_drift_ns,
             )
-            if h != target.height:
-                self.store.save_light_block(inter)
-            current = inter
+            if lb is not target:
+                self.store.save_light_block(lb)
+            return
+        with _trace.span("light.verify_run", headers=len(run)) as root:
+            root.began_at(fetched[0])
+            _trace.interval("light.fetch", *fetched, parent=root, headers=len(run))
+            sched = _scheduler.default_scheduler()
+            acc = sched.accumulate("light") if sched is not None else _batch.FlushAccumulator()
+            verified, failure = verifier.verify_adjacent_run(
+                self.chain_id, trusted.signed_header, run,
+                self.trust_options.period_ns, now_ns, self.max_clock_drift_ns, acc, root,
+            )
+            with _trace.span("light.store") as sp:
+                saved = [lb for lb in run[:verified] if lb is not target]
+                for lb in saved:
+                    self.store.save_light_block(lb)
+                sp.set(headers=len(saved))
+            if failure is None:
+                root.set(verdict="accepted")
+            else:
+                root.set(verdict=f"refused at height {run[verified].height}: "
+                                 f"{type(failure).__name__}")
+                raise failure
 
     async def _verify_skipping(
         self, trusted: LightBlock, target: LightBlock, now_ns: int

@@ -7,11 +7,18 @@ HeaderExpired (:210).
 Both commit checks ride the framework's batched verification path
 (types/validator_set.py verify_commit_light / verify_commit_light_trusting),
 so a bisection step verifies all signatures of a 10k-validator commit in one
-device batch instead of the reference's serial loop.
+device batch instead of the reference's serial loop. Sequential verification
+goes one step further (verify_adjacent_run): VerifyAdjacent is split into its
+host part (check_adjacent) and its commit part, and a run of adjacent headers
+does the first header by header and the second once, all the run's
+signatures in one flush.
 """
 
 from __future__ import annotations
 
+from typing import Optional, Sequence, Tuple
+
+from tendermint_tpu.libs import trace as _trace
 from tendermint_tpu.types.light import LightBlock, SignedHeader
 from tendermint_tpu.types.validator_set import (
     CommitVerifyError,
@@ -150,7 +157,7 @@ def verify_non_adjacent(
         raise ErrInvalidHeader(f"invalid commit: {e}") from e
 
 
-def verify_adjacent(
+def check_adjacent(
     chain_id: str,
     trusted: SignedHeader,
     untrusted: SignedHeader,
@@ -159,9 +166,11 @@ def verify_adjacent(
     now_ns: int,
     max_clock_drift_ns: int,
 ) -> None:
-    """Sequential verification (reference: light/verifier.go:95 VerifyAdjacent).
-
-    The new valset is pinned by the trusted header's NextValidatorsHash."""
+    """The host part of VerifyAdjacent (reference: light/verifier.go:95):
+    everything but the commit's signatures. The new header is one height on,
+    the trusted one has not expired, the new header and its set are sound
+    (_verify_new_header_and_vals), and the set is the one the trusted header
+    committed to (NextValidatorsHash)."""
     if untrusted.height != trusted.height + 1:
         raise ValueError("headers must be adjacent in height")
     if header_expired(trusted, trusting_period_ns, now_ns):
@@ -175,12 +184,119 @@ def verify_adjacent(
             f"new header ({untrusted.header.validators_hash.hex()})"
         )
 
+
+def _invalid_commit(e: CommitVerifyError) -> ErrInvalidHeader:
+    """What VerifyAdjacent raises for a commit its set refuses."""
+    err = ErrInvalidHeader(f"invalid commit: {e}")
+    err.__cause__ = e
+    return err
+
+
+def verify_adjacent(
+    chain_id: str,
+    trusted: SignedHeader,
+    untrusted: SignedHeader,
+    untrusted_vals: ValidatorSet,
+    trusting_period_ns: int,
+    now_ns: int,
+    max_clock_drift_ns: int,
+) -> None:
+    """Sequential verification (reference: light/verifier.go:95 VerifyAdjacent).
+
+    The new valset is pinned by the trusted header's NextValidatorsHash."""
+    check_adjacent(
+        chain_id, trusted, untrusted, untrusted_vals,
+        trusting_period_ns, now_ns, max_clock_drift_ns,
+    )
     try:
         untrusted_vals.verify_commit_light(
             chain_id, untrusted.commit.block_id, untrusted.height, untrusted.commit
         )
     except CommitVerifyError as e:
-        raise ErrInvalidHeader(f"invalid commit: {e}") from e
+        raise _invalid_commit(e)
+
+
+def verify_adjacent_run(
+    chain_id: str,
+    trusted: SignedHeader,
+    run: Sequence[LightBlock],
+    trusting_period_ns: int,
+    now_ns: int,
+    max_clock_drift_ns: int,
+    accumulator,
+    root=_trace.NOOP,
+) -> Tuple[int, Optional[Exception]]:
+    """VerifyAdjacent over a run of light blocks at consecutive heights above
+    `trusted`, with the meaning of one verify_adjacent a header in height
+    order: every host check (check_adjacent) header by header against the
+    header before it, then the for-block rows of every header under the set
+    that header carries, ALL of them in one flush of `accumulator` (a
+    crypto/batch.FlushAccumulator, or the scheduler's light-lane one), then
+    the valid ones tallied header by header by that set's power.
+
+    Returns (verified, failure): the first `verified` headers of `run` are
+    verified; `failure` is what verify_adjacent raises for the header after
+    them, or None where the whole run is. A stage that fails at a header
+    leaves that header and everything above it out of the later stages, so
+    the failure kept is the lowest height's, and at one height the one the
+    one-header path meets first.
+
+    Spans: ONE a stage a run, under the caller's `root` (`light.verify_run`,
+    which gets `rows`, `sets` and `flushes`), never one a header: a span a
+    header would roll the recorder's ring over in a few calls."""
+    from tendermint_tpu.crypto.batch import (
+        accumulate_flushes,
+        verify_batch_finish,
+        verify_batch_submit,
+    )
+
+    failure: Optional[Exception] = None
+    with _trace.span("light.header_checks") as sp:
+        prev = trusted
+        for k, lb in enumerate(run):
+            try:
+                check_adjacent(
+                    chain_id, prev, lb.signed_header, lb.validator_set,
+                    trusting_period_ns, now_ns, max_clock_drift_ns,
+                )
+            except Exception as e:
+                failure, run = e, run[:k]
+                break
+            prev = lb.signed_header
+        sp.set(headers=len(run))
+    pubkeys, sigs, key_types = [], [], []
+    blocks = []  # per header: (commit, idxs, powers, its first row)
+    with _trace.span("light.gather") as sp:
+        for k, lb in enumerate(run):
+            commit, vals = lb.signed_header.commit, lb.validator_set
+            try:
+                vals._check_commit_for(commit.block_id, lb.height, commit)
+            except CommitVerifyError as e:
+                failure, run = _invalid_commit(e), run[:k]
+                break
+            start = len(sigs)
+            idxs, powers = vals.for_block_rows(commit, pubkeys, sigs, key_types)
+            blocks.append((commit, idxs, powers, start))
+        sp.set(rows=len(sigs))
+    root.set(rows=len(sigs), sets=len({lb.header.validators_hash for lb in run}))
+    if not blocks:
+        return 0, failure
+    msgs = []
+    with _trace.span("light.sign_bytes", rows=len(sigs), headers=len(blocks)):
+        for commit, idxs, _powers, _start in blocks:
+            msgs.extend(commit.vote_sign_bytes_many(chain_id, idxs))
+    with accumulate_flushes(accumulator):
+        handle = verify_batch_submit(pubkeys, msgs, sigs, key_types=key_types)
+    accumulator.flush()
+    mask = verify_batch_finish(handle)
+    root.set(flushes=accumulator.flush_count)
+    with _trace.span("light.tally"):
+        for k, (lb, (_commit, _idxs, powers, start)) in enumerate(zip(run, blocks)):
+            tallied = sum(p for ok, p in zip(mask[start : start + len(powers)], powers) if ok)
+            needed = lb.validator_set.total_voting_power() * 2 // 3
+            if tallied <= needed:
+                return k, _invalid_commit(NotEnoughVotingPowerError(tallied, needed))
+    return len(run), failure
 
 
 def verify(

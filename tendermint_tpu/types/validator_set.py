@@ -392,6 +392,27 @@ class ValidatorSet:
                 f"invalid commit -- wrong block ID: want {block_id}, got {commit.block_id}"
             )
 
+    def for_block_rows(self, commit, pubkeys: list, sigs: list, key_types: list):
+        """The rows VerifyCommitLight's rule checks of `commit`, whose
+        signatures stand in this set's order (the caller has held the two
+        lengths equal): key, signature and key type of every for-block
+        signature appended to the caller's lists, which may already hold the
+        rows of other commits. Returns (idxs, powers) of the appended rows.
+        No span: the caller opens one a call, or one a run of commits
+        (blocksync/reactor.py, light/verifier.py), never one a commit."""
+        idxs, powers = [], []
+        validators = self.validators
+        for idx, cs in enumerate(commit.signatures):
+            if not cs.for_block():
+                continue
+            val = validators[idx]
+            pubkeys.append(val.pub_key.bytes())
+            idxs.append(idx)
+            sigs.append(cs.signature)
+            key_types.append(val.pub_key.type_name())
+            powers.append(val.voting_power)
+        return idxs, powers
+
     def begin_verify_commit_light(self, chain_id: str, block_id, height: int, commit):
         """Submit-phase of verify_commit_light: structural checks + device
         submit; returns a finish() callable that syncs, tallies, and raises
@@ -407,18 +428,9 @@ class ValidatorSet:
 
         with _commit_span("verify_commit_light", commit, height, "submitted") as root:
             self._check_commit_for(block_id, height, commit)
-            pubkeys, sigs, powers, idxs = [], [], [], []
-            key_types = []
+            pubkeys, sigs, key_types = [], [], []
             with _trace.span("commit.gather") as sp:
-                for idx, cs in enumerate(commit.signatures):
-                    if not cs.for_block():
-                        continue
-                    val = self.validators[idx]
-                    pubkeys.append(val.pub_key.bytes())
-                    idxs.append(idx)
-                    sigs.append(cs.signature)
-                    powers.append(val.voting_power)
-                    key_types.append(val.pub_key.type_name())
+                idxs, powers = self.for_block_rows(commit, pubkeys, sigs, key_types)
                 sp.set(rows=len(idxs))
             root.set(rows=len(idxs))
             msgs = _sign_bytes_spanned(commit, chain_id, idxs)

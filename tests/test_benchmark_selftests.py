@@ -3,7 +3,8 @@
 skewed cell as files (test_room.py) and that the cell hub-175.catchup is
 what its files say (test_hub175.py). They drive benchmark/run.py in child
 processes on the host backend and count as cases of this file. The files
-stay where `python benchmark/selftest.py` finds them; nothing is copied."""
+stay where `python benchmark/selftest.py` finds them; nothing is copied.
+benchmark/conftest.py is loaded with them, as pytest loads it there."""
 
 import glob
 import importlib.util
@@ -15,7 +16,8 @@ _DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 
 def _collect():
     seen = {}
-    for path in sorted(glob.glob(os.path.join(_DIR, "test_*.py"))):
+    paths = sorted(glob.glob(os.path.join(_DIR, "test_*.py")))
+    for path in [os.path.join(os.path.dirname(_DIR), "conftest.py")] + paths:
         name = "benchmark_tests_" + os.path.basename(path)[:-3]
         spec = importlib.util.spec_from_file_location(name, path)
         mod = importlib.util.module_from_spec(spec)
