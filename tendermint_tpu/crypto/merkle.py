@@ -39,14 +39,27 @@ def split_point(n: int) -> int:
     return 1 << (n - 1).bit_length() - 1
 
 
-def hash_from_byte_slices(items: Sequence[bytes]) -> bytes:
-    n = len(items)
-    if n == 0:
+def hash_from_leaf_hashes(hashes: Sequence[bytes]) -> bytes:
+    """The root over leaf hashes: the one place a root is computed.
+
+    Level by level, neighbours paired and an odd last node carried up as it
+    is. That is RFC 6962's tree: a node of level j covers the leaves
+    [i * 2^j, (i + 1) * 2^j) cut off at n, so every node's left child is a
+    full power of two, the largest below the leaves the node covers
+    (split_point)."""
+    if not hashes:
         return empty_hash()
-    if n == 1:
-        return leaf_hash(items[0])
-    k = split_point(n)
-    return inner_hash(hash_from_byte_slices(items[:k]), hash_from_byte_slices(items[k:]))
+    level = hashes
+    while len(level) > 1:
+        paired = [inner_hash(level[i], level[i + 1]) for i in range(0, len(level) - 1, 2)]
+        if len(level) & 1:
+            paired.append(level[-1])
+        level = paired
+    return level[0]
+
+
+def hash_from_byte_slices(items: Sequence[bytes]) -> bytes:
+    return hash_from_leaf_hashes([leaf_hash(item) for item in items])
 
 
 @dataclass

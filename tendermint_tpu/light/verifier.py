@@ -25,6 +25,7 @@ from tendermint_tpu.types.validator_set import (
     Fraction,
     NotEnoughVotingPowerError,
     ValidatorSet,
+    leaf_memo_counts,
 )
 
 # 1/3 — the default trust level (reference: light/trust_options.go,
@@ -243,7 +244,10 @@ def verify_adjacent_run(
 
     Spans: ONE a stage a run, under the caller's `root` (`light.verify_run`,
     which gets `rows`, `sets` and `flushes`), never one a header: a span a
-    header would roll the recorder's ring over in a few calls."""
+    header would roll the recorder's ring over in a few calls. The leaves the
+    header checks' set hashes asked for and found hashed (`set_leaves`,
+    `set_leaf_hits`: the process's counts, read before and after the loop)
+    go on `light.header_checks` and beside `rows` on the root."""
     from tendermint_tpu.crypto.batch import (
         accumulate_flushes,
         verify_batch_finish,
@@ -252,6 +256,7 @@ def verify_adjacent_run(
 
     failure: Optional[Exception] = None
     with _trace.span("light.header_checks") as sp:
+        leaves0, hits0 = leaf_memo_counts()
         prev = trusted
         for k, lb in enumerate(run):
             try:
@@ -263,7 +268,9 @@ def verify_adjacent_run(
                 failure, run = e, run[:k]
                 break
             prev = lb.signed_header
-        sp.set(headers=len(run))
+        leaves1, hits1 = leaf_memo_counts()
+        leaf_counts = dict(set_leaves=leaves1 - leaves0, set_leaf_hits=hits1 - hits0)
+        sp.set(headers=len(run), **leaf_counts)
     pubkeys, sigs, key_types = [], [], []
     blocks = []  # per header: (commit, idxs, powers, its first row)
     with _trace.span("light.gather") as sp:
@@ -278,7 +285,7 @@ def verify_adjacent_run(
             idxs, powers = vals.for_block_rows(commit, pubkeys, sigs, key_types)
             blocks.append((commit, idxs, powers, start))
         sp.set(rows=len(sigs))
-    root.set(rows=len(sigs), sets=len({lb.header.validators_hash for lb in run}))
+    root.set(rows=len(sigs), sets=len({lb.header.validators_hash for lb in run}), **leaf_counts)
     if not blocks:
         return 0, failure
     msgs = []

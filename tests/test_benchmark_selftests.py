@@ -9,6 +9,7 @@ benchmark/conftest.py is loaded with them, as pytest loads it there."""
 import glob
 import importlib.util
 import os
+import sys
 
 _DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "benchmark", "tests")
@@ -21,6 +22,8 @@ def _collect():
         name = "benchmark_tests_" + os.path.basename(path)[:-3]
         spec = importlib.util.spec_from_file_location(name, path)
         mod = importlib.util.module_from_spec(spec)
+        if path in paths:  # a test file imports another by the name pytest gives it there
+            sys.modules[os.path.basename(path)[:-3]] = mod
         spec.loader.exec_module(mod)
         for attr, obj in vars(mod).items():
             # tests, and the fixtures they ask for by name

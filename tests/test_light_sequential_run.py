@@ -23,7 +23,7 @@ import pytest
 os.environ.setdefault("TMTPU_CRYPTO_BACKEND", "cpu")
 
 from tendermint_tpu.config.config import SchedulerConfig
-from tendermint_tpu.crypto import batch, scheduler
+from tendermint_tpu.crypto import batch, merkle, scheduler
 from tendermint_tpu.crypto.scheduler import VerifyScheduler
 from tendermint_tpu.libs import trace
 from tendermint_tpu.libs.kvdb import MemDB
@@ -33,6 +33,7 @@ from tendermint_tpu.light import client as client_mod
 from tendermint_tpu.light.client import SEQUENTIAL
 from tendermint_tpu.light.provider import MockProvider
 from tendermint_tpu.light.verifier import ErrOldHeaderExpired, LightError
+from tendermint_tpu.types import validator_set
 from tendermint_tpu.types.light import LightBlock
 
 from test_prep_pipeline import needs_native, prep_cfg, small_rlc  # noqa: F401
@@ -69,6 +70,11 @@ def chain(seed, config=CONFIG, mix=MIX):
     vals = data.make_validators(seed, config)
     (item,) = data.make_ring(seed, config, mix, vals)
     return vals, item
+
+
+def plain_set_hash(vs) -> bytes:
+    """ValidatorSet.hash() by the plain formula, no memo."""
+    return merkle.hash_from_byte_slices([v.simple_bytes() for v in vs.validators])
 
 
 def light_block(c, vals=None):
@@ -214,24 +220,33 @@ def test_runs_give_what_one_header_at_a_time_and_the_plain_rule_give(runs_of_fou
         assert got["heights"] == list(range(1, k + 2))
 
 
+@pytest.mark.parametrize("memo", ["cold", "warm"])
 @pytest.mark.parametrize("k", BLOCKS)
-def test_a_set_that_is_not_the_headers_is_refused_at_its_height(runs_of_four, k):
+def test_a_set_that_is_not_the_headers_is_refused_at_its_height(runs_of_four, k, memo):
     """The primary hands over height k's header with another set than the one
     its validators_hash names: the host check of that header refuses it, the
     heights below it are verified and saved. (The target's own light block is
     held to its header before anything is fetched, as ever: verify_light_block
-    calls its validate_basic first.)"""
+    calls its validate_basic first.) With every leaf of the chain in the
+    validator sets' leaf memo as with none: the same height, error and words."""
     vals, item = chain(350 + k)
     others = data._changed(item[k].vals, 1, np.random.default_rng(k))
     blocks = [light_block(item[0].prev)] + [
         light_block(c, others if j == k else None) for j, c in enumerate(item)]
+    validator_set._leaf_memo.clear()
+    if memo == "warm":
+        for lb in blocks:
+            lb.validator_set.hash()
     got = by_runs(CONFIG, blocks)
     if k == HEADERS - 1:
         assert got["error"][0] == "ValueError" and "expected validators hash" in got["error"][1]
         assert got["heights"] == [1]
         return
     assert got == one_header_at_a_time(CONFIG, blocks)
-    assert got["error"][0] == "ErrInvalidHeader" and "to match those supplied" in got["error"][1]
+    handed_over = blocks[k + 1]
+    assert got["error"] == ("ErrInvalidHeader", (
+        f"expected new header validators ({handed_over.header.validators_hash.hex()}) to match "
+        f"those supplied ({plain_set_hash(handed_over.validator_set).hex()})"))
     assert got["heights"] == list(range(1, k + 2))
     # the plain rule, given the set that was handed over, calls the same block unlinked
     handed = data.with_commit(item, k, replace(item[k], vals=others))
@@ -482,18 +497,45 @@ def test_a_run_is_one_span_tree_with_one_span_a_stage(monkeypatch):
     children = [e for e in mine if e["parent"] == root["span"]]
     assert [e["name"] for e in sorted(children, key=lambda e: e["t0_ns"])] == STAGES
     assert mine[0]["name"] == "light.fetch" and events[-1] is root  # first written, and last
-    assert root["attrs"] == {"headers": HEADERS, "rows": HEADERS * 8, "sets": HEADERS,
-                             "flushes": 1, "verdict": "accepted"}
     by = {e["name"]: e for e in children}
+    leaves = {k: v for k, v in by["light.header_checks"]["attrs"].items() if k != "headers"}
+    assert set(leaves) == {"set_leaves", "set_leaf_hits"} and leaves["set_leaves"] == HEADERS * 8
+    assert root["attrs"] == {"headers": HEADERS, "rows": HEADERS * 8, "sets": HEADERS,
+                             "flushes": 1, "verdict": "accepted", **leaves}
     assert by["light.fetch"]["attrs"] == {"headers": HEADERS}
     assert by["light.fetch"]["t0_ns"] == root["t0_ns"]  # the root began when its fetch began
-    assert by["light.header_checks"]["attrs"] == {"headers": HEADERS}
+    assert by["light.header_checks"]["attrs"]["headers"] == HEADERS
     assert by["light.gather"]["attrs"] == {"rows": HEADERS * 8}
     assert by["light.sign_bytes"]["attrs"] == {"rows": HEADERS * 8, "headers": HEADERS}
     assert by["light.store"]["attrs"] == {"headers": HEADERS - 1}  # the target is saved later
     for e in mine:
         assert e["t0_ns"] >= root["t0_ns"]
     assert root["dur_ms"] >= sum(e["dur_ms"] for e in children) * 0.99
+
+
+def test_the_header_checks_count_the_leaves_asked_for_and_found(monkeypatch):
+    """From a cleared memo a run hashes each distinct key once: 8 for its
+    first set and the ONE key replaced at every height above; a second run of
+    the same chain finds every leaf (what a later lap of the cell's ring reads)."""
+    t = Tracer(ring_size=256)
+    monkeypatch.setattr(trace, "tracer", t)
+    vals, item, blocks = blocks_of_case(403, "sound", 0)
+    distinct = {(v.pub_key.bytes(), v.voting_power) for lb in blocks[1:]
+                for v in lb.validator_set.validators}
+    assert len(distinct) == 8 + HEADERS - 1
+    validator_set._leaf_memo.clear()
+    for lap in range(2):
+        with trace.span("light.verify_run") as root:
+            verified, failure = verifier.verify_adjacent_run(
+                CONFIG["chain_id"], blocks[0].signed_header, blocks[1:], PERIOD_NS, NOW_NS,
+                10 * 10**9, batch.FlushAccumulator(), root)
+        assert (verified, failure) == (HEADERS, None)
+    checks = [e["attrs"] for e in t.dump() if e["name"] == "light.header_checks"]
+    roots = [e["attrs"] for e in t.dump() if e["name"] == "light.verify_run"]
+    want = [{"set_leaves": HEADERS * 8, "set_leaf_hits": HEADERS * 8 - len(distinct)},
+            {"set_leaves": HEADERS * 8, "set_leaf_hits": HEADERS * 8}]
+    assert checks == [dict(w, headers=HEADERS) for w in want]
+    assert [{k: a[k] for k in w} for a, w in zip(roots, want)] == want
 
 
 def test_a_refused_run_names_the_height_and_saves_what_stands_below(monkeypatch):

@@ -380,3 +380,133 @@ def test_vote_set_deferred_flush_mixed_key_types():
     committed, failed = vote_set.flush()
     assert failed == []
     assert len(committed) == 4  # the sr25519 vote survives the deferred path
+
+
+# -- ValidatorSet.hash() from leaf hashes memoised by value (ISSUE 35)
+
+
+def _pub_key(kind, i):
+    import hashlib
+
+    from tendermint_tpu.crypto.keys import Bls12381PubKey, Ed25519PubKey
+    from tendermint_tpu.crypto.sr25519 import Sr25519PubKey
+
+    raw = hashlib.sha512(b"key-%d" % i).digest()
+    return {"ed25519": lambda: Ed25519PubKey(raw[:32]), "sr25519": lambda: Sr25519PubKey(raw[:32]),
+            "bls12_381": lambda: Bls12381PubKey(raw[:48])}[kind]()
+
+
+def _set_of(kind, n, power=10, first=0):
+    return ValidatorSet([Validator(_pub_key(kind, first + i), power + i % 3) for i in range(n)])
+
+
+def _plain_hash(vs):
+    """The formula: the root over every validator's SimpleValidator bytes."""
+    from tendermint_tpu.crypto.merkle import hash_from_byte_slices
+
+    return hash_from_byte_slices([v.simple_bytes() for v in vs.validators])
+
+
+@pytest.fixture
+def cold_memo():
+    from tendermint_tpu.types import validator_set
+
+    validator_set._leaf_memo.clear()
+    return validator_set
+
+
+@pytest.mark.parametrize("kind", ["ed25519", "sr25519", "bls12_381"])
+@pytest.mark.parametrize("n", [1, 2, 3, 100, 101])
+def test_hash_is_the_plain_formula_cold_and_warm(cold_memo, kind, n):
+    vs = _set_of(kind, n)
+    want = _plain_hash(vs)
+    before = cold_memo.leaf_memo_counts()
+    assert vs.hash() == want  # cold: every leaf hashed
+    cold = cold_memo.leaf_memo_counts()
+    assert vs.hash() == want and vs.copy().hash() == want  # warm: every leaf found
+    warm = cold_memo.leaf_memo_counts()
+    assert (cold[0] - before[0], cold[1] - before[1]) == (n, 0)
+    assert (warm[0] - cold[0], warm[1] - cold[1]) == (2 * n, 2 * n)
+    assert len(cold_memo._leaf_memo) == n
+
+
+def test_one_power_or_one_key_changed_is_another_hash(cold_memo):
+    vs = _set_of("ed25519", 7)
+    h = vs.hash()
+    target = vs.validators[3]
+    # another power for one validator, in a set built anew
+    other = ValidatorSet([Validator(v.pub_key, v.voting_power + (v is target)) for v in vs.validators])
+    assert other.hash() == _plain_hash(other) != h
+    # another key at the same power, and the same key bytes under another key type
+    from tendermint_tpu.crypto.sr25519 import Sr25519PubKey
+
+    for key in (_pub_key("ed25519", 99), Sr25519PubKey(target.pub_key.bytes())):
+        swapped = ValidatorSet([Validator(key if v is target else v.pub_key, v.voting_power)
+                                for v in vs.validators])
+        assert swapped.hash() == _plain_hash(swapped) and swapped.hash() not in (h, other.hash())
+    # a power changed in place, memo warm: the leaf's key is its value, not the object
+    vs.update_with_change_set([Validator(target.pub_key, target.voting_power + 1)])
+    assert vs.hash() == _plain_hash(vs) == other.hash()
+    # proposer priority has no part in a leaf
+    before = vs.hash()
+    priorities = [v.proposer_priority for v in vs.validators]
+    vs.increment_proposer_priority(3)
+    assert [v.proposer_priority for v in vs.validators] != priorities
+    assert vs.hash() == before == _plain_hash(vs)
+
+
+def test_a_set_larger_than_the_memos_bound_is_hashed_right_twice(cold_memo, monkeypatch):
+    monkeypatch.setattr(cold_memo, "_LEAF_MEMO_BOUND", 5)
+    vs, small = _set_of("ed25519", 23), _set_of("ed25519", 4, first=50)
+    want = _plain_hash(vs)
+    for _ in range(2):
+        assert vs.hash() == want and len(cold_memo._leaf_memo) <= 5
+        assert small.hash() == _plain_hash(small)
+
+
+def test_an_unsupported_key_type_raises_warm_or_cold(cold_memo):
+    class OtherKey(type(_pub_key("ed25519", 0))):
+        def type_name(self):
+            return "secp256k1"
+
+    vs = _set_of("ed25519", 3)
+    vs.validators[1].pub_key = OtherKey(vs.validators[1].pub_key.bytes())
+    for _ in range(2):  # nothing is kept for it, so the second ask raises as the first
+        with pytest.raises(ValueError, match="unsupported key type secp256k1"):
+            vs.hash()
+    assert all(key[0] == "ed25519" for key in cold_memo._leaf_memo)
+
+
+def test_eight_threads_hashing_overlapping_sets_agree(cold_memo, monkeypatch):
+    """Sets that share most of their keys, as a chain's do, hashed from eight
+    threads over a memo so small that it is dropped again and again."""
+    import sys
+    import threading
+
+    monkeypatch.setattr(cold_memo, "_LEAF_MEMO_BOUND", 48)
+    sets = [_set_of("ed25519", 40, first=k) for k in range(16)]
+    want = [_plain_hash(vs) for vs in sets]
+    before = cold_memo.leaf_memo_counts()
+    got, errors = {}, []
+
+    def work(t):
+        try:
+            got[t] = [[sets[(t + k) % 16].copy().hash() for k in range(16)] for _ in range(6)]
+        except Exception as e:  # a worker's failure has to reach the assertion
+            errors.append(e)
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in range(8)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(th.is_alive() for th in threads)
+    for t in range(8):
+        assert got[t] == [[want[(t + k) % 16] for k in range(16)]] * 6
+    after = cold_memo.leaf_memo_counts()
+    assert after[0] - before[0] == 8 * 6 * 16 * 40 and 0 < after[1] - before[1] < after[0] - before[0]
