@@ -39,6 +39,7 @@ from tendermint_tpu.consensus.wal import (
     TimeoutInfo,
 )
 from tendermint_tpu.libs import fail
+from tendermint_tpu.libs.trace import span as _trace_span
 from tendermint_tpu.libs.trace import tracer as _tracer
 from tendermint_tpu.state.execution import BlockExecutor, BlockValidationError
 from tendermint_tpu.state.sm_state import State
@@ -1105,54 +1106,53 @@ class ConsensusState:
         the commit path only pays device time for signatures that were never
         deferred-verified in the first place."""
         rs = self.rs
-        if rs.votes is not None and rs.votes.has_pending():
-            tr = _tracer if _tracer.enabled else None
-            span = None
-            if tr is not None:
-                span = tr.span("consensus.vote_flush", height=rs.height)
-                span.__enter__()
-            try:
-                height_before = rs.height
-                votes_before = rs.votes
-                flushed = votes_before.flush_all()
-                for err in votes_before.drain_conflicts():
-                    self._handle_vote_conflict(err)
-                if span is not None:
-                    span.set(
-                        committed=sum(len(c) for _, _, c, _ in flushed),
-                        failed=sum(len(f) for _, _, _, f in flushed),
-                    )
-            finally:
-                # always close: a raise between enter and here would corrupt
-                # the tracer's thread-local span stack for the whole loop —
-                # and pass the live exception so the span records error=...
-                if span is not None:
-                    import sys as _sys
-
-                    span.__exit__(*_sys.exc_info())
-            for vtype, vround, committed, failed in flushed:
-                # Publish only now: enqueue time would advertise (HasVote)
-                # signatures we have not verified, letting a forged vote
-                # suppress gossip of the genuine one.
-                self._publish_votes(committed)
-                if failed:
-                    logger.warning(
-                        "deferred flush: %d invalid %s signatures at round %d",
-                        len(failed), vtype.name, vround,
-                    )
-                # A progress check can COMMIT the block and advance the
-                # height, replacing rs.votes with a fresh HeightVoteSet; the
-                # remaining (type, round) pairs belong to the finished height
-                # and must not be re-checked against the new one.
-                if rs.height != height_before:
-                    break
-                self._check_progress_after_vote(vtype, vround)
-        if rs.last_commit is not None and rs.last_commit.pending_count() > 0:
-            committed, _failed = rs.last_commit.flush()
+        votes = rs.votes if rs.votes is not None and rs.votes.has_pending() else None
+        last = rs.last_commit
+        if last is not None and last.pending_count() == 0:
+            last = None
+        if votes is None and last is None:
+            return
+        flushed, late, conflicts = [], None, []
+        # ONE span for the tick: the height's vote sets and the late
+        # precommits of the height before it. Only the flushes stand under it;
+        # publishing and the progress checks (which may commit a block) follow.
+        with _trace_span("consensus.vote_flush", height=rs.height) as span:
+            if votes is not None:
+                flushed = votes.flush_all()
+                conflicts = votes.drain_conflicts()
+            if last is not None:
+                late = last.flush()
+                conflicts += last.pop_conflicts()
+            span.set(
+                committed=sum(len(c) for _, _, c, _ in flushed) + len(late[0] if late else ()),
+                failed=sum(len(f) for _, _, _, f in flushed) + len(late[1] if late else ()),
+            )
+        for err in conflicts:
+            self._handle_vote_conflict(err)
+        height_before = rs.height
+        for vtype, vround, committed, failed in flushed:
+            # Publish only now: enqueue time would advertise (HasVote)
+            # signatures we have not verified, letting a forged vote
+            # suppress gossip of the genuine one.
             self._publish_votes(committed)
-            for err in rs.last_commit.pop_conflicts():
-                self._handle_vote_conflict(err)
-            if self.config.skip_timeout_commit and rs.last_commit.has_all():
+            if failed:
+                logger.warning(
+                    "deferred flush: %d invalid %s signatures at round %d",
+                    len(failed), vtype.name, vround,
+                )
+            # A progress check can COMMIT the block and advance the
+            # height, replacing rs.votes with a fresh HeightVoteSet; the
+            # remaining (type, round) pairs belong to the finished height
+            # and must not be re-checked against the new one.
+            if rs.height != height_before:
+                break
+            self._check_progress_after_vote(vtype, vround)
+        if late is not None:
+            self._publish_votes(late[0])
+            # only while `last` is still the height's last commit: a progress
+            # check above may have committed a block and put another in its place
+            if (rs.last_commit is last and self.config.skip_timeout_commit
+                    and last.has_all()):
                 self._enter_new_round(rs.height, 0)
 
     def _add_vote(self, vote: Vote, peer_id: str) -> bool:

@@ -44,8 +44,6 @@ class HeightVoteSet:
 
     def set_round(self, round_: int) -> None:
         """Track round and round+1 (to allow round-skipping)."""
-        new_round = self.round - 1 if self.round > 0 else 0
-        del new_round
         for r in range(self.round, round_ + 2):
             if r not in self._round_vote_sets:
                 self._add_round(r)

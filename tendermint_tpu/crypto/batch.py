@@ -431,14 +431,15 @@ class VerifiedRowMemo:
             _metrics.batch_metrics().memo_hits.inc(nh)
         return out
 
-    def insert(self, digests, mask) -> None:
+    def insert(self, digests, mask) -> int:
         """Record verified rows: ONLY rows whose verdict is True — failed
         rows never enter, and callers skip insert entirely on exceptions
-        (never-cache-on-failure)."""
+        (never-cache-on-failure). Returns the rows newly inserted."""
         if self.capacity == 0 or digests is None:
-            return
+            return 0
         with self._lock:
             rows = self._rows
+            before = self.insertions
             for i, d in enumerate(digests):
                 if not mask[i]:
                     continue
@@ -450,6 +451,7 @@ class VerifiedRowMemo:
                 if len(rows) > self.capacity:
                     rows.popitem(last=False)
                     self.evictions += 1
+            return self.insertions - before
 
     def __len__(self) -> int:
         with self._lock:
@@ -2930,19 +2932,14 @@ def verify_batch_submit(
         )
     memo_digests = None
     if _MEMO.capacity:
-        memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
-        t_memo = time.perf_counter()
-        if len(_MEMO) and _MEMO.lookup(memo_digests).all():
+        with _trace.timed("verify_batch.memo", rows=len(pubkeys)) as ms:
+            memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
+            nh = int(_MEMO.lookup(memo_digests).sum()) if len(_MEMO) else 0
+            ms.set(hits=nh)
+        if nh == len(pubkeys):
             # every row already verified OK: hand back a resolved handle —
             # no submit, no device round trip (the deferred-verified shape)
-            _trace.record_flush(
-                backend="memo",
-                path="memo",
-                n=len(pubkeys),
-                total_s=time.perf_counter() - t_memo,
-                n_valid=len(pubkeys),
-                memo_hits=len(pubkeys),
-            )
+            _record_memo_answer(len(pubkeys), len(pubkeys), ms)
             return BatchHandle(mask=np.ones(len(pubkeys), dtype=bool))
     t0 = time.perf_counter()
     try:
@@ -2975,6 +2972,23 @@ def _record_flush(detail: dict, **head) -> None:
     caller's to pass: an async finish's detail may still hold an earlier
     flush's."""
     _trace.record_flush(**head, **{k: detail.get(k) for k in _DETAIL_FIELDS})
+
+
+def _record_memo_answer(rows: int, hits: int, pass_) -> None:
+    """The flush record of rows answered from the verified-row memo with no
+    verify at all: path `memo`, `n` = the rows answered, `memo_rows` the rows
+    asked. Its total is the memo's own pass `pass_` (digests and look-up)."""
+    _trace.record_flush(
+        backend="memo",
+        path="memo",
+        n=hits,
+        total_s=pass_.seconds,
+        n_valid=hits,
+        memo_hits=hits,
+        memo_rows=rows,
+        memo_s=pass_.seconds,
+        tracer_=_trace.tracer if pass_.recording else None,
+    )
 
 
 def verify_batch_finish(h: BatchHandle) -> np.ndarray:
@@ -3135,9 +3149,8 @@ def verify_batch(
         return np.zeros(0, dtype=bool)
     memo_digests = None
     if _MEMO.capacity:
-        with _trace.span("verify_batch.memo", rows=len(pubkeys)) as ms:
+        with _trace.timed("verify_batch.memo", rows=len(pubkeys)) as ms:
             memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
-            t_memo = time.perf_counter()
             hit = _MEMO.lookup(memo_digests) if len(_MEMO) else np.zeros(
                 len(memo_digests), dtype=bool
             )
@@ -3146,14 +3159,7 @@ def verify_batch(
         if nh == len(pubkeys):
             # every row already verified OK in an earlier flush (the
             # deferred-verified commit shape): no residue, no device work
-            _trace.record_flush(
-                backend="memo",
-                path="memo",
-                n=nh,
-                total_s=time.perf_counter() - t_memo,
-                n_valid=nh,
-                memo_hits=nh,
-            )
+            _record_memo_answer(nh, nh, ms)
             if sources is not None:
                 # memo-answered rows verified clean in an earlier flush:
                 # they still count toward a quarantined source's parole
@@ -3169,14 +3175,7 @@ def verify_batch(
         if nh:
             # partial hit: verify only the unseen residue (the recursive
             # call re-misses the residue digests and inserts its True rows)
-            _trace.record_flush(
-                backend="memo",
-                path="memo",
-                n=nh,
-                total_s=time.perf_counter() - t_memo,
-                n_valid=nh,
-                memo_hits=nh,
-            )
+            _record_memo_answer(len(pubkeys), nh, ms)
             if sources is not None:
                 # memo-answered rows verified clean in an earlier flush:
                 # they still count toward a quarantined source's parole
@@ -3240,6 +3239,16 @@ def verify_batch(
         # the flush's total closes HERE: the record's own body (flush.record)
         # and the memo insert below are the caller's time, not the flush's
         total_s = vb.elapsed()
+        memo = {}
+        if memo_digests is not None:
+            # memoize the rows that verified OK (never on exception — we only
+            # get here when the flush produced an exact per-row mask); before
+            # the record, which says what the memo's two passes did and took
+            with _trace.timed("verify_batch.memo", rows=len(pubkeys), insert=True) as mi:
+                inserted = _MEMO.insert(memo_digests, mask)
+                mi.set(inserted=inserted)
+            memo = dict(memo_rows=len(pubkeys), memo_hits=0, memo_inserted=inserted,
+                        memo_s=ms.seconds + mi.seconds)
         with _trace.span("flush.record"):
             _record_flush(
                 detail,
@@ -3253,13 +3262,9 @@ def verify_batch(
                 recovery_flushes=detail.get("recovery_flushes"),
                 quarantined=quarantined,
                 tracer_=_trace.tracer if vb.recording else None,
+                **memo,
             )
         vb.set(path=path, backend=be)
-    # memoize the rows that verified OK (never on exception — we only get
-    # here when the flush produced an exact per-row mask)
-    if memo_digests is not None:
-        with _trace.span("verify_batch.memo", rows=len(pubkeys), insert=True):
-            _MEMO.insert(memo_digests, mask)
     return mask
 
 

@@ -460,7 +460,11 @@ class VerifyScheduler:
                 self.preemptions += 1
                 if self.metrics is not None:
                     self.metrics.preemptions.inc()
-        mask = self._inline(pubkeys, msgs, sigs, key_types, sources)
+        # the span the other lanes' flushes have (_flush), under whatever the
+        # caller has open (a VoteSet.flush: `votes.flush`): no ticket, no wait
+        with _trace.span("lane.flush", lanes="votes", rows=n, tickets=1) as sp:
+            mask = self._inline(pubkeys, msgs, sigs, key_types, sources)
+            sp.set(flushes=1)
         wall = time.monotonic() - t0
         with self._cv:
             self.flush_seq += 1

@@ -407,6 +407,9 @@ def record_flush(
     prep_overlap_s: Optional[float] = None,
     prep_stages: Optional[dict] = None,
     memo_hits: Optional[int] = None,
+    memo_rows: Optional[int] = None,
+    memo_inserted: Optional[int] = None,
+    memo_s: Optional[float] = None,
     recovery_flushes: Optional[int] = None,
     quarantined: Optional[int] = None,
     tracer_: Optional[Tracer] = None,
@@ -504,6 +507,12 @@ def record_flush(
         }
     if memo_hits is not None:
         last["memo_hits"] = memo_hits
+    if memo_rows is not None:
+        # the verified-row memo's pass over this verify: rows asked, rows
+        # newly inserted, and its digest + look-up + insert time
+        last["memo_rows"] = memo_rows
+        last["memo_inserted"] = memo_inserted or 0
+        last["memo_ms"] = round((memo_s or 0.0) * 1e3, 4)
     if recovery_flushes is not None:
         last["recovery_flushes"] = recovery_flushes
     if quarantined is not None:

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from tendermint_tpu.crypto.batch import verify_batch
 from tendermint_tpu.libs import hotstats
+from tendermint_tpu.libs import trace as _trace
 from tendermint_tpu.types.basic import BlockID, SignedMsgType
 from tendermint_tpu.types.validator_set import ValidatorSet
 from tendermint_tpu.types.vote import Vote
@@ -218,26 +219,41 @@ class VoteSet:
         valid ones through the same conflict-aware path as add_vote. Returns
         (committed votes — safe to publish/gossip now, indices of votes that
         FAILED verification); conflicts discovered are available via
-        pop_conflicts()."""
+        pop_conflicts().
+
+        One span tree a flush (`votes.flush`: gather, sign bytes, the
+        scheduler's `lane.flush` / `verify_batch`, count), never a span a
+        vote: add_vote opens none."""
         if not self._pending:
             return [], []
+        with _trace.span(
+            "votes.flush", height=self.height, round=self.round,
+            type=SignedMsgType(self.signed_msg_type).name.lower(), rows=len(self._pending),
+        ) as root:
+            committed, failed = self._flush_pending()
+            root.set(committed=len(committed), failed=len(failed))
+        return committed, failed
+
+    def _flush_pending(self) -> Tuple[List[Vote], List[int]]:
         from tendermint_tpu.types import canonical
 
         pubkeys, sigs, key_types, sources = [], [], [], []
-        for _idx, vote, val, peer_id in self._pending:
-            pubkeys.append(val.pub_key.bytes())
-            sigs.append(vote.signature)
-            key_types.append(val.pub_key.type_name())
-            sources.append(f"peer:{peer_id}" if peer_id else "lane:votes")
+        with _trace.span("votes.gather"):
+            for _idx, vote, val, peer_id in self._pending:
+                pubkeys.append(val.pub_key.bytes())
+                sigs.append(vote.signature)
+                key_types.append(val.pub_key.type_name())
+                sources.append(f"peer:{peer_id}" if peer_id else "lane:votes")
         # One batched sign-bytes pass (shared type/height/round/chain_id;
         # profiled: the per-vote builder was 72% of flush time).
-        msgs = canonical.vote_sign_bytes_many(
-            self.chain_id,
-            self.signed_msg_type,
-            self.height,
-            self.round,
-            ((vote.block_id, vote.timestamp_ns) for _, vote, _, _ in self._pending),
-        )
+        with _trace.span("votes.sign_bytes"):
+            msgs = canonical.vote_sign_bytes_many(
+                self.chain_id,
+                self.signed_msg_type,
+                self.height,
+                self.round,
+                ((vote.block_id, vote.timestamp_ns) for _, vote, _, _ in self._pending),
+            )
         # key_types matters: in a mixed validator set an sr25519 vote
         # verified under ed25519 rules always fails (marker bit forces
         # s >= L) — dropping valid votes on the deferred path would be a
@@ -266,19 +282,22 @@ class VoteSet:
             hs.add("verify", hotstats.perf_counter() - t0, n=len(pubkeys))
         committed = []
         failed = []
-        for ok, (idx, vote, val, _peer) in zip(mask, self._pending):
-            if not ok:
-                failed.append(idx)
-                continue
-            block_key = vote.block_id.key()
-            # Re-check: an earlier pending vote may have committed already.
-            if self._get_vote(idx, block_key) is not None:
-                continue
-            added, conflicting = self._add_verified(idx, vote, val.voting_power, block_key)
-            if added:
-                committed.append(vote)
-            if conflicting is not None:
-                self._conflicts.append(ConflictingVotesError(conflicting, vote))
+        with _trace.span("votes.count") as sp:
+            conflicts = len(self._conflicts)
+            for ok, (idx, vote, val, _peer) in zip(mask, self._pending):
+                if not ok:
+                    failed.append(idx)
+                    continue
+                block_key = vote.block_id.key()
+                # Re-check: an earlier pending vote may have committed already.
+                if self._get_vote(idx, block_key) is not None:
+                    continue
+                added, conflicting = self._add_verified(idx, vote, val.voting_power, block_key)
+                if added:
+                    committed.append(vote)
+                if conflicting is not None:
+                    self._conflicts.append(ConflictingVotesError(conflicting, vote))
+            sp.set(conflicts=len(self._conflicts) - conflicts)
         self._pending.clear()
         self._pending_seen.clear()
         return committed, failed
@@ -354,29 +373,31 @@ class VoteSet:
             raise VoteSetError("cannot MakeCommit() unless VoteSet.Type is PRECOMMIT")
         if self._maj23 is None:
             raise VoteSetError("cannot MakeCommit() unless a blockhash has +2/3")
-        sigs = []
-        for vote in self._votes:
-            if vote is None:
-                sigs.append(CommitSig.absent_sig())
-            elif vote.block_id == self._maj23:
-                sigs.append(
-                    CommitSig(
-                        BlockIDFlag.COMMIT,
-                        vote.validator_address,
-                        vote.timestamp_ns,
-                        vote.signature,
+        with _trace.span("votes.make_commit", height=self.height) as sp:
+            sigs = []
+            absent = 0
+            for vote in self._votes:
+                if vote is not None and vote.block_id == self._maj23:
+                    sigs.append(
+                        CommitSig(
+                            BlockIDFlag.COMMIT,
+                            vote.validator_address,
+                            vote.timestamp_ns,
+                            vote.signature,
+                        )
                     )
-                )
-            elif vote.block_id.is_zero():
-                sigs.append(
-                    CommitSig(
-                        BlockIDFlag.NIL,
-                        vote.validator_address,
-                        vote.timestamp_ns,
-                        vote.signature,
+                elif vote is not None and vote.block_id.is_zero():
+                    sigs.append(
+                        CommitSig(
+                            BlockIDFlag.NIL,
+                            vote.validator_address,
+                            vote.timestamp_ns,
+                            vote.signature,
+                        )
                     )
-                )
-            else:
-                # Vote for a different block: counted as absent in the commit.
-                sigs.append(CommitSig.absent_sig())
-        return Commit(self.height, self.round, self._maj23, tuple(sigs))
+                else:
+                    # No vote, or one for a different block: absent in the commit.
+                    sigs.append(CommitSig.absent_sig())
+                    absent += 1
+            sp.set(rows=len(sigs) - absent)
+            return Commit(self.height, self.round, self._maj23, tuple(sigs))
