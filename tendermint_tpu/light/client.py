@@ -326,9 +326,8 @@ class Client:
             )
             with _trace.span("light.store") as sp:
                 saved = [lb for lb in run[:verified] if lb is not target]
-                for lb in saved:
-                    self.store.save_light_block(lb)
-                sp.set(headers=len(saved))
+                sp.set(headers=len(saved),
+                       bytes=sum(self.store.save_light_block(lb) for lb in saved))
             if failure is None:
                 root.set(verdict="accepted")
             else:

@@ -44,15 +44,20 @@ class LightStore:
         ]
         self._heights.sort()
 
-    def save_light_block(self, lb: LightBlock) -> None:
-        """reference: light/store/db/db.go:52 SaveLightBlock."""
+    def save_light_block(self, lb: LightBlock) -> int:
+        """reference: light/store/db/db.go:52 SaveLightBlock. The block's
+        record (types/light.py light_block_to_bytes) is in the db when this
+        returns; the record's length is returned (the `light.store` span's
+        `bytes`)."""
         if lb.height <= 0:
             raise ValueError("height <= 0")
+        record = light_block_to_bytes(lb)
         with self._lock:
             i = bisect.bisect_left(self._heights, lb.height)
             if i == len(self._heights) or self._heights[i] != lb.height:
                 self._heights.insert(i, lb.height)
-            self.db.set(_key(lb.height), light_block_to_bytes(lb))
+            self.db.set(_key(lb.height), record)
+        return len(record)
 
     def light_block(self, height: int) -> Optional[LightBlock]:
         """reference: light/store/db/db.go:96 LightBlock."""

@@ -8,7 +8,9 @@ RPC /commit /light_block responses and the light store's persistence.
 from __future__ import annotations
 
 import base64
+import itertools
 import json
+import struct
 from dataclasses import dataclass
 from typing import Optional
 
@@ -278,9 +280,141 @@ def light_block_from_json(o: dict) -> LightBlock:
     )
 
 
+# ------------------------------------------------------- the store's record
+#
+# What light/store.py keeps under a height (docs/LIGHT.md has the layout): a
+# version byte, the header and the commit's BlockID by their own encoders, and
+# the commit's signatures and the set's validators in COLUMNS, one run of
+# bytes a field over all rows. Only this program reads a store record, so it
+# is neither the RPC's JSON (a tree of dicts a block) nor upstream's
+# tmproto.LightBlock. A record that begins with `{` is an earlier build's
+# JSON and is read as such; the writer writes version 1 alone.
+
+_RECORD_V1 = b"\x01"
+_ROW_LENGTHS = 0xFFFFFFFF  # a column's width when its rows differ: a length a row follows
+
+
+def _column(rows: list) -> bytes:
+    """One bytes field over all rows: the width once where every row has
+    it, else the marker and a length a row; then the rows end to end."""
+    widths = set(map(len, rows))
+    if len(widths) <= 1:
+        head = struct.pack(">I", widths.pop() if widths else 0)
+    else:
+        head = struct.pack(">I%dI" % len(rows), _ROW_LENGTHS, *map(len, rows))
+    return head + b"".join(rows)
+
+
+class _RecordReader:
+    """A cursor over a record that never reads past its end."""
+
+    def __init__(self, data: bytes, pos: int):
+        self.data = data
+        self.pos = pos
+
+    def take(self, n: int) -> bytes:
+        end = self.pos + n
+        if end > len(self.data):
+            raise ValueError("light block record ends early")
+        out = self.data[self.pos:end]
+        self.pos = end
+        return out
+
+    def unpack(self, fmt: str) -> tuple:
+        return struct.unpack(fmt, self.take(struct.calcsize(fmt)))
+
+    def prefixed(self) -> bytes:
+        return self.take(self.unpack(">I")[0])
+
+    def ints(self, n: int) -> tuple:
+        return self.unpack(">%dq" % n)
+
+    def column(self, n: int) -> list:
+        (width,) = self.unpack(">I")
+        lengths = self.unpack(">%dI" % n) if width == _ROW_LENGTHS else (width,) * n
+        bounds = list(itertools.accumulate(lengths, initial=0))
+        run = self.take(bounds[-1])
+        return [run[a:b] for a, b in zip(bounds, bounds[1:])]
+
+
 def light_block_to_bytes(lb: LightBlock) -> bytes:
-    return json.dumps(light_block_to_json(lb), separators=(",", ":")).encode()
+    commit = lb.signed_header.commit
+    sigs = commit.signatures
+    vals = lb.validator_set.validators
+    proposer = lb.validator_set.proposer
+    keys = [v.pub_key for v in vals]
+    key_types = [k.type_name() for k in keys]
+    type_names = list(dict.fromkeys(key_types))
+    header = lb.signed_header.header.encode()
+    block_id = commit.block_id.encode()
+    try:
+        return b"".join((
+            _RECORD_V1, struct.pack(">I", len(header)), header,
+            struct.pack(">I", len(block_id)), block_id,
+            struct.pack(">qiI", commit.height, commit.round, len(sigs)),
+            bytes([cs.block_id_flag for cs in sigs]),
+            _column([cs.validator_address for cs in sigs]),
+            struct.pack(">%dq" % len(sigs), *[cs.timestamp_ns for cs in sigs]),
+            _column([cs.signature for cs in sigs]),
+            struct.pack(
+                ">IiB", len(vals),
+                lb.validator_set.get_by_address(proposer.address)[0] if proposer else -1,
+                len(type_names),
+            ),
+            _column([name.encode() for name in type_names]),
+            bytes(map(type_names.index, key_types)),
+            _column([k.bytes() for k in keys]),
+            struct.pack(">%dq" % len(vals), *[v.voting_power for v in vals]),
+            struct.pack(">%dq" % len(vals), *[v.proposer_priority for v in vals]),
+        ))
+    except struct.error as e:
+        raise ValueError(f"light block {lb.height} does not fit the store's record: {e}") from e
+
+
+def _light_block_from_record(data: bytes) -> LightBlock:
+    r = _RecordReader(data, len(_RECORD_V1))
+    header = Header.decode(r.prefixed())
+    block_id = BlockID.decode(r.prefixed())
+    height, round_, n_sigs = r.unpack(">qiI")
+    signatures = [
+        CommitSig(BlockIDFlag(flag), address, timestamp_ns, signature)
+        for flag, address, timestamp_ns, signature in zip(
+            r.take(n_sigs), r.column(n_sigs), r.ints(n_sigs), r.column(n_sigs)
+        )
+    ]
+    n_vals, proposer_at, n_types = r.unpack(">IiB")
+    type_names = [name.decode() for name in r.column(n_types)]
+    vals = [
+        Validator(
+            pub_key=pubkey_from_type_and_bytes(type_names[type_at], key),
+            voting_power=power,
+            proposer_priority=priority,
+        )
+        for type_at, key, power, priority in zip(
+            r.take(n_vals), r.column(n_vals), r.ints(n_vals), r.ints(n_vals)
+        )
+    ]
+    if r.pos != len(data):
+        raise ValueError(f"light block record has {len(data) - r.pos} bytes left over")
+    if not -1 <= proposer_at < n_vals:
+        raise ValueError(f"light block record names proposer {proposer_at} of {n_vals}")
+    # the proposer it was saved with, as validator_set_from_json restores it:
+    # the set's own row of that address, not a recomputed one
+    vs = ValidatorSet(vals)
+    if proposer_at >= 0:
+        vs.proposer = vs.get_by_address(vals[proposer_at].address)[1]
+    return LightBlock(
+        SignedHeader(header, Commit(height, round_, block_id, signatures)), vs
+    )
 
 
 def light_block_from_bytes(data: bytes) -> LightBlock:
-    return light_block_from_json(json.loads(data.decode()))
+    """A whole block or ValueError, never a half-built one."""
+    if data[:1] == b"{":
+        return light_block_from_json(json.loads(data.decode()))
+    if data[:1] != _RECORD_V1:
+        raise ValueError(f"light block record of unknown version {data[:1].hex() or 'none'}")
+    try:
+        return _light_block_from_record(data)
+    except IndexError as e:
+        raise ValueError("light block record names a key type past its table") from e

@@ -167,10 +167,10 @@ def one_header_at_a_time(config, blocks, now_ns=NOW_NS) -> dict:
     return outcome(err, store, blocks[1].height)
 
 
-def by_runs(config, blocks, now_ns=NOW_NS, witnesses=False) -> dict:
+def by_runs(config, blocks, now_ns=NOW_NS, witnesses=False, store=None) -> dict:
     """The client in SEQUENTIAL mode from the trusted root, which its store
     holds already, to the last block's height."""
-    store = LightStore(MemDB())
+    store = store or LightStore(MemDB())
     store.save_light_block(blocks[0])
     provider = MockProvider(config["chain_id"], {lb.height: lb for lb in blocks})
     root = blocks[0]
@@ -481,6 +481,11 @@ def test_with_a_scheduler_installed_the_run_rides_the_light_lane(
 
 # -- the span tree of a run
 
+def stored_bytes(store, blocks) -> int:
+    """The lengths of the records the store's db holds for these blocks."""
+    return sum(len(store.db.get(b"lb/" + lb.height.to_bytes(8, "big"))) for lb in blocks)
+
+
 STAGES = ["light.fetch", "light.header_checks", "light.gather", "light.sign_bytes",
           "verify_batch", "light.tally", "light.store"]
 
@@ -489,7 +494,8 @@ def test_a_run_is_one_span_tree_with_one_span_a_stage(monkeypatch):
     t = Tracer(ring_size=1024)
     monkeypatch.setattr(trace, "tracer", t)
     vals, item, blocks = blocks_of_case(400, "sound", 0)
-    assert by_runs(CONFIG, blocks, witnesses=True)["said"] == "accepted"
+    store = LightStore(MemDB())
+    assert by_runs(CONFIG, blocks, witnesses=True, store=store)["said"] == "accepted"
     events = t.dump()
     (root,) = [e for e in events if e["name"] == "light.verify_run"]
     mine = [e for e in events if e["root"] == root["span"]]
@@ -507,7 +513,9 @@ def test_a_run_is_one_span_tree_with_one_span_a_stage(monkeypatch):
     assert by["light.header_checks"]["attrs"]["headers"] == HEADERS
     assert by["light.gather"]["attrs"] == {"rows": HEADERS * 8}
     assert by["light.sign_bytes"]["attrs"] == {"rows": HEADERS * 8, "headers": HEADERS}
-    assert by["light.store"]["attrs"] == {"headers": HEADERS - 1}  # the target is saved later
+    # the target is saved later; the bytes are the records the db holds
+    assert by["light.store"]["attrs"] == {
+        "headers": HEADERS - 1, "bytes": stored_bytes(store, blocks[1:-1])}
     for e in mine:
         assert e["t0_ns"] >= root["t0_ns"]
     assert root["dur_ms"] >= sum(e["dur_ms"] for e in children) * 0.99
@@ -542,7 +550,8 @@ def test_a_refused_run_names_the_height_and_saves_what_stands_below(monkeypatch)
     t = Tracer(ring_size=1024)
     monkeypatch.setattr(trace, "tracer", t)
     vals, item, blocks = blocks_of_case(401, "broken_link", 5)
-    got = by_runs(CONFIG, blocks)
+    store = LightStore(MemDB())
+    got = by_runs(CONFIG, blocks, store=store)
     assert got["heights"] == list(range(1, 7))
     (root,) = [e for e in t.dump() if e["name"] == "light.verify_run"]
     assert root["attrs"]["verdict"] == "refused at height 7: ErrInvalidHeader"
@@ -550,7 +559,7 @@ def test_a_refused_run_names_the_height_and_saves_what_stands_below(monkeypatch)
     assert root["attrs"]["error"] == "ErrInvalidHeader"
     by = {e["name"]: e for e in t.dump() if e["parent"] == root["span"]}
     assert by["light.header_checks"]["attrs"]["headers"] == 5
-    assert by["light.store"]["attrs"] == {"headers": 5}
+    assert by["light.store"]["attrs"] == {"headers": 5, "bytes": stored_bytes(store, blocks[1:6])}
 
 
 def test_nothing_is_constructed_with_the_recorder_off(monkeypatch):
