@@ -116,21 +116,6 @@ Flight-recorder breakdown (always in "extra", including the stall fallback):
                   device-side verify_stats. null when no sub-benchmark
                   constructed a node.
 
-Vote hot-loop breakdown (vote_storm + live_consensus sub-results): each
-mode's `stage_breakdown_us*` dict reports per-vote microseconds by hot-loop
-stage from libs/hotstats.py —
-  encode_us  — protowire/sign-bytes COMPUTES (memoized; cache hits are free)
-  wal_us     — WAL frame writes, group-commit flushes and fsyncs
-  pubsub_us  — event-bus publishes (votes + round-state events)
-  gossip_us  — reactor HasVote broadcast fan-out (0 without p2p peers)
-  verify_us  — signature verification (host serial or batched device flush)
-  total_us   — wall time per vote for the timed region
-  bookkeeping_us — total_us - verify_us: the non-verify host cost per vote,
-                  the number PERF.md round 6 budgets. Stages are measured at
-                  their own layer and NEST (a WAL frame write that triggers
-                  a first-time encode counts under both wal and encode), so
-                  the stage values do not sum to total_us.
-
 Run WITHOUT the test conftest (the device children need the TPU):
 `python bench.py`.
 """
@@ -867,9 +852,6 @@ def bench_vote_storm(n_vals: int = 1024, heights: int = 4):
 
     all_votes = [signed_votes(h + 1) for h in range(heights)]
 
-    from tendermint_tpu.libs import hotstats as hstats
-
-    hs = hstats.stats
     n_votes = heights * n_vals
     DRAIN = 512  # the receive loop's greedy-drain batch bound
 
@@ -878,8 +860,6 @@ def bench_vote_storm(n_vals: int = 1024, heights: int = 4):
         # memos must start cold, as they do for votes arriving off the wire
         votes = [[dataclasses.replace(v) for v in hv] for hv in all_votes]
         bus = EventBus()  # zero subscribers — the node-without-listeners case
-        hs.reset()
-        hs.enabled = True
         t0 = time.perf_counter()
         for h in range(heights):
             vs = VoteSet("storm", h + 1, 0, 2, vals, defer_verification=defer)
@@ -899,31 +879,21 @@ def bench_vote_storm(n_vals: int = 1024, heights: int = 4):
                 bus.publish_votes(committed)
                 assert not failed
             assert vs.has_two_thirds_majority()
-        total = time.perf_counter() - t0
-        hs.enabled = False
-        br = hstats.HotpathStats.breakdown_us(hs.snapshot(), n_votes)
-        br["total_us"] = round(total / n_votes * 1e6, 3)
-        # non-verify host bookkeeping — the per-vote number this PR's
-        # acceptance tracks (verify is the device/OpenSSL's problem)
-        br["bookkeeping_us"] = round(br["total_us"] - br["verify_us"], 3)
-        return n_votes / total, br
+        return n_votes / (time.perf_counter() - t0)
 
     with tempfile.TemporaryDirectory() as tmp:
         def make_wal(tag):
             return WAL(os.path.join(tmp, f"wal-{tag}", "wal"), group_commit=True)
 
         run(True, make_wal("warm"))  # warm device kernels for the deferred path
-        deferred, deferred_br = run(True, make_wal("deferred"))
-        serial, serial_br = run(False, make_wal("serial"))
+        deferred = run(True, make_wal("deferred"))
+        serial = run(False, make_wal("serial"))
     return {
         "n_vals": n_vals,
         "heights": heights,
         "votes_per_sec_serial": round(serial),
         "votes_per_sec_deferred": round(deferred),
         "speedup": round(deferred / serial, 2),
-        # per-vote µs by stage (libs/hotstats.py; stages nest, see module doc)
-        "stage_breakdown_us_serial": serial_br,
-        "stage_breakdown_us_deferred": deferred_br,
     }
 
 
@@ -1015,15 +985,11 @@ def bench_live_consensus(n_vals: int = 1024, heights: int = 3):
         return cs, block_exec, sorted_privs
 
     async def run(defer: bool, tmp) -> dict:
-        from tendermint_tpu.libs import hotstats as hstats
-
-        hs = hstats.stats
         cs, block_exec, sorted_privs = build(defer, tmp)
         await cs.start()
         me = sorted_privs[0].get_pub_key().address()
         timed = 0.0
         votes_injected = 0
-        hs.reset()
         try:
             for target_h in range(1, heights + 1):
                 log(f"[live_consensus] defer={defer} height {target_h}: waiting")
@@ -1084,9 +1050,6 @@ def bench_live_consensus(n_vals: int = 1024, heights: int = 3):
                 )
 
                 # ---- timed: OUR node's processing of the wire messages
-                # (hotstats only inside the timed window, so the stub
-                # validators' signing above never pollutes the encode stage)
-                hs.enabled = True
                 t0 = time.perf_counter()
                 if prop is not None:
                     await cs.add_peer_message(ProposalMessage(prop), "bench-peer")
@@ -1103,21 +1066,12 @@ def bench_live_consensus(n_vals: int = 1024, heights: int = 3):
                 while cs.rs.height == target_h:
                     await asyncio.sleep(0.002)
                 timed += time.perf_counter() - t0
-                hs.enabled = False
         finally:
-            hs.enabled = False
             await cs.stop()
-        br = hstats.HotpathStats.breakdown_us(hs.snapshot(), votes_injected)
-        if br:
-            br["total_us"] = round(timed / votes_injected * 1e6, 3)
-            br["bookkeeping_us"] = round(
-                br["total_us"] - br["verify_us"], 3
-            )
         return {
             "blocks_per_sec": heights / timed,
             "votes_per_sec": votes_injected / timed,
             "timed_s": timed,
-            "stage_breakdown_us": br,
         }
 
     with tempfile.TemporaryDirectory() as tmp:
@@ -1140,10 +1094,6 @@ def bench_live_consensus(n_vals: int = 1024, heights: int = 3):
         "speedup": round(
             deferred["blocks_per_sec"] / serial["blocks_per_sec"], 2
         ),
-        # per-vote µs by hot-loop stage (encode/wal/pubsub/gossip/verify;
-        # libs/hotstats.py — stages nest, bookkeeping_us = total - verify)
-        "stage_breakdown_us_serial": serial["stage_breakdown_us"],
-        "stage_breakdown_us_deferred": deferred["stage_breakdown_us"],
         # Each deferred flush pays one device round trip; what that costs
         # against serially host-verifying the same ~1k votes is not
         # measured on today's chip.

@@ -36,7 +36,6 @@ from tendermint_tpu.consensus.messages import (
     encode_message,
 )
 from tendermint_tpu.consensus.round_state import RoundStepType
-from tendermint_tpu.libs import hotstats as _hotstats
 from tendermint_tpu.libs.bits import BitArray
 from tendermint_tpu.p2p.base_reactor import Reactor
 from tendermint_tpu.p2p.conn.connection import ChannelDescriptor
@@ -627,9 +626,6 @@ class ConsensusReactor(Reactor):
         async def on_votes(msgs):
             if self.switch is None:
                 return
-            hs = _hotstats.stats if _hotstats.stats.enabled else None
-            if hs is not None:
-                t0 = _hotstats.perf_counter()
             payloads = []
             trace = self._fresh_trace()  # one stamp for the whole drain batch
             for msg in msgs:
@@ -641,8 +637,6 @@ class ConsensusReactor(Reactor):
                     )
                 )
             await self.switch.broadcast_many(STATE_CHANNEL, payloads)
-            if hs is not None:
-                hs.add("gossip", _hotstats.perf_counter() - t0, n=len(msgs))
 
         await asyncio.gather(
             consume(sub_step, on_steps), consume(sub_valid, on_valid), consume(sub_vote, on_votes)

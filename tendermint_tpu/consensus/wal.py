@@ -17,7 +17,6 @@ from dataclasses import dataclass
 from typing import Iterator, List, Optional, Tuple, Union
 
 from tendermint_tpu.consensus.messages import decode_message, encode_message
-from tendermint_tpu.libs import hotstats as _hotstats
 from tendermint_tpu.libs import protowire as pw
 
 MAX_MSG_SIZE_BYTES = 1024 * 1024  # 1MB (reference: consensus/wal.go:32)
@@ -255,14 +254,6 @@ class WAL:
 
     def write(self, msg: WALMessage) -> None:
         """(reference: consensus/wal.go:184 Write — async, no fsync)"""
-        hs = _hotstats.stats if _hotstats.stats.enabled else None
-        if hs is None:
-            return self._write(msg)
-        t0 = _hotstats.perf_counter()
-        self._write(msg)
-        hs.add("wal", _hotstats.perf_counter() - t0)
-
-    def _write(self, msg: WALMessage) -> None:
         self.write_calls += 1
         frame = self._frame(msg)
         if self.group_commit:
@@ -277,9 +268,6 @@ class WAL:
                 now - self._dirty_since > self.group_commit_max_latency
                 or len(self._buf) >= self.head_size_limit
             ):
-                # untimed variant: write()'s own hotstats wrapper already
-                # covers this inline flush — the timed public method here
-                # would double-count the flush into the 'wal' stage
                 self._flush_buffered()
             return
         self._fh.write(frame)
@@ -290,8 +278,6 @@ class WAL:
         """(reference: consensus/wal.go:201 WriteSync — fsync before returning).
         In group-commit mode any buffered frames land first (exact ordering),
         in the same write+fsync."""
-        hs = _hotstats.stats if _hotstats.stats.enabled else None
-        t0 = _hotstats.perf_counter() if hs is not None else 0.0
         self.write_calls += 1
         frame = self._frame(msg)
         if self.group_commit:
@@ -300,8 +286,6 @@ class WAL:
             self._fh.write(frame)
         self.flush_and_sync()
         self._maybe_rotate()
-        if hs is not None:
-            hs.add("wal", _hotstats.perf_counter() - t0)
 
     def flush_buffered(self) -> None:
         """Group-commit boundary (called once per receive-loop queue drain):
@@ -311,12 +295,7 @@ class WAL:
         per queue drain, in either mode)."""
         if self._dirty_since is None and not self._buf:
             return
-        hs = _hotstats.stats if _hotstats.stats.enabled else None
-        if hs is None:
-            return self._flush_buffered()
-        t0 = _hotstats.perf_counter()
         self._flush_buffered()
-        hs.add("wal", _hotstats.perf_counter() - t0, n=0)
 
     def _flush_buffered(self) -> None:
         if (

@@ -2933,7 +2933,8 @@ def verify_batch_submit(
     memo_digests = None
     if _MEMO.capacity:
         with _trace.timed("verify_batch.memo", rows=len(pubkeys)) as ms:
-            memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
+            with _trace.span("memo.digest", rows=len(pubkeys)):
+                memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
             nh = int(_MEMO.lookup(memo_digests).sum()) if len(_MEMO) else 0
             ms.set(hits=nh)
         if nh == len(pubkeys):
@@ -3150,7 +3151,8 @@ def verify_batch(
     memo_digests = None
     if _MEMO.capacity:
         with _trace.timed("verify_batch.memo", rows=len(pubkeys)) as ms:
-            memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
+            with _trace.span("memo.digest", rows=len(pubkeys)):
+                memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
             hit = _MEMO.lookup(memo_digests) if len(_MEMO) else np.zeros(
                 len(memo_digests), dtype=bool
             )
@@ -3166,9 +3168,10 @@ def verify_batch(
                 try:
                     from tendermint_tpu.crypto import provenance as _prov
 
-                    _prov.default_scorer().record_rows(
-                        sources, np.ones(nh, dtype=bool)
-                    )
+                    with _trace.span("provenance.score", rows=nh):
+                        _prov.default_scorer().record_rows(
+                            sources, np.ones(nh, dtype=bool)
+                        )
                 except Exception:
                     pass
             return np.ones(nh, dtype=bool)
@@ -3183,10 +3186,11 @@ def verify_batch(
                 try:
                     from tendermint_tpu.crypto import provenance as _prov
 
-                    _prov.default_scorer().record_rows(
-                        [sources[i] for i in np.flatnonzero(hit)],
-                        np.ones(nh, dtype=bool),
-                    )
+                    with _trace.span("provenance.score", rows=nh):
+                        _prov.default_scorer().record_rows(
+                            [sources[i] for i in np.flatnonzero(hit)],
+                            np.ones(nh, dtype=bool),
+                        )
                 except Exception:
                     pass
             miss = ~hit
@@ -3229,11 +3233,12 @@ def verify_batch(
             try:
                 from tendermint_tpu.crypto import provenance as _prov
 
-                scorer = _prov.default_scorer()
-                q = scorer.quarantined_sources()
-                if q:
-                    quarantined = sum(1 for s in sources if s in q) or None
-                scorer.record_rows(sources, mask)
+                with _trace.span("provenance.score", rows=len(sources)):
+                    scorer = _prov.default_scorer()
+                    q = scorer.quarantined_sources()
+                    if q:
+                        quarantined = sum(1 for s in sources if s in q) or None
+                    scorer.record_rows(sources, mask)
             except Exception:
                 quarantined = None
         # the flush's total closes HERE: the record's own body (flush.record)

@@ -26,6 +26,7 @@ def _fmt_labels(label_names: Sequence[str], label_values: Tuple[str, ...]) -> st
 
 class _Metric:
     kind = "untyped"
+    collect = None  # a series read from its source when scraped: fills the values
 
     def __init__(self, name: str, help_: str, label_names: Sequence[str] = ()):
         self.name = name
@@ -206,6 +207,8 @@ class Registry:
         with self._lock:
             metrics = list(self._metrics)
         for m in metrics:
+            if m.collect is not None:
+                m.collect()
             lines.extend(m.expose())
         return "\n".join(lines) + "\n"
 
@@ -219,6 +222,8 @@ class Registry:
             metrics = list(self._metrics)
         out: Dict[str, dict] = {}
         for m in metrics:
+            if m.collect is not None:
+                m.collect()
             if isinstance(m, Histogram):
                 with m._lock:
                     series = {
@@ -1087,6 +1092,25 @@ class FleetMetrics:
 # crypto batch pipeline, the AOT kernel cache, pubsub overflow accounting)
 # rather than a Node instance.
 _GLOBAL_LOCK = threading.Lock()
+class ProcessMetrics:
+    """The process's own runtime, read when scraped (the Go client's
+    `go_gc_duration_seconds` beside a Go node's tendermint_* series)."""
+
+    def __init__(self, reg: Registry):
+        self.gc_pause_seconds = reg.counter(
+            f"{NAMESPACE}_process_gc_pause_seconds",
+            "Seconds the garbage collector paused the process, by generation "
+            "(counted while the flight recorder is on).",
+            ("generation",),
+        )
+        self.gc_pause_seconds.collect = self._collect_gc
+
+    def _collect_gc(self) -> None:
+        from tendermint_tpu.libs import trace
+
+        trace.fill_gc_series(self)
+
+
 _GLOBAL_REGISTRY: Optional[Registry] = None
 _BATCH_METRICS: Optional[BatchVerifyMetrics] = None
 _PUBSUB_METRICS: Optional[PubSubMetrics] = None
@@ -1108,6 +1132,7 @@ def global_registry() -> Registry:
             _MESH_METRICS = MeshMetrics(_GLOBAL_REGISTRY)
             _OBSERVATORY_METRICS = ObservatoryMetrics(_GLOBAL_REGISTRY)
             _FLEET_METRICS = FleetMetrics(_GLOBAL_REGISTRY)
+            ProcessMetrics(_GLOBAL_REGISTRY)  # filled at scrape time
         return _GLOBAL_REGISTRY
 
 

@@ -39,10 +39,21 @@ While a JAX profiler session is live each span is mirrored as
 `jax.profiler.TraceAnnotation("tm:" + name)`, which puts the program's spans
 on the host plane of the same `.xplane.pb`, on the profiler's clock, beside
 the device plane.
+
+While the recorder is on, one `gc.callbacks` hook times every collection of
+the garbage collector into process totals by generation (`gc_stats()`,
+`tendermint_process_gc_pause_seconds{generation}`), stamps the totals so far
+(`gc_ms`, `gc_n`) on every root span as it closes, and leaves one
+`gc.collect` span a FULL collection, under the span it interrupted. The hook
+takes no lock and touches no ring: a collection can start on a thread that
+holds the ring's lock (an allocation in `dump()`), so the span is handed over
+and written by the next ordinary event. With the recorder off (`configure(
+enabled=False)`) the hook is not registered at all.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -160,6 +171,9 @@ class Span(_Interval):
         self._tracer._record(
             self.name, self.span_id, self.parent_id, self.root, self.t0_ns,
             (self.t1_ns - self.t0_ns) / 1e9, self.attrs,
+            # a root carries the collector's totals so far, monotone: what
+            # the calls between two roots paid is the difference
+            (sum(_GC_NS), sum(_GC_N)) if self.parent_id is None and _GC_HOOKED else None,
         )
 
 
@@ -214,6 +228,8 @@ class Tracer:
         self._ring: deque = deque(maxlen=max(1, int(ring_size)))
         self._local = threading.local()
         self._ids = itertools.count(1)  # next() is atomic: no lock a span
+        # full collections the GC hook handed over, not yet in the ring
+        self._gc_pending: list = []
 
     # -- recording ----------------------------------------------------------
 
@@ -240,6 +256,7 @@ class Tracer:
 
     def dump(self, limit: Optional[int] = None) -> List[dict]:
         """Ring contents, oldest first (most recent `limit` if given)."""
+        self._take_gc()
         with self._lock:
             events = list(self._ring)
         if limit is not None and limit >= 0:
@@ -258,6 +275,7 @@ class Tracer:
         return self._ring.maxlen or 0
 
     def clear(self) -> None:
+        self._gc_pending.clear()
         with self._lock:
             self._ring.clear()
 
@@ -270,6 +288,8 @@ class Tracer:
                 self._ring = deque(self._ring, maxlen=max(1, int(ring_size)))
         if enabled is not None:
             self.enabled = bool(enabled)
+            if self is tracer:
+                _hook_gc(self.enabled)
 
     # -- internals ----------------------------------------------------------
 
@@ -282,24 +302,124 @@ class Tracer:
             stack = self._local.stack = []
         return stack
 
-    def _record(self, name, span_id, parent_id, root, t0_ns, dur_s, attrs) -> None:
-        event = {
-            "name": name,
-            "span": span_id,
-            "parent": parent_id,
-            "root": root,
-            "t0_ns": t0_ns,
-            "ts": time.time(),
-        }
-        if dur_s is not None:
-            event["dur_ms"] = round(dur_s * 1e3, 4)
-        if attrs:
-            event["attrs"] = dict(attrs)
+    def _record(self, name, span_id, parent_id, root, t0_ns, dur_s, attrs,
+                gc_total=None) -> None:
+        event = _event(name, span_id, parent_id, root, t0_ns, dur_s, attrs)
+        if gc_total is not None:
+            event["gc_ms"] = round(gc_total[0] / 1e6, 4)
+            event["gc_n"] = gc_total[1]
+        if self._gc_pending:
+            self._take_gc()
         with self._lock:
             self._ring.append(event)
 
+    def _take_gc(self) -> None:
+        """Writes the full collections the GC hook handed over, each as the
+        `gc.collect` span it was: outside the hook, where a lock may be taken."""
+        events = []
+        pending = self._gc_pending
+        while pending:
+            try:
+                t0_ns, dur_ns, top, gen, collected, uncollectable = pending.pop(0)
+            except IndexError:  # another thread took the last one
+                break
+            span_id = self._next_id()
+            events.append(_event(
+                "gc.collect", span_id, top.span_id if top else None,
+                top.root if top else span_id, t0_ns, dur_ns / 1e9,
+                {"generation": gen, "collected": collected,
+                 "uncollectable": uncollectable},
+            ))
+        if events:
+            with self._lock:
+                self._ring.extend(events)
+
+
+def _event(name, span_id, parent_id, root, t0_ns, dur_s, attrs) -> dict:
+    event = {
+        "name": name,
+        "span": span_id,
+        "parent": parent_id,
+        "root": root,
+        "t0_ns": t0_ns,
+        "ts": time.time(),
+    }
+    if dur_s is not None:
+        event["dur_ms"] = round(dur_s * 1e3, 4)
+    if attrs:
+        event["attrs"] = dict(attrs)
+    return event
+
+
+# -- garbage-collector pauses --------------------------------------------------
+# Process totals by generation, written by the hook alone: nanoseconds and
+# collections.
+_GC_NS = [0, 0, 0]
+_GC_N = [0, 0, 0]
+_GC_OPEN: list = [0, None]  # the running collection: its start, its tm: mirror
+_GC_HOOKED = False
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """gc.callbacks hook. Runs on whatever thread allocated, maybe one that
+    holds the ring's lock: it takes no lock, imports nothing and writes plain
+    numbers; a full collection is handed to the recorder as a tuple."""
+    if phase == "start":
+        _GC_OPEN[0] = _perf_ns()
+        ann = _ANNOTATION
+        if info["generation"] == 2 and ann and ann.is_enabled():
+            _GC_OPEN[1] = ann(ANNOTATION_PREFIX + "gc.collect")
+            _GC_OPEN[1].__enter__()
+        return
+    t1 = _perf_ns()
+    t0 = _GC_OPEN[0]
+    gen = info["generation"]
+    _GC_NS[gen] += t1 - t0
+    _GC_N[gen] += 1
+    ann, _GC_OPEN[1] = _GC_OPEN[1], None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    t = tracer
+    if gen == 2 and t.enabled:
+        stack = getattr(t._local, "stack", None)
+        t._gc_pending.append((t0, t1 - t0, stack[-1] if stack else None, gen,
+                              info["collected"], info["uncollectable"]))
+
+
+def _hook_gc(on: bool) -> None:
+    """The hook in `gc.callbacks` while the recorder is on, and not at all
+    while it is off."""
+    global _GC_HOOKED
+    if on and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    elif not on:
+        while _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+    _GC_HOOKED = _on_gc in gc.callbacks
+
+
+def gc_stats() -> dict:
+    """Collections and their seconds by generation since the process started,
+    counted while the recorder was on."""
+    return {
+        "hooked": _GC_HOOKED,
+        "generations": {
+            str(g): {"collections": _GC_N[g], "seconds": _GC_NS[g] / 1e9}
+            for g in range(3)
+        },
+    }
+
+
+def fill_gc_series(process_metrics) -> None:
+    """`tendermint_process_gc_pause_seconds{generation}`, read at scrape time
+    (libs/metrics.py ProcessMetrics)."""
+    process_metrics.gc_pause_seconds.replace_series(
+        {(str(g),): _GC_NS[g] / 1e9 for g in range(3)}
+    )
+
 
 tracer = Tracer(enabled=os.environ.get("TMTPU_TRACE", "1") != "0")
+_hook_gc(tracer.enabled)
 
 
 def span(name: str, parent=None, **attrs):
@@ -338,6 +458,39 @@ def interval(name: str, t0_ns: int, t1_ns: int, parent=None, **attrs) -> None:
     else:
         parent_id, root = None, span_id
     t._record(name, span_id, parent_id, root, t0_ns, (t1_ns - t0_ns) / 1e9, attrs)
+
+
+class Since:
+    """The open start of an interval that ends in another call, maybe of
+    another thread (`since()`): its reading of `perf_counter_ns` and, while a
+    profiler session is live, its `tm:` mirror, opened now. `end()` closes
+    the mirror and returns the end's reading; the interval is then written
+    closed with `interval()`. A TraceMe is written by the thread that closes
+    it, so a mirror closed on another thread lies on that thread's line."""
+
+    __slots__ = ("t0_ns", "_ann")
+
+    def __init__(self, name: str):
+        ann = _live_annotation()
+        self._ann = None
+        if ann is not None:
+            self._ann = ann(ANNOTATION_PREFIX + name)
+            self._ann.__enter__()
+        self.t0_ns = _perf_ns()
+
+    def end(self) -> int:
+        t1_ns = _perf_ns()
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        return t1_ns
+
+
+def since(name: str) -> Optional[Since]:
+    """The start of an interval whose end a later call reads (the first vote
+    queued to the flush that takes it); None when the recorder is off: one
+    flag read."""
+    return Since(name) if tracer.enabled else None
 
 
 def current():
@@ -582,6 +735,7 @@ def verify_stats() -> dict:
             },
         }
     out["device"] = device_health()
+    out["gc"] = gc_stats()
     try:
         # lazy: batch imports this module at load time; the reverse edge
         # only exists at call time

@@ -27,7 +27,7 @@ consensus/cs_state.py (proposal inclusion, commit), state/execution.py
 (libs/slo.py), bench.py's overload waterfall, and the chain observatory's
 fleet merge.
 
-Overhead contract (the hotstats model): recording is gated on the flight
+Overhead contract (libs/trace.py's): recording is gated on the flight
 recorder's `tracer.enabled` flag — with tracing disabled every hook reduces
 to one attribute read + one flag check and the PR 3 vote-path counter
 budgets are byte-identical to a tracker-less build. The ring is bounded

@@ -142,8 +142,10 @@ class Client:
         options on restart (reference: checkTrustedHeaderUsingOptions :237)."""
         now_ns = now_ns if now_ns is not None else _now_ns()
         async with self._lock:
-            existing = self.store.light_block(self.trust_options.height)
-            if existing is not None and existing.hash() == self.trust_options.hash:
+            with _trace.span("light.load"):
+                existing = self.store.light_block(self.trust_options.height)
+                trusted = existing is not None and existing.hash() == self.trust_options.hash
+            if trusted:
                 self._initialized = True
                 return existing
             lb = await self.primary.light_block(self.trust_options.height)
@@ -194,7 +196,8 @@ class Client:
             raise ValueError("height must be positive")
         now_ns = now_ns if now_ns is not None else _now_ns()
         await self._ensure_initialized(now_ns)
-        existing = self.store.light_block(height)
+        with _trace.span("light.load"):
+            existing = self.store.light_block(height)
         if existing is not None:
             return existing
         lb = await self._fetch_from_primary(height)
@@ -202,10 +205,22 @@ class Client:
 
     async def verify_light_block(self, new_lb: LightBlock, now_ns: int) -> LightBlock:
         """Verify a light block obtained elsewhere
-        (reference: light/client.go:497 VerifyHeader)."""
+        (reference: light/client.go:497 VerifyHeader).
+
+        Around the verification a call spans what it reads of the store
+        (`light.load`, the trusted blocks decoded), the target's own checks
+        (`light.target_checks`), the witnesses (`light.witness`, across
+        awaits: written closed) and the target's save and prune
+        (`light.save`); one span each, never one a header."""
         await self._ensure_initialized(now_ns)
         async with self._lock:
-            existing = self.store.light_block(new_lb.height)
+            with _trace.span("light.load"):
+                existing = self.store.light_block(new_lb.height)
+                if existing is None:
+                    first = self.store.first_light_block()
+                    backwards = first is not None and new_lb.height < first.height
+                    closest = None if backwards else \
+                        self.store.light_block_before(new_lb.height + 1)
             if existing is not None:
                 if existing.hash() != new_lb.hash():
                     raise LightError(
@@ -213,13 +228,12 @@ class Client:
                         f"match new one {new_lb.hash().hex()} at height {new_lb.height}"
                     )
                 return existing
-            new_lb.validate_basic(self.chain_id)
+            with _trace.span("light.target_checks"):
+                new_lb.validate_basic(self.chain_id)
 
-            first = self.store.first_light_block()
-            if first is not None and new_lb.height < first.height:
+            if backwards:
                 await self._backwards(first, new_lb, now_ns)
             else:
-                closest = self.store.light_block_before(new_lb.height + 1)
                 if closest is None:
                     raise LightError("no trusted state to verify from")
                 if self.mode == SEQUENTIAL:
@@ -227,9 +241,14 @@ class Client:
                 else:
                     await self._verify_skipping(closest, new_lb, now_ns)
 
+            since = time.perf_counter_ns() if _trace.tracer.enabled else 0
             await self._compare_with_witnesses(new_lb)
-            self.store.save_light_block(new_lb)
-            self.store.prune(self.pruning_size)
+            if since:
+                _trace.interval("light.witness", since, time.perf_counter_ns(),
+                                witnesses=len(self.witnesses))
+            with _trace.span("light.save"):
+                self.store.save_light_block(new_lb)
+                self.store.prune(self.pruning_size)
             return new_lb
 
     # -------------------------------------------------------- verify drivers

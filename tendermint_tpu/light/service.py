@@ -298,8 +298,8 @@ class LightService:
         # attributable to a STAGE — admission backstop, cache probe,
         # single-flight wait, provider fetch, coalesce-window wait, the
         # shared device flush wall, or the bisection walk — instead of one
-        # opaque number. Recording is gated on the tracer flag (the
-        # hotstats contract: disabled costs one flag check per site);
+        # opaque number. Recording is gated on the tracer flag (libs/trace.py's
+        # contract: disabled costs one flag check per site);
         # percentiles surface in light_status / GET /debug/light.
         self.stage_stats = StageStats()
 

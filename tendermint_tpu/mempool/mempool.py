@@ -269,7 +269,7 @@ class Mempool:
 
     def _tt(self):
         """The lifecycle tracker iff recording is on — one attribute read +
-        one flag check when disabled (the hotstats contract)."""
+        one flag check when disabled (libs/trace.py's contract)."""
         tt = self.tx_tracker
         if tt is None or not tt.enabled:
             return None

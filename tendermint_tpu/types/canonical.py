@@ -15,7 +15,6 @@ from __future__ import annotations
 import numpy as np
 
 from tendermint_tpu import native
-from tendermint_tpu.libs import hotstats
 from tendermint_tpu.libs import protowire as pw
 from tendermint_tpu.types.basic import BlockID, SignedMsgType, ts_seconds_nanos
 
@@ -116,7 +115,6 @@ def vote_sign_bytes_many(
     the rows become two columns, an index into the table of distinct block
     ids and the timestamp, for the builders of vote_sign_bytes_columns.
     Byte-identical to vote_sign_bytes per row (differentially tested)."""
-    t0 = _encode_clock()
     block_ids: list = []
     index: dict = {}
     sel = []
@@ -129,8 +127,7 @@ def vote_sign_bytes_many(
             block_ids.append(block_id)
         sel.append(k)
         ts_ns.append(ts)
-    out, _ = _build_columns(chain_id, msg_type, height, round_, block_ids, sel, ts_ns)
-    _encode_account(t0, len(out))
+    out, _ = vote_sign_bytes_columns(chain_id, msg_type, height, round_, block_ids, sel, ts_ns)
     return out
 
 
@@ -153,25 +150,6 @@ def vote_sign_bytes_columns(
     library is absent (or TMTPU_NATIVE=0), under NATIVE_MIN_ROWS rows, or
     for a timestamp outside int64 (a hostile commit can decode to one).
     Nothing is kept between calls: every call encodes every row."""
-    t0 = _encode_clock()
-    out, builder = _build_columns(
-        chain_id, msg_type, height, round_, block_ids, sel, ts_ns
-    )
-    _encode_account(t0, len(out))
-    return out, builder
-
-
-def _encode_clock():
-    """hotstats' `encode` stage: the start, or None while it is off."""
-    return hotstats.perf_counter() if hotstats.stats.enabled else None
-
-
-def _encode_account(t0, n: int) -> None:
-    if t0 is not None:
-        hotstats.stats.add("encode", hotstats.perf_counter() - t0, n=n)
-
-
-def _build_columns(chain_id, msg_type, height, round_, block_ids, sel, ts_ns) -> tuple:
     w = pw.Writer()
     w.varint_field(1, int(msg_type))
     w.sfixed64_field(2, height)

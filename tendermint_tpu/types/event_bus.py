@@ -9,7 +9,6 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 from tendermint_tpu.crypto import tmhash
-from tendermint_tpu.libs import hotstats as _hotstats
 from tendermint_tpu.libs.pubsub import PubSubServer, Query, Subscription
 
 EVENT_NEW_BLOCK = "NewBlock"
@@ -78,13 +77,6 @@ class EventBus:
         self.pubsub.unsubscribe_all(subscriber)
 
     def _publish(self, event_type: str, data: object, extra: Optional[Dict[str, List[str]]] = None) -> None:
-        hs = _hotstats.stats if _hotstats.stats.enabled else None
-        t0 = _hotstats.perf_counter() if hs is not None else 0.0
-        self._publish_untimed(event_type, data, extra)
-        if hs is not None:
-            hs.add("pubsub", _hotstats.perf_counter() - t0, n=0)
-
-    def _publish_untimed(self, event_type: str, data: object, extra: Optional[Dict[str, List[str]]] = None) -> None:
         # Zero-subscriber fast path: consensus publishes events for every
         # vote/step whether or not anyone listens; skip the event-map build
         # and the query walk when nothing could match.
@@ -135,28 +127,20 @@ class EventBus:
         self._publish(EVENT_VALIDATOR_SET_UPDATES, updates)
 
     def publish_vote(self, vote) -> None:
-        hs = _hotstats.stats if _hotstats.stats.enabled else None
-        t0 = _hotstats.perf_counter() if hs is not None else 0.0
         # explicit check (not just _publish's) so the EventDataVote wrapper
         # is never allocated on the zero-subscriber path
         if self.pubsub.has_subscribers(EVENT_VOTE):
-            self._publish_untimed(EVENT_VOTE, EventDataVote(vote))
-        if hs is not None:
-            hs.add("pubsub", _hotstats.perf_counter() - t0)
+            self._publish(EVENT_VOTE, EventDataVote(vote))
 
     def publish_votes(self, votes) -> None:
         """Batch publish for the deferred-vote drain: one subscriber-match
         pass for the whole flush (pubsub.publish_many)."""
         if not votes:
             return
-        hs = _hotstats.stats if _hotstats.stats.enabled else None
-        t0 = _hotstats.perf_counter() if hs is not None else 0.0
         if self.pubsub.has_subscribers(EVENT_VOTE):
             self.pubsub.publish_many(
                 [EventDataVote(v) for v in votes], {EVENT_TYPE_KEY: [EVENT_VOTE]}
             )
-        if hs is not None:
-            hs.add("pubsub", _hotstats.perf_counter() - t0, n=len(votes))
 
     def publish_round_state(self, event_type: str, height: int, round_: int, step: str) -> None:
         self._publish(event_type, EventDataRoundState(height, round_, step))

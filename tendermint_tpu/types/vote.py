@@ -10,7 +10,6 @@ from __future__ import annotations
 from dataclasses import dataclass, field, replace
 
 from tendermint_tpu.crypto.keys import PubKey
-from tendermint_tpu.libs import hotstats
 from tendermint_tpu.libs import protowire as pw
 from tendermint_tpu.types import canonical
 from tendermint_tpu.types.basic import BlockID, SignedMsgType, ts_seconds_nanos
@@ -48,14 +47,9 @@ class Vote:
             return cached[1]
         global SIGN_BYTES_COMPUTES
         SIGN_BYTES_COMPUTES += 1
-        hs = hotstats.stats if hotstats.stats.enabled else None
-        if hs is not None:
-            t0 = hotstats.perf_counter()
         data = canonical.vote_sign_bytes(
             chain_id, self.type, self.height, self.round, self.block_id, self.timestamp_ns
         )
-        if hs is not None:
-            hs.add("encode", hotstats.perf_counter() - t0)
         object.__setattr__(self, "_sign_bytes", (chain_id, data))
         return data
 
@@ -122,9 +116,6 @@ class Vote:
             return cached
         global ENCODE_COMPUTES
         ENCODE_COMPUTES += 1
-        hs = hotstats.stats if hotstats.stats.enabled else None
-        if hs is not None:
-            t0 = hotstats.perf_counter()
         enc = pw.encode_varint
         parts = []
         t = int(self.type)
@@ -146,8 +137,6 @@ class Vote:
         if self.signature:
             parts.append(self._T8 + enc(len(self.signature)) + self.signature)
         data = b"".join(parts)
-        if hs is not None:
-            hs.add("encode", hotstats.perf_counter() - t0)
         object.__setattr__(self, "_wire", data)
         return data
 

@@ -21,7 +21,6 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Set, Tuple
 
 from tendermint_tpu.crypto.batch import verify_batch
-from tendermint_tpu.libs import hotstats
 from tendermint_tpu.libs import trace as _trace
 from tendermint_tpu.types.basic import BlockID, SignedMsgType
 from tendermint_tpu.types.validator_set import ValidatorSet
@@ -82,6 +81,8 @@ class VoteSet:
         self._peer_maj23s: Dict[str, BlockID] = {}
         # deferred-verification queue: (idx, vote, validator, peer_id)
         self._pending: List[tuple] = []
+        # the first queued vote's reading (trace.since), for `votes.pending`
+        self._pending_since: Optional[_trace.Since] = None
         self._pending_seen: Set[Tuple[int, bytes, bytes]] = set()
         self._conflicts: List[ConflictingVotesError] = []
 
@@ -194,6 +195,8 @@ class VoteSet:
             self._pending_seen.add(seen_key)
             # carry the resolved Validator so flush() skips a second
             # get_by_index per vote, and the gossiping peer for provenance
+            if not self._pending:
+                self._pending_since = _trace.since("votes.pending")
             self._pending.append((idx, vote, val, peer_id))
             return "pending"
 
@@ -205,14 +208,7 @@ class VoteSet:
         return added
 
     def _verify_now(self, vote: Vote, pub_key) -> bool:
-        hs = hotstats.stats if hotstats.stats.enabled else None
-        if hs is None:
-            return pub_key.verify(vote.sign_bytes(self.chain_id), vote.signature)
-        msg = vote.sign_bytes(self.chain_id)  # counted under "encode" by the memo
-        t0 = hotstats.perf_counter()
-        ok = pub_key.verify(msg, vote.signature)
-        hs.add("verify", hotstats.perf_counter() - t0)
-        return ok
+        return pub_key.verify(vote.sign_bytes(self.chain_id), vote.signature)
 
     def flush(self) -> Tuple[List[Vote], List[int]]:
         """Batch-verify all deferred votes in one device call; commits the
@@ -221,15 +217,23 @@ class VoteSet:
         FAILED verification); conflicts discovered are available via
         pop_conflicts().
 
-        One span tree a flush (`votes.flush`: gather, sign bytes, the
-        scheduler's `lane.flush` / `verify_batch`, count), never a span a
-        vote: add_vote opens none."""
+        One span tree a flush (`votes.flush`: the queue's wait `votes.pending`,
+        gather, sign bytes, the scheduler's `lane.flush` / `verify_batch`,
+        count), never a span a vote: add_vote opens none, and reads the clock
+        only for the vote that finds the queue empty."""
         if not self._pending:
             return [], []
+        since, self._pending_since = self._pending_since, None
+        t1_ns = since.end() if since is not None else 0
         with _trace.span(
             "votes.flush", height=self.height, round=self.round,
             type=SignedMsgType(self.signed_msg_type).name.lower(), rows=len(self._pending),
         ) as root:
+            if since is not None:
+                # from the first vote queued to the flush that takes it: the
+                # batching delay the deferred path adds to every vote
+                _trace.interval("votes.pending", since.t0_ns, t1_ns, parent=root,
+                                rows=len(self._pending))
             committed, failed = self._flush_pending()
             root.set(committed=len(committed), failed=len(failed))
         return committed, failed
@@ -258,9 +262,6 @@ class VoteSet:
         # verified under ed25519 rules always fails (marker bit forces
         # s >= L) — dropping valid votes on the deferred path would be a
         # liveness break (mirrors validator_set.py batched Verify*).
-        hs = hotstats.stats if hotstats.stats.enabled else None
-        if hs is not None:
-            t0 = hotstats.perf_counter()
         # Global verification scheduler (crypto/scheduler.py): the deferred
         # vote flush rides the VOTES lane — it PREEMPTS queued bulk work
         # (light/admission/catch-up rows never inflate a vote flush's wall)
@@ -278,8 +279,6 @@ class VoteSet:
         else:
             mask = verify_batch(pubkeys, msgs, sigs, key_types=key_types,
                                 sources=sources)
-        if hs is not None:
-            hs.add("verify", hotstats.perf_counter() - t0, n=len(pubkeys))
         committed = []
         failed = []
         with _trace.span("votes.count") as sp:

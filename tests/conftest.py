@@ -72,3 +72,16 @@ def free_compile_memory() -> None:
 
     _jax.clear_caches()
     gc.collect()
+
+
+@pytest.fixture(autouse=True)
+def _gc_hook_off():
+    """The flight recorder's garbage-collector hook (libs/trace.py) is off in
+    every test unless it turns it on (`trace._hook_gc(True)`): a full
+    collection lands wherever an allocation tips it, and would leave a
+    `gc.collect` span in whatever ring a test holds to its exact spans."""
+    from tendermint_tpu.libs import trace
+
+    trace._hook_gc(False)
+    yield
+    trace._hook_gc(trace.tracer.enabled)

@@ -488,6 +488,7 @@ def stored_bytes(store, blocks) -> int:
 
 STAGES = ["light.fetch", "light.header_checks", "light.gather", "light.sign_bytes",
           "verify_batch", "light.tally", "light.store"]
+AROUND = ["light.load"] * 3 + ["light.target_checks", "light.witness", "light.save"]
 
 
 def test_a_run_is_one_span_tree_with_one_span_a_stage(monkeypatch):
@@ -499,10 +500,20 @@ def test_a_run_is_one_span_tree_with_one_span_a_stage(monkeypatch):
     events = t.dump()
     (root,) = [e for e in events if e["name"] == "light.verify_run"]
     mine = [e for e in events if e["root"] == root["span"]]
-    assert len(mine) == len(events) <= 60  # nothing of the call outside its one tree
+    assert len(events) <= 60
+    # outside its one tree, the client's work around the run: a span a site,
+    # each a root of its own, none a header (the store's reads at each of the
+    # three entries the call passes through)
+    around = [e for e in events if e["root"] != root["span"]]
+    assert sorted(e["name"] for e in around) == sorted(AROUND)
+    assert all(e["parent"] is None and e["root"] == e["span"] for e in around)
+    assert [e for e in around if e["name"] == "light.witness"][0]["attrs"] == {"witnesses": 1}
+    (target,) = [e for e in around if e["name"] == "light.target_checks"]
+    (save,) = [e for e in around if e["name"] == "light.save"]
+    assert target["t0_ns"] < root["t0_ns"] and save["t0_ns"] > root["t0_ns"]
     children = [e for e in mine if e["parent"] == root["span"]]
     assert [e["name"] for e in sorted(children, key=lambda e: e["t0_ns"])] == STAGES
-    assert mine[0]["name"] == "light.fetch" and events[-1] is root  # first written, and last
+    assert mine[0]["name"] == "light.fetch" and mine[-1] is root  # first written, and last
     by = {e["name"]: e for e in children}
     leaves = {k: v for k, v in by["light.header_checks"]["attrs"].items() if k != "headers"}
     assert set(leaves) == {"set_leaves", "set_leaf_hits"} and leaves["set_leaves"] == HEADERS * 8

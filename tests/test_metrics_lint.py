@@ -196,6 +196,9 @@ METRICS_SETS = (
     # (nodes per role) and tools/fleet_referee.py (safety-audit comparisons,
     # verdicts handed down)
     M.FleetMetrics,
+    # the process's own runtime (ISSUE 38): tendermint_process_gc_pause_seconds,
+    # read from libs/trace.py's collector totals at scrape time
+    M.ProcessMetrics,
 )
 
 

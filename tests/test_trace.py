@@ -2,6 +2,7 @@
 bounds, JSONL round-trip, thread safety, and the disabled-mode overhead
 contract (crypto/batch.py makes ZERO tracer calls beyond one flag read)."""
 
+import gc
 import threading
 
 import numpy as np
@@ -533,3 +534,176 @@ def test_tm_mirror_on_the_profilers_host_plane(tmp_path, monkeypatch):
     for name, (s, e) in seen.items():
         assert lo <= s and e <= hi, name
     assert not any(n.startswith("bench:") for n in seen)
+
+
+# -- the garbage collector's pauses (ISSUE 38)
+
+
+@pytest.fixture
+def gc_hooked(monkeypatch):
+    """A fresh recorder with the GC hook on (tests/conftest.py turns the hook
+    off for every test)."""
+    t = Tracer(ring_size=256)
+    monkeypatch.setattr(trace, "tracer", t)
+    trace._hook_gc(True)
+    yield t
+    trace._hook_gc(False)
+
+
+def test_a_full_collection_in_an_open_span_leaves_one_gc_collect_child(gc_hooked):
+    t = gc_hooked
+    with t.span("outer") as outer:
+        gc.collect()
+    events = t.dump()
+    (col,) = [e for e in events if e["name"] == "gc.collect"]
+    assert col["parent"] == outer.span_id and col["root"] == outer.span_id
+    assert col["attrs"]["generation"] == 2
+    assert set(col["attrs"]) == {"generation", "collected", "uncollectable"}
+    assert outer.t0_ns <= col["t0_ns"] and col["t0_ns"] + col["dur_ms"] * 1e6 <= outer.t1_ns + 1e3
+    assert events[-1]["name"] == "outer" and "gc_ms" not in col  # written before its root
+    # a young generation's collection is counted and writes no event
+    n = trace.gc_stats()["generations"]["0"]["collections"]
+    with t.span("young"):
+        gc.collect(0)
+        gc.collect(1)
+    assert [e["name"] for e in t.dump()[len(events):]] == ["young"]
+    assert trace.gc_stats()["generations"]["0"]["collections"] == n + 1
+
+
+def test_root_stamps_are_monotone_totals_of_every_generation(gc_hooked):
+    t = gc_hooked
+    for i in range(6):
+        with t.span("root"):
+            with t.span("child"):
+                gc.collect(i % 3)
+    events = t.dump()
+    roots = [e for e in events if e["name"] == "root"]
+    assert all("gc_ms" not in e for e in events if e["name"] != "root")
+    ms, n = [e["gc_ms"] for e in roots], [e["gc_n"] for e in roots]
+    assert ms == sorted(ms) and all(b > a for a, b in zip(n, n[1:]))
+    stats = trace.gc_stats()
+    assert stats["hooked"] is True
+    assert sum(g["collections"] for g in stats["generations"].values()) >= n[-1]
+    assert trace.verify_stats()["gc"]["generations"]["2"]["collections"] >= 2
+
+
+def test_the_hook_is_in_gc_callbacks_only_while_the_recorder_is_on(monkeypatch):
+    t = Tracer(ring_size=16)
+    monkeypatch.setattr(trace, "tracer", t)
+    try:
+        t.configure(enabled=True)
+        t.configure(enabled=True)
+        assert gc.callbacks.count(trace._on_gc) == 1
+        Tracer(ring_size=4).configure(enabled=False)  # not the process's: leaves the hook be
+        assert trace._on_gc in gc.callbacks
+        t.configure(enabled=False)
+        assert trace._on_gc not in gc.callbacks and trace.gc_stats()["hooked"] is False
+        # off, each new site is one flag read: no Span, no Since, no event
+        assert trace.since("votes.pending") is None and trace.span("memo.digest") is trace.NOOP
+        trace.interval("light.witness", 1, 2)
+        assert t.dump() == []
+    finally:
+        t.configure(enabled=True)
+        trace._hook_gc(False)
+    # on, with the hook off (as in tests): roots carry no stamp
+    with t.span("root"):
+        pass
+    assert "gc_ms" not in t.dump()[-1]
+
+
+def test_a_collection_inside_dumps_critical_section_completes(gc_hooked):
+    """The hook runs on the thread that allocated, here one that holds the
+    ring's lock (dump() copies the ring under it): it takes no lock, so the
+    call returns, and the collection is written by the next event."""
+    import collections
+
+    t = gc_hooked
+
+    class Collecting(collections.deque):
+        def __iter__(self):
+            gc.collect()  # a full collection, with the ring's lock held
+            return super().__iter__()
+
+    t._ring = Collecting(t._ring, maxlen=t._ring.maxlen)
+    done = []
+
+    def go():
+        for _ in range(20):
+            with t.span("s"):
+                pass
+            done.append(len(t.dump()))
+
+    old = gc.get_threshold()
+    gc.set_threshold(1)
+    try:
+        worker = threading.Thread(target=go, daemon=True)
+        worker.start()
+        worker.join(timeout=60)
+    finally:
+        gc.set_threshold(*old)
+    assert not worker.is_alive() and len(done) == 20
+    with t.span("last"):
+        pass
+    names = [e["name"] for e in t.dump()]
+    assert names.count("gc.collect") >= 20 and names[-1] == "last"
+
+
+def test_tm_mirror_of_the_queue_the_digests_the_scorer_and_the_collector(tmp_path, monkeypatch):
+    """Under a profiler session the host plane carries tm:votes.pending
+    (ending where tm:votes.flush begins), tm:memo.digest,
+    tm:provenance.score and tm:gc.collect (inside the span it interrupted)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from test_vote_commit_path import CHAIN as VOTE_CHAIN
+    from test_vote_commit_path import scenario
+
+    from tendermint_tpu.consensus.round_state import HeightVoteSet
+    from tendermint_tpu.crypto import batch
+
+    t = Tracer(ring_size=256)
+    monkeypatch.setattr(trace, "tracer", t)
+    batch.configure_verified_memo(4096)
+    step, arrivals = scenario("shuffled", 8, seed=47)
+    trace._hook_gc(True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        votes = HeightVoteSet(VOTE_CHAIN, step.height, step.vals, defer_verification=True)
+        for vote, peer in arrivals:
+            votes.add_vote(vote, peer)
+        votes.flush_all()
+        with trace.span("outer"):
+            gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+        trace._hook_gc(False)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("tm:"):
+                    seen.setdefault(e.name, []).append((e.start_ns, e.start_ns + e.duration_ns))
+    assert {"tm:votes.pending", "tm:votes.flush", "tm:memo.digest", "tm:provenance.score",
+            "tm:gc.collect", "tm:outer"} <= set(seen)
+    (pending,), (flush,) = seen["tm:votes.pending"], seen["tm:votes.flush"]
+    assert pending[0] < pending[1] <= flush[0]
+    (outer,) = seen["tm:outer"]
+    assert any(outer[0] <= s and e <= outer[1] for s, e in seen["tm:gc.collect"])
+
+
+def test_the_collectors_pauses_are_a_series_filled_at_scrape_time(gc_hooked):
+    from tendermint_tpu.libs import metrics
+
+    gc.collect()
+    fams = metrics.parse_exposition(metrics.global_registry().expose())
+    fam = fams["tendermint_process_gc_pause_seconds"]
+    assert fam["type"] == "counter"
+    got = {labels["generation"]: v for _, labels, v in fam["samples"]}
+    want = trace.gc_stats()["generations"]
+    assert set(got) == {"0", "1", "2"} and 0 < got["2"] <= want["2"]["seconds"]
+    snap = metrics.global_registry().snapshot()["tendermint_process_gc_pause_seconds"]
+    assert snap["series"]['generation="2"'] == got["2"]

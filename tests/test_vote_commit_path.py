@@ -400,9 +400,15 @@ def test_one_span_tree_a_flush_and_one_root_a_make_commit(votes_lane):
     assert root["attrs"] == {"height": step.height, "round": 0, "type": "precommit", "rows": 17,
                              "committed": 16, "failed": 0}
     assert {e["root"] for e in events} == {root["span"]}  # ONE tree
-    assert events[0]["name"] == "votes.gather"            # the first child written
+    assert events[0]["name"] == "votes.pending"           # the first child written
     children = [e["name"] for e in events if e["parent"] == root["span"]]
-    assert children == ["votes.gather", "votes.sign_bytes", "lane.flush", "votes.count"]
+    assert children == ["votes.pending", "votes.gather", "votes.sign_bytes", "lane.flush",
+                        "votes.count"]
+    # the queue's wait: from the first vote queued to the flush's start
+    (pending,) = got["votes.pending"]
+    assert pending["attrs"] == {"rows": 17}
+    assert pending["t0_ns"] + pending["dur_ms"] * 1e6 <= root["t0_ns"] + 1e3
+    assert pending["dur_ms"] > 0 and pending["t0_ns"] < got["votes.gather"][0]["t0_ns"]
     (lane,) = got["lane.flush"]
     assert lane["attrs"] == {"lanes": "votes", "rows": 17, "tickets": 1, "flushes": 1}
     (vb,) = got["verify_batch"]
@@ -410,6 +416,13 @@ def test_one_span_tree_a_flush_and_one_root_a_make_commit(votes_lane):
     assert got["votes.count"][0]["attrs"] == {"conflicts": 1}
     look_up, insert = got["verify_batch.memo"]
     assert look_up["attrs"] == {"rows": 17, "hits": 0}
+    # the memo's digests under its look-up pass, the scorer once under the flush
+    (digest,) = got["memo.digest"]
+    assert digest["parent"] == look_up["span"] and digest["attrs"] == {"rows": 17}
+    assert look_up["t0_ns"] <= digest["t0_ns"]
+    assert digest["t0_ns"] + digest["dur_ms"] * 1e6 <= look_up["t0_ns"] + look_up["dur_ms"] * 1e6
+    (score,) = got["provenance.score"]
+    assert score["parent"] == vb["span"] and score["attrs"] == {"rows": 17}
     assert insert["attrs"] == {"rows": 17, "insert": True, "inserted": 17}
     (record,) = got["batch_verify.flush"]
     attrs = record["attrs"]
@@ -430,6 +443,9 @@ def test_one_span_tree_a_flush_and_one_root_a_make_commit(votes_lane):
     assert record["root"] == got["commit.verify"][0]["span"]
     assert got["verify_batch.memo"][0]["attrs"] == {"rows": 16, "hits": 16}
     assert "verify_batch" not in got and "flush.record" not in got
+    # the commit's own pass digests its rows again; its rows carry no source
+    assert got["memo.digest"][0]["parent"] == got["verify_batch.memo"][0]["span"]
+    assert "provenance.score" not in got
 
 
 def test_without_a_lane_the_flush_s_verify_batch_hangs_under_the_root():
@@ -443,7 +459,7 @@ def test_without_a_lane_the_flush_s_verify_batch_hangs_under_the_root():
     root = events[-1]
     assert root["name"] == "votes.flush"
     assert [e["name"] for e in events if e["parent"] == root["span"]] == [
-        "votes.gather", "votes.sign_bytes", "verify_batch", "votes.count"]
+        "votes.pending", "votes.gather", "votes.sign_bytes", "verify_batch", "votes.count"]
     assert "verify_batch.memo" not in by_name(events)  # the memo is off: no pass, no counters
     assert "memo_rows" not in last_flush()
 
@@ -459,6 +475,7 @@ def test_with_the_recorder_off_the_path_constructs_no_span(monkeypatch, votes_la
         init(self, *a, **kw)
 
     monkeypatch.setattr(trace.Span, "__init__", counting)
+    monkeypatch.setattr(trace.Since, "__init__", counting)
     monkeypatch.setattr(trace.tracer, "enabled", False)
     trace.tracer.clear()
     precommits, committed, failed, _ = run_deferred(step, arrivals)
@@ -467,6 +484,33 @@ def test_with_the_recorder_off_the_path_constructs_no_span(monkeypatch, votes_la
     assert failed == [4] and len(committed) == 7
     record = last_flush()  # the records are kept all the same, with the memo's time
     assert record["path"] == "memo" and record["memo_hits"] == 7 and record["memo_ms"] > 0
+
+
+def test_the_queue_s_wait_starts_at_the_first_deferred_vote():
+    """`votes.pending` runs from the vote that finds the queue empty to the
+    flush's start, once a flush; votes queued after a flush wait anew, and a
+    flush with nothing queued writes nothing."""
+    step, arrivals = scenario("shuffled", 8, seed=46)
+    vs = VoteSet(CHAIN, step.height, 0, SignedMsgType.PRECOMMIT, step.vals,
+                 defer_verification=True)
+    trace.tracer.clear()
+    waits = []
+    for part in (arrivals[:3], arrivals[3:]):
+        before = trace._perf_ns()
+        vs.add_vote(*part[0])
+        after = trace._perf_ns()
+        for vote, peer in part[1:]:
+            vs.add_vote(vote, peer)
+        vs.flush()
+        assert vs.flush() == ([], [])
+        events = trace.tracer.dump()
+        (pending,) = [e for e in events if e["name"] == "votes.pending"]
+        (root,) = [e for e in events if e["name"] == "votes.flush"]
+        assert before <= pending["t0_ns"] <= after and pending["parent"] == root["span"]
+        assert pending["attrs"] == {"rows": len(part)}
+        waits.append(pending["dur_ms"])
+        trace.tracer.clear()
+    assert all(w > 0 for w in waits)
 
 
 # -- consensus: ONE consensus.vote_flush span a tick
