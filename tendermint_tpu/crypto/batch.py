@@ -391,10 +391,31 @@ class VerifiedRowMemo:
         self.evictions = 0
 
     def digest_rows(self, pubkeys, msgs, sigs, key_types=None) -> list:
-        """Length-framed SHA-256 per row. The frame prevents boundary
-        ambiguity (pk||msg splits are not unique); the mode byte keeps
-        cofactored and cofactorless (reference-exact) verdicts from ever
-        aliasing each other across a set_verify_mode flip."""
+        """Length-framed SHA-256 per row: SHA-256(mode || le32(len kt) || kt
+        || le32(len pk) || pk || le32(len msg) || msg || le32(len sig) ||
+        sig). The frame prevents boundary ambiguity (pk||msg splits are not
+        unique); the mode byte keeps cofactored and cofactorless
+        (reference-exact) verdicts from ever aliasing each other across a
+        set_verify_mode flip. One threaded native pass over the rows where
+        the library loaded, else `_digest_rows_py`: the same digests."""
+        from tendermint_tpu import native
+        from tendermint_tpu.crypto.keys import cofactorless_mode
+
+        if not native.available():
+            return self._digest_rows_py(pubkeys, msgs, sigs, key_types)
+        n = len(pubkeys)
+        table = ["ed25519"] if key_types is None else list(dict.fromkeys(key_types))
+        idx = None
+        if len(table) > 1:
+            pos = {t: k for k, t in enumerate(table)}
+            idx = np.fromiter(map(pos.__getitem__, key_types), dtype=np.int32, count=n)
+        blob = native.memo_digest_batch(
+            1 if cofactorless_mode() else 0, table, idx, pubkeys, msgs, sigs
+        )
+        return [blob[i : i + 32] for i in range(0, 32 * n, 32)]
+
+    def _digest_rows_py(self, pubkeys, msgs, sigs, key_types=None) -> list:
+        """`digest_rows` a row at a time with hashlib."""
         from tendermint_tpu.crypto.keys import cofactorless_mode
 
         mode = b"\x01" if cofactorless_mode() else b"\x00"
@@ -2932,8 +2953,10 @@ def verify_batch_submit(
         )
     memo_digests = None
     if _MEMO.capacity:
+        from tendermint_tpu import native
+
         with _trace.timed("verify_batch.memo", rows=len(pubkeys)) as ms:
-            with _trace.span("memo.digest", rows=len(pubkeys)):
+            with _trace.span("memo.digest", rows=len(pubkeys), native=native.available()):
                 memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
             nh = int(_MEMO.lookup(memo_digests).sum()) if len(_MEMO) else 0
             ms.set(hits=nh)
@@ -3150,8 +3173,10 @@ def verify_batch(
         return np.zeros(0, dtype=bool)
     memo_digests = None
     if _MEMO.capacity:
+        from tendermint_tpu import native
+
         with _trace.timed("verify_batch.memo", rows=len(pubkeys)) as ms:
-            with _trace.span("memo.digest", rows=len(pubkeys)):
+            with _trace.span("memo.digest", rows=len(pubkeys), native=native.available()):
                 memo_digests = _MEMO.digest_rows(pubkeys, msgs, sigs, key_types)
             hit = _MEMO.lookup(memo_digests) if len(_MEMO) else np.zeros(
                 len(memo_digests), dtype=bool

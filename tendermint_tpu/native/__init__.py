@@ -125,6 +125,13 @@ def _build() -> "ctypes.CDLL | None":
     i64p = ctypes.POINTER(ctypes.c_int64)
     i32p = ctypes.POINTER(ctypes.c_int32)
     lib.tm_ed25519_h_batch.argtypes = [u8p, u8p, u8p, i64p, ctypes.c_int64, u8p, ctypes.c_int]
+    lib.tm_sha256.argtypes = [u8p, ctypes.c_int64, u8p]
+    lib.tm_sha256.restype = None
+    lib.tm_memo_digest_batch.argtypes = [
+        ctypes.c_uint8, u8p, i64p, i32p, u8p, i64p, u8p, i64p, u8p, i64p,
+        ctypes.c_int64, u8p, ctypes.c_int,
+    ]
+    lib.tm_memo_digest_batch.restype = None
     lib.tm_rlc_scalars.argtypes = [u8p, u8p, u8p, ctypes.c_int64, u8p, u8p, ctypes.c_int]
     lib.tm_sort_windows.argtypes = [u8p, ctypes.c_int64, i32p, i32p, ctypes.c_int, ctypes.c_int64]
     lib.tm_vote_sign_bytes.argtypes = [
@@ -196,6 +203,51 @@ def ed25519_h_batch(
         n, _u8p(out), _NTHREADS,
     )
     return out
+
+
+def _column(rows, n: int):
+    """n bytes-like rows joined once -> (uint8 array, (n+1,) int64 offsets)."""
+    blob = b"".join(rows)
+    lens = np.fromiter(map(len, rows), dtype=np.int64, count=n)
+    if lens.sum() != len(blob):  # a memoryview whose len() counts items, not bytes
+        lens = np.fromiter((len(bytes(r)) for r in rows), dtype=np.int64, count=n)
+    if n and int(lens.max()) >> 32:
+        raise OverflowError("a row part of 4 GiB or more has no 32-bit length frame")
+    offs = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(lens, out=offs[1:])
+    return np.frombuffer(blob or b"\0", dtype=np.uint8), offs
+
+
+def memo_digest_batch(mode: int, key_types: list, kt_idx, pubkeys, msgs, sigs) -> bytes:
+    """The verified-row memo's digest of n rows in one pass: row i's is
+    SHA-256(mode || le32(len kt) || kt || le32(len pk) || pk || le32(len msg)
+    || msg || le32(len sig) || sig) (crypto/batch.VerifiedRowMemo.digest_rows).
+
+    key_types: the distinct key-type strings; kt_idx (n,) int32 indexes them
+    row by row, or None where every row has key_types[0]. pubkeys, msgs,
+    sigs: n bytes-like rows each. Returns n*32 bytes, row i's at [32i, 32i+32)."""
+    lib = _lib()
+    assert lib is not None
+    n = len(pubkeys)
+    if not len(msgs) == len(sigs) == n:
+        raise ValueError("pubkeys/msgs/sigs length mismatch")
+    if n and not key_types:
+        raise ValueError("rows need at least one key type")
+    i64p = ctypes.POINTER(ctypes.c_int64)
+    kts, kt_offs = _column([t.encode() for t in key_types], len(key_types))
+    idx = None
+    if kt_idx is not None:
+        kt_idx = np.ascontiguousarray(kt_idx, dtype=np.int32)
+        if kt_idx.shape != (n,) or (n and not 0 <= kt_idx.min() <= kt_idx.max() < len(key_types)):
+            raise ValueError("kt_idx must index key_types, one entry a row")
+        idx = kt_idx.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
+    cols = [_column(c, n) for c in (pubkeys, msgs, sigs)]
+    out = np.empty(32 * n or 1, dtype=np.uint8)
+    args = [a for blob, offs in cols for a in (_u8p(blob), offs.ctypes.data_as(i64p))]
+    lib.tm_memo_digest_batch(
+        mode, _u8p(kts), kt_offs.ctypes.data_as(i64p), idx, *args, n, _u8p(out), _NTHREADS
+    )
+    return out[: 32 * n].tobytes()
 
 
 def vote_sign_bytes(

@@ -447,6 +447,162 @@ void tm_ed25519_h_batch(const uint8_t *sigs, const uint8_t *pks,
   run_jobs(hash_worker, jobs, sizeof(hash_job), used, tids);
 }
 
+/* ------------------------------------------------------------------ */
+/* SHA-256 and the verified-row memo's digests                         */
+/*
+ * SHA-256 per FIPS 180-4. Its constants are the first 32 bits of the same
+ * fractional parts whose first 64 bits are SHA-512's (cube roots of the
+ * first 64 primes, square roots of the first 8), so they are read off the
+ * generated SHA-512 tables: SHA512_K[t] >> 32 and SHA512_IV[i] >> 32.
+ */
+
+typedef struct {
+  uint32_t h[8];
+  uint8_t buf[64];
+  size_t fill;
+  uint64_t total;
+} sha256_ctx;
+
+static inline uint32_t rotr32(uint32_t x, int n) {
+  return (x >> n) | (x << (32 - n));
+}
+
+static void sha256_block(uint32_t *st, const uint8_t *p) {
+  uint32_t w[64];
+  for (int t = 0; t < 16; t++) {
+    w[t] = ((uint32_t)p[t * 4] << 24) | ((uint32_t)p[t * 4 + 1] << 16) |
+           ((uint32_t)p[t * 4 + 2] << 8) | (uint32_t)p[t * 4 + 3];
+  }
+  for (int t = 16; t < 64; t++) {
+    uint32_t s0 = rotr32(w[t - 15], 7) ^ rotr32(w[t - 15], 18) ^ (w[t - 15] >> 3);
+    uint32_t s1 = rotr32(w[t - 2], 17) ^ rotr32(w[t - 2], 19) ^ (w[t - 2] >> 10);
+    w[t] = w[t - 16] + s0 + w[t - 7] + s1;
+  }
+  uint32_t a = st[0], b = st[1], c = st[2], d = st[3];
+  uint32_t e = st[4], f = st[5], g = st[6], h = st[7];
+  for (int t = 0; t < 64; t++) {
+    uint32_t S1 = rotr32(e, 6) ^ rotr32(e, 11) ^ rotr32(e, 25);
+    uint32_t ch = (e & f) ^ (~e & g);
+    uint32_t t1 = h + S1 + ch + (uint32_t)(SHA512_K[t] >> 32) + w[t];
+    uint32_t S0 = rotr32(a, 2) ^ rotr32(a, 13) ^ rotr32(a, 22);
+    uint32_t maj = (a & b) ^ (a & c) ^ (b & c);
+    uint32_t t2 = S0 + maj;
+    h = g; g = f; f = e; e = d + t1;
+    d = c; c = b; b = a; a = t1 + t2;
+  }
+  st[0] += a; st[1] += b; st[2] += c; st[3] += d;
+  st[4] += e; st[5] += f; st[6] += g; st[7] += h;
+}
+
+static void sha256_init(sha256_ctx *c) {
+  for (int i = 0; i < 8; i++) c->h[i] = (uint32_t)(SHA512_IV[i] >> 32);
+  c->fill = 0;
+  c->total = 0;
+}
+
+static void sha256_update(sha256_ctx *c, const uint8_t *p, size_t n) {
+  c->total += n;
+  if (c->fill) {
+    size_t take = 64 - c->fill;
+    if (take > n) take = n;
+    memcpy(c->buf + c->fill, p, take);
+    c->fill += take;
+    p += take;
+    n -= take;
+    if (c->fill < 64) return;
+    sha256_block(c->h, c->buf);
+    c->fill = 0;
+  }
+  for (; n >= 64; p += 64, n -= 64) sha256_block(c->h, p);
+  memcpy(c->buf, p, n);
+  c->fill = n;
+}
+
+/* padding: 0x80, zeros, 64-bit big-endian bit length; out = 32 bytes */
+static void sha256_final(sha256_ctx *c, uint8_t *out) {
+  uint64_t bits = c->total * 8;
+  c->buf[c->fill++] = 0x80;
+  if (c->fill > 56) {
+    memset(c->buf + c->fill, 0, 64 - c->fill);
+    sha256_block(c->h, c->buf);
+    c->fill = 0;
+  }
+  memset(c->buf + c->fill, 0, 56 - c->fill);
+  for (int i = 0; i < 8; i++) c->buf[56 + i] = (uint8_t)(bits >> (56 - 8 * i));
+  sha256_block(c->h, c->buf);
+  for (int i = 0; i < 8; i++)
+    for (int j = 0; j < 4; j++) out[i * 4 + j] = (uint8_t)(c->h[i] >> (24 - 8 * j));
+}
+
+/* SHA-256 of one message: the memo digests' core, exported for its test. */
+void tm_sha256(const uint8_t *p, int64_t n, uint8_t *out) {
+  sha256_ctx c;
+  sha256_init(&c);
+  sha256_update(&c, p, (size_t)n);
+  sha256_final(&c, out);
+}
+
+/* le32(n) || part */
+static void sha256_framed(sha256_ctx *c, const uint8_t *p, int64_t n) {
+  uint8_t len[4] = {(uint8_t)n, (uint8_t)(n >> 8), (uint8_t)(n >> 16), (uint8_t)(n >> 24)};
+  sha256_update(c, len, 4);
+  sha256_update(c, p, (size_t)n);
+}
+
+typedef struct {
+  uint8_t mode;
+  const uint8_t *kts;      /* the distinct key-type strings, concatenated */
+  const int64_t *kt_offs;  /* their offsets (k+1) */
+  const int32_t *kt_idx;   /* n: row i's string; NULL = string 0 for all */
+  const uint8_t *cols[3];  /* pubkeys, msgs, sigs, each concatenated */
+  const int64_t *offs[3];  /* n+1 each */
+  uint8_t *out;            /* n*32 */
+  int64_t lo, hi;
+} memo_job;
+
+static void *memo_worker(void *arg) {
+  memo_job *j = (memo_job *)arg;
+  sha256_ctx c;
+  for (int64_t i = j->lo; i < j->hi; i++) {
+    int32_t k = j->kt_idx ? j->kt_idx[i] : 0;
+    sha256_init(&c);
+    sha256_update(&c, &j->mode, 1);
+    sha256_framed(&c, j->kts + j->kt_offs[k], j->kt_offs[k + 1] - j->kt_offs[k]);
+    for (int p = 0; p < 3; p++)
+      sha256_framed(&c, j->cols[p] + j->offs[p][i], j->offs[p][i + 1] - j->offs[p][i]);
+    sha256_final(&c, j->out + 32 * i);
+  }
+  return 0;
+}
+
+/* Row i's digest: SHA-256(mode || le32(len kt) || kt || le32(len pk) || pk
+ * || le32(len msg) || msg || le32(len sig) || sig), kt the key-type string
+ * kt_idx[i] of the table (crypto/batch.py VerifiedRowMemo.digest_rows). */
+void tm_memo_digest_batch(uint8_t mode, const uint8_t *kts, const int64_t *kt_offs,
+                          const int32_t *kt_idx, const uint8_t *pks,
+                          const int64_t *pk_offs, const uint8_t *msgs,
+                          const int64_t *m_offs, const uint8_t *sigs,
+                          const int64_t *s_offs, int64_t n, uint8_t *out,
+                          int nthreads) {
+  if (nthreads < 1) nthreads = 1;
+  if (nthreads > 64) nthreads = 64;
+  if (n < 512) nthreads = 1;
+  pthread_t tids[64];
+  memo_job jobs[64];
+  int64_t chunk = (n + nthreads - 1) / nthreads;
+  int used = 0;
+  for (int t = 0; t < nthreads; t++) {
+    int64_t lo = t * chunk, hi = lo + chunk;
+    if (lo >= n) break;
+    if (hi > n) hi = n;
+    jobs[t] = (memo_job){mode, kts, kt_offs, kt_idx, {pks, msgs, sigs},
+                         {pk_offs, m_offs, s_offs}, out, lo, hi};
+    used = t + 1;
+    if (hi == n) break;
+  }
+  run_jobs(memo_worker, jobs, sizeof(memo_job), used, tids);
+}
+
 typedef struct {
   const uint8_t *z;  /* n*16 LE (0 => excluded row) */
   const uint8_t *h;  /* n*32 LE */

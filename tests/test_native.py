@@ -48,6 +48,72 @@ def test_h_batch_matches_hashlib():
         assert int.from_bytes(out[i].tobytes(), "little") == exp, i
 
 
+def test_sha256_matches_hashlib():
+    """The memo digests' SHA-256 core alone, every length over its padding
+    edges (0-300 bytes: one to six blocks) and one of 1 MB."""
+    native = _native()
+    rng = np.random.default_rng(9)
+    out = np.empty(32, dtype=np.uint8)
+    for ln in list(range(301)) + [1 << 20]:
+        data = rng.bytes(ln)
+        buf = np.frombuffer(data or b"\0", dtype=np.uint8)
+        native._lib().tm_sha256(native._u8p(buf), ln, native._u8p(out))
+        assert out.tobytes() == hashlib.sha256(data).digest(), ln
+
+
+@pytest.mark.parametrize("bad", ["lengths", "no_key_type", "index_high", "index_negative", "index_short"])
+def test_memo_digest_batch_refuses_rows_it_cannot_frame(bad):
+    native = _native()
+    pks, msgs, sigs = [b"k" * 32] * 3, [b"m"] * 3, [b"s" * 64] * 3
+    key_types, idx = ["ed25519", "sr25519"], np.array([0, 1, 0], dtype=np.int32)
+    if bad == "lengths":
+        sigs = sigs[:2]
+    elif bad == "no_key_type":
+        key_types, idx = [], None
+    elif bad == "index_high":
+        idx[2] = 2
+    elif bad == "index_negative":
+        idx[0] = -1
+    else:
+        idx = idx[:2]
+    with pytest.raises(ValueError):
+        native.memo_digest_batch(0, key_types, idx, pks, msgs, sigs)
+
+
+def test_memo_digests_exact_while_the_pool_is_busy():
+    """A memo digest pass of more rows than the pool has threads, while a
+    long ed25519_h_batch holds the pool: it takes per-call threads and still
+    gives every row the hashlib loop's digest."""
+    import threading
+
+    from tendermint_tpu.crypto import batch
+
+    native = _native()
+    rng = np.random.default_rng(10)
+    n = 64 * max(8, native.prep_threads())
+    pks, msgs, sigs = ([rng.bytes(w) for _ in range(n)] for w in (32, 120, 64))
+    memo = batch.VerifiedRowMemo(16)
+    want = memo._digest_rows_py(pks, msgs, sigs)
+    big = 200_000
+    blobs = [rng.bytes(big * w) for w in (64, 32, 8)]
+    moffs = np.arange(0, 8 * (big + 1), 8, dtype=np.int64)
+    started = threading.Event()
+
+    def hold_the_pool():
+        started.set()
+        native.ed25519_h_batch(*blobs, moffs)
+
+    holder = threading.Thread(target=hold_the_pool)
+    holder.start()
+    assert started.wait(60)
+    passes = 0
+    while holder.is_alive() or passes == 0:
+        assert memo.digest_rows(pks, msgs, sigs) == want
+        passes += 1
+    holder.join(60)
+    assert not holder.is_alive()
+
+
 def test_rlc_scalars_matches_bigint():
     native = _native()
     rng = np.random.default_rng(8)

@@ -166,6 +166,88 @@ def test_digest_framing_is_unambiguous():
     assert d1 != d2
 
 
+# ---------------------------------------------------------------------------
+# the native digest pass (native/batchhost.c tm_memo_digest_batch) against
+# the hashlib loop, byte for byte
+
+# raw SHA-256 padding edges, and those of an ed25519 row's frame (120 bytes
+# besides the msg: 55/56 mod 64 at msg 63/64, 127/128; a whole block at 8)
+_MSG_LENS = [0, 1, 8, 55, 56, 63, 64, 119, 120, 127, 128, 1024]
+
+
+def _needs_native():
+    from tendermint_tpu import native
+
+    if not native.available():
+        pytest.skip("native batchhost unavailable (no compiler?)")
+
+
+def _rows(n, kinds, form, seed=11):
+    rng = np.random.default_rng([seed, n])
+    pks = [rng.bytes(32) for _ in range(n)]
+    msgs = [rng.bytes(_MSG_LENS[i % len(_MSG_LENS)]) for i in range(n)]
+    sigs = [rng.bytes(64) for _ in range(n)]
+    key_types = None
+    if kinds == "ed25519":
+        key_types = ["ed25519"] * n
+    elif kinds == "mixed":
+        key_types = ["ed25519" if i % 3 else "sr25519" for i in range(n)]
+        if n:  # digest_rows frames any string, and any key width
+            key_types[0], pks[0] = "secp256k1", rng.bytes(33)
+    cast = {"bytes": bytes, "bytearray": bytearray, "memoryview": memoryview}[form]
+    pks, msgs, sigs = ([cast(r) for r in col] for col in (pks, msgs, sigs))
+    if form == "memoryview" and n > 1:  # a view whose len() counts 2-byte items
+        msgs[1] = memoryview(rng.bytes(56)).cast("H")
+    return pks, msgs, sigs, key_types
+
+
+@pytest.mark.parametrize("form", ["bytes", "bytearray", "memoryview"])
+@pytest.mark.parametrize("kinds", [None, "ed25519", "mixed"])
+@pytest.mark.parametrize("n", [0, 1, 511, 512, 10_000])
+def test_native_digests_equal_the_hashlib_loop(n, kinds, form):
+    """One native pass gives every row the digest the hashlib loop gives it,
+    in both verify modes, for rows of any width, key type and buffer kind,
+    under the threaded split (n >= 512) and the single thread."""
+    from tendermint_tpu.crypto.keys import cofactorless_mode, set_verify_mode
+
+    _needs_native()
+    memo = batch.VerifiedRowMemo(16)
+    pks, msgs, sigs, key_types = _rows(n, kinds, form)
+    before = "cofactorless" if cofactorless_mode() else "cofactored"
+    seen = set()
+    try:
+        for mode in ("cofactored", "cofactorless"):
+            set_verify_mode(mode)
+            got = memo.digest_rows(pks, msgs, sigs, key_types)
+            assert got == memo._digest_rows_py(pks, msgs, sigs, key_types)
+            assert all(type(d) is bytes and len(d) == 32 for d in got)
+            seen.update(got)
+    finally:
+        set_verify_mode(before)
+    assert len(seen) == 2 * n  # the mode byte parts the two modes' digests
+
+
+@pytest.mark.parametrize("writer", ["python", "native"])
+def test_a_memo_written_by_either_digest_path_is_read_by_the_other(writer, monkeypatch):
+    """A flush whose digests the hashlib loop made fills the memo the native
+    pass reads, and the other way round: the same rows hit, all of them."""
+    from tendermint_tpu import native
+
+    _needs_native()
+    memo = _memo_on()
+    pks, msgs, sigs = _signed(40, b"\x3a")
+    with monkeypatch.context() as m:
+        if writer == "python":
+            m.setattr(native, "available", lambda: False)
+        assert batch.verify_batch(pks, msgs, sigs).all()
+    assert len(memo) == 40
+    if writer == "native":
+        monkeypatch.setattr(native, "available", lambda: False)
+    assert batch.verify_batch(pks, msgs, sigs).all()
+    lf = _last_flush()
+    assert lf["path"] == "memo" and lf["memo_hits"] == 40
+
+
 def test_scheduler_stats_carry_memo_block():
     _memo_on(128)
     pks, msgs, sigs = _signed(8, b"\x36")

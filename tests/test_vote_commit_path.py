@@ -18,6 +18,7 @@ import types
 import numpy as np
 import pytest
 
+from tendermint_tpu import native
 from tendermint_tpu.consensus.round_state import HeightVoteSet
 from tendermint_tpu.crypto import batch, ed25519_ref, scheduler
 from tendermint_tpu.crypto.keys import gen_ed25519
@@ -418,7 +419,8 @@ def test_one_span_tree_a_flush_and_one_root_a_make_commit(votes_lane):
     assert look_up["attrs"] == {"rows": 17, "hits": 0}
     # the memo's digests under its look-up pass, the scorer once under the flush
     (digest,) = got["memo.digest"]
-    assert digest["parent"] == look_up["span"] and digest["attrs"] == {"rows": 17}
+    assert digest["parent"] == look_up["span"]
+    assert digest["attrs"] == {"rows": 17, "native": native.available()}
     assert look_up["t0_ns"] <= digest["t0_ns"]
     assert digest["t0_ns"] + digest["dur_ms"] * 1e6 <= look_up["t0_ns"] + look_up["dur_ms"] * 1e6
     (score,) = got["provenance.score"]
@@ -446,6 +448,27 @@ def test_one_span_tree_a_flush_and_one_root_a_make_commit(votes_lane):
     # the commit's own pass digests its rows again; its rows carry no source
     assert got["memo.digest"][0]["parent"] == got["verify_batch.memo"][0]["span"]
     assert "provenance.score" not in got
+
+
+@pytest.mark.parametrize("loaded", [True, False])
+def test_the_memo_s_digest_spans_say_which_path_hashed_the_rows(loaded, monkeypatch, votes_lane):
+    """`memo.digest` says `native` True where the library hashed the rows and
+    False where the library is unavailable; either way the votes' digests are
+    the ones the commit's look-up finds, every row of it."""
+    if loaded and not native.available():
+        pytest.skip("native batchhost unavailable (no compiler?)")
+    if not loaded:
+        monkeypatch.setattr(native, "available", lambda: False)
+    batch.configure_verified_memo(4096)
+    step, arrivals = scenario("shuffled", 12, seed=47)
+    trace.tracer.clear()
+    precommits, committed, failed, _ = run_deferred(step, arrivals)
+    assert len(committed) == 12 and failed == []
+    step.vals.verify_commit(CHAIN, BLOCK, step.height, precommits.make_commit())
+    record = last_flush()
+    assert (record["path"], record["n"], record["memo_hits"]) == ("memo", 12, 12)
+    digests = by_name(trace.tracer.dump())["memo.digest"]
+    assert [d["attrs"] for d in digests] == [{"rows": 12, "native": loaded}] * 2
 
 
 def test_without_a_lane_the_flush_s_verify_batch_hangs_under_the_root():
