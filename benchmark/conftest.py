@@ -1,32 +1,10 @@
-"""One outdated pin of tests/test_hub175.py, held to what it meant.
+"""Holds no fixture. It held one until the accepted tests looked their entries
+up by name: `tests/test_hub175.py` found hub-175's entries at `[-1]` of
+`configs` and `workloads`, and this file gave that one test the benchmark with
+them moved last. No test says now where in its list an entry stands.
 
-`test_the_lint_passes_with_the_new_entries` finds hub-175's entries at `[-1]`
-of `configs` and `workloads`: they were the newest when it was written. A PR
-that adds a cell appends its entries (the driver reads one put in the middle as
-a change to what was there), so the position is the one thing that test says
-which no later benchmark can keep. For that test alone, `BM` is the benchmark
-with hub-175's two entries moved to the end of their lists: the same entries,
-the lint over all of them (it reads no order), and every assertion of the
-test run and enforced. Nothing is keyed on what was added, so the next cell
-needs no line here. A `benchmark` PR that looks the entries up by name
-(PERF.md section 7) deletes this file.
-
-tests/test_benchmark_selftests.py loads the fixture into tier-1, as pytest
-loads it for `python -m pytest benchmark/tests`. It stands here and not in
-tests/: this directory is on `sys.path` while those tests run, so a
-`tests/conftest.py` under it would answer the repo's `from tests.conftest
-import ...` (`tests` is a namespace package)."""
-
-import pytest
-
-
-@pytest.fixture(autouse=True)
-def hub175_entries_last(request, monkeypatch):
-    test = request.function
-    if test.__name__ != "test_the_lint_passes_with_the_new_entries":
-        return
-    module = test.__globals__
-    bm = dict(module["BM"])
-    bm["configs"] = sorted(bm["configs"], key=lambda c: c["name"] == "hub-175")
-    bm["workloads"] = sorted(bm["workloads"], key=lambda w: w["name"] == module["CELL"])
-    monkeypatch.setitem(module, "BM", bm)
+tests/test_benchmark_selftests.py loads this file by its path, as pytest
+loads it for `python -m pytest benchmark/tests`, so it stays, empty. It stands
+here and not in tests/: this directory is on `sys.path` while those tests run,
+so a `tests/conftest.py` under it would answer the repo's `from
+tests.conftest import ...` (`tests` is a namespace package)."""

@@ -248,8 +248,10 @@ def make_ring(seed: int, config: dict, traffic: dict, vals: ValidatorData) -> li
     its own block id, timestamps and draw of absent signers (with 1, the
     default, the commit itself and no list). `absent_share` of the validators
     (the same count in every commit, drawn afresh) do not sign; every
-    `tampered_one_in`-th item carries one flipped bit. All are signed by the
-    one set `vals`, unless the configuration states `headers`: then the ring
+    `tampered_one_in`-th item carries one flipped bit, in a row drawn from the
+    seed or, where the mix states `tampered_seats`, in the row its seat gives
+    (`seated`; the k-th forged item takes the k-th share, cycled). All are
+    signed by the one set `vals`, unless the configuration states `headers`: then the ring
     is one chain of signed headers from the trusted root at `first_height -
     1`, which `vals` signed and the first item's first commit has as `prev`;
     every height has a set of its own, and item j continues where j - 1
@@ -259,6 +261,7 @@ def make_ring(seed: int, config: dict, traffic: dict, vals: ValidatorData) -> li
     per_call = int(traffic.get("commits_per_call", 1))
     first = int(traffic.get("first_height", 1))
     one_in = int(traffic.get("tampered_one_in", 0))
+    seats = traffic.get("tampered_seats")
     chain = None
     if config.get("headers"):
         chain = _Chain(seed, config, vals, first - 1)
@@ -270,12 +273,29 @@ def make_ring(seed: int, config: dict, traffic: dict, vals: ValidatorData) -> li
             run.append(chain.commit(rng) if chain
                        else _signed_commit(rng, config, vals, first + j * per_call + b))
         if one_in and j % one_in == one_in - 1:
-            _, c = _draw_commit(rng, run)
-            bad = int(rng.choice(c.present()))
+            if seats:
+                c, bad = seated(run, float(seats[j // one_in % len(seats)]))
+            else:
+                _, c = _draw_commit(rng, run)
+                bad = int(rng.choice(c.present()))
             c.sigs[bad] = flip_bit(c.sigs[bad], "Rs"[j // one_in % 2])
             c.tampered = (bad,)
         ring.append(run[0] if per_call == 1 else run)
     return ring
+
+
+def seated(item, share: float) -> tuple:
+    """(commit, seat) of the row at position floor(share * rows) of an item's
+    signing rows, all its commits in block order as one list (as `probes`
+    counts them): where a forger who sits at that share of the rows signs.
+    No draw: the same row under every seed."""
+    at = int(share * n_rows(item))
+    for c in commits_of(item):
+        here = c.present()
+        if at < len(here):
+            return c, here[at]
+        at -= len(here)
+    raise ValueError(f"tampered seat {share!r} lies outside [0, 1)")
 
 
 def rows_of(config: dict, vals: ValidatorData, item):

@@ -106,6 +106,9 @@ def flush_reading() -> dict:
         "fused": last.get("fused"),
         "rlc_fallback": bool(last.get("rlc_fallback", False)),
         "recovery_flushes": last.get("recovery_flushes"),
+        # the host backend's combined check answered the whole flush (a row of
+        # an invalid encoding drops out of it with its verdict, and no ladder)
+        "host_rlc": bool(batch.LAST_FLUSH_DETAIL.get("host_rlc", False)),
     }
 
 
@@ -122,6 +125,34 @@ def flush_fault(r: dict, expect: dict, rows: int) -> str | None:
             return "not fused"
     if r["rlc_fallback"] or r["recovery_flushes"]:
         return "fell back to recovery"
+    if r["rows"] != rows:
+        return f"{r['rows']} rows flushed"
+    return None
+
+
+def recovery_fault(r: dict, expect_recovery: dict, rows: int) -> str | None:
+    """None where a call on a forged item recovered where the configuration's
+    `expect_recovery` says: on its backend and one of its paths, by a ladder
+    of at least one and at most `max_recovery_flushes` flushes after the
+    failed combined check, with nothing compiled and all `rows` of the item;
+    else why not. A straight per-signature pass writes no ladder, and a
+    recovery on the host names the host's backend. On the host backend a bad
+    row whose encoding is invalid drops out of the combined check, which then
+    answers with no ladder (`host_rlc`); on the device it fails its lane and
+    the ladder runs."""
+    if r["backend"] != expect_recovery["backend"]:
+        return f"recovery on backend {r['backend']!r}"
+    if r["path"] not in expect_recovery["paths"]:
+        return f"recovery on path {r['path']!r}"
+    if expect_recovery["backend"] == "jax" and r["jax_path"] != r["path"]:
+        return f"recovery jax path {r['jax_path']!r} under {r['path']!r}"
+    walked = r["recovery_flushes"]
+    if not walked and not (r.get("host_rlc") and not r["rlc_fallback"]):
+        return "no recovery ladder walked"
+    if (walked or 0) > int(expect_recovery["max_recovery_flushes"]):
+        return f"{walked} recovery flushes"
+    if r["compile_ms"]:
+        return f"compiled {r['compile_ms']:.0f} ms in the recovery"
     if r["rows"] != rows:
         return f"{r['rows']} rows flushed"
     return None
@@ -260,4 +291,18 @@ def declines() -> None:
             setattr(batch, attr, lambda *a, **kw: None)
 
 
-PROGRAM_CONTROLS = {"unsent_third": unsent_third, "declines": declines}
+def recovery_off_ladder() -> None:
+    """Planted fault, for the tests: a forged flush answered by a straight
+    per-signature pass on the host in the place of the recovery ladder (the
+    device's and the host backend's). It names the right index, so only the
+    recovery contract (`expect_recovery`) can find it."""
+    from tendermint_tpu.crypto import batch
+
+    def straight(pubkeys, msgs, sigs, *a, **kw):
+        return batch._verify_serial_host(pubkeys, msgs, sigs)
+
+    batch._bisect_recover = batch._bisect_recover_host = straight
+
+
+PROGRAM_CONTROLS = {"unsent_third": unsent_third, "declines": declines,
+                    "recovery_off_ladder": recovery_off_ladder}

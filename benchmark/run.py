@@ -181,12 +181,26 @@ def run_window(entry, state, ring_len, seconds, traffic, tracer, keep_trace, reh
     return calls, reduced
 
 
+def recovery_fault(entry, reading: dict, expect_recovery: dict | None, rows: int) -> str | None:
+    """Of a call on a forged item: why its recovery left the configuration's
+    `expect_recovery`; None where it kept to it, or where no contract is
+    stated. A driver without `recovery_fault` cannot be held to one."""
+    if expect_recovery is None:
+        return None
+    check = getattr(entry, "recovery_fault", None)
+    if check is None:
+        return "the entry driver has no recovery_fault"
+    return check(reading, expect_recovery, rows)
+
+
 def judge(cell, entry, state, vals, ring, eprobes, calls, expect, seed, compiles_in_window,
-          alarm):
+          alarm, expect_recovery=None):
     """What the timed path produced against the plain reference, each number
     beside its limit. Runs after the window has closed. An item's rows are
     one list in block order; its verdict is the configuration's rule over
-    the reference's mask of them."""
+    the reference's mask of them. A call on a forged item (the reference's
+    mask not all valid) is held to `expect_recovery` where the
+    configuration states it, under the same `flush_off_path`."""
     config, traffic = cell.config, cell.traffic
     rule = cell.rule()
 
@@ -203,7 +217,9 @@ def judge(cell, entry, state, vals, ring, eprobes, calls, expect, seed, compiles
         verdict_mismatch += c["wrong"]
         want = len(ref_rows[c["ring"]][0])
         clean = all(ref_masks[c["ring"]])
-        fault = entry.flush_fault(c["flush"], expect, want) if clean else None
+        c["forged"] = not clean
+        fault = entry.flush_fault(c["flush"], expect, want) if clean else \
+            recovery_fault(entry, c["flush"], expect_recovery, want)
         c["fault"] = fault
         off_path += fault is not None
         rows_short += clean and c["flush"]["rows_valid"] != sum(ref_masks[c["ring"]])
@@ -250,6 +266,15 @@ def judge(cell, entry, state, vals, ring, eprobes, calls, expect, seed, compiles
              "rows_compared": sum(len(m) for m in ref_masks),
              "entry_probes": entry_said, "faults": faults, "warnings": alarm.records[:5],
              "first_off_path": next((c["fault"] for c in calls if c["fault"]), None)}
+    forged = [c for c in calls if c["forged"]]
+    if forged:  # what the recovery ladder cost; a later reader has it in ctx.calls too
+        walked = [c["flush"].get("recovery_flushes") or 0 for c in forged]
+        walls = [(c["end"] - c["start"]) * 1e3 for c in forged]
+        clean = [(c["end"] - c["start"]) * 1e3 for c in calls if not c["forged"]] or [0.0]
+        notes.update(forged_calls=len(forged), recovery_flushes_p50=stats.percentile(walked, 50),
+                     recovery_flushes_max=max(walked), forged_ms_p50=stats.percentile(walls, 50),
+                     forged_ms_max=max(walls), clean_ms_p50=stats.percentile(clean, 50),
+                     forged_paths=sorted({str(c["flush"]["path"]) for c in forged}))
     return checks, notes
 
 
@@ -282,9 +307,13 @@ def main(argv=None) -> int:
         sys.exit("benchmark: the program's native prep library did not build or load")
     entry.configure(cell.traffic)
     expect = dict(cell.config["expect_flush"])
+    expect_recovery = cell.config.get("expect_recovery")
     if args.rehearse:
         expect = {"backend": os.environ["TMTPU_CRYPTO_BACKEND"],
                   "paths": ["cpu", "rlc", "rlc-pipelined"]}
+        if expect_recovery:  # the host backend's ladder, under the stated count
+            expect_recovery = dict(expect_recovery, backend=expect["backend"],
+                                   paths=["cpu", "rlc-bisect", "persig"])
 
     # set-up: the data from the seed, the program's objects, every shape warm
     split = {"imports_and_device_s": time.perf_counter() - T0}
@@ -322,8 +351,13 @@ def main(argv=None) -> int:
 
     t_judge = time.perf_counter()
     checks, notes = judge(cell, entry, state, vals, ring, eprobes, calls, expect, args.seed,
-                          compiles_in_window, alarm)
+                          compiles_in_window, alarm, expect_recovery)
     notes["judge_s"] = time.perf_counter() - t_judge
+    if reduced and notes.get("forged_calls"):  # the traced calls' device time, forged and clean
+        per_call = list(zip(calls, reduced["device_s_per_call"]))
+        notes["forged_device_ms"] = [s * 1e3 for c, s in per_call if c["forged"]]
+        notes["clean_device_ms_p50"] = stats.percentile(
+            [s * 1e3 for c, s in per_call if not c["forged"]] or [0.0], 50)
     correct = all(v <= limit for v, limit in checks.values())
     item_rows = [data.n_rows(item) for item in ring]
     rows = item_rows[0]  # of one call: what the per-layer readers and work.py reckon by

@@ -16,7 +16,10 @@ are also collected into the repo's tier-1 (tests/test_benchmark_selftests.py).
    another shape than the accepted ones (a call of 6 commits, a skewed stake
    with absent signers, a verdict rule and an entry driver of its own, from
    tests/fixtures/, and a per-layer metric) in a copy of this directory, run
-   (rehearsed on the host) without a line of the harness changed.
+   (rehearsed on the host) without a line of the harness changed; and a
+   throwaway cell of forged commits (the mix seats each forged row, the
+   configuration states the recovery ladder's contract), sound and with its
+   recovery taken off the ladder.
 7. tests/: `correct` fails the control and the planted faults, of the
    accepted cells (test_correct.py, test_hub175.py), of the windowed fixture
    (test_room.py) and of the chain fixture (test_chain.py: a header and a
@@ -164,6 +167,7 @@ WINDOWED_CELL = "skewed-48.window-6"
 
 
 CHAIN_CELL = "chain-16.sequence-12"
+FORGED_CELL = "forge-128.forged-8"
 
 
 def add_fixture_cell(tmp: str, config: str, traffic: str):
@@ -203,6 +207,12 @@ def add_chain_cell(tmp: str):
     return add_fixture_cell(tmp, *CHAIN_CELL.split("."))
 
 
+def add_forged_cell(tmp: str):
+    """The forged fixture (a configuration that states `expect_recovery`, the
+    mix `forged-8` that seats its forged rows) as the cell FORGED_CELL."""
+    return add_fixture_cell(tmp, *FORGED_CELL.split("."))
+
+
 def run_fixture_cell(tmp: str, here: str, cell: str, rows: int, seed: int, control: str = "",
                      seconds: float = 1.0):
     cmd = [sys.executable, os.path.join(here, "run.py"), "--workload", cell, "--seed", str(seed),
@@ -213,6 +223,10 @@ def run_fixture_cell(tmp: str, here: str, cell: str, rows: int, seed: int, contr
 
 def run_windowed_cell(tmp: str, here: str, seed: int, control: str = "", seconds: float = 1.0):
     return run_fixture_cell(tmp, here, WINDOWED_CELL, 48, seed, control, seconds)
+
+
+def run_forged_cell(tmp: str, here: str, seed: int, control: str = "", seconds: float = 1.0):
+    return run_fixture_cell(tmp, here, FORGED_CELL, 128, seed, control, seconds)
 
 
 def test_add_a_cell_as_data():
@@ -251,13 +265,17 @@ def test_add_a_cell_as_data():
         with open(os.path.join(here, "configs", "tiny-48.json"), "w") as f:
             json.dump(cfg, f)
         mix = spec.load_json(os.path.join(here, "traffic", "verify-commit.json"))
-        mix.update(name="open-tampered", loop="open", rate_per_s=25, tampered_one_in=2)
+        mix.update(name="open-tampered", loop="open", rate_per_s=25, tampered_one_in=2,
+                   warmup_calls=4)  # every item in set-up, the forged ones with them
         with open(os.path.join(here, "traffic", "open-tampered.json"), "w") as f:
             json.dump(mix, f)
         bm["configs"].append({"name": "tiny-48", "source": "selftest", "reduced": [],
                               "file": "benchmark/configs/tiny-48.json", "why": "throwaway"})
         bm["workloads"].append({"name": "tiny-48.open-tampered", "config": "tiny-48",
                                 "traffic": "open-tampered", "chips": 1, "why": "throwaway"})
+        for m in bm["per_layer"]:  # a cell reports the metrics that list it, and needs one
+            if m["name"] == "late.max_ms":
+                m["workloads"].append("tiny-48.open-tampered")
         with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
             json.dump(bm, f)
         check(spec.lint(bm, tmp, here) == [], "a second added cell passes the lint")
@@ -271,6 +289,28 @@ def test_add_a_cell_as_data():
         check(30 <= out["attempted"] <= 40, f"its open loop kept its rate ({out['attempted']} calls)")
 
 
+def test_add_a_forged_cell_as_data():
+    with tempfile.TemporaryDirectory() as tmp:
+        bm, here = add_forged_cell(tmp)
+        check(spec.lint(bm, tmp, here) == [], "the copy with the forged cell passes the lint")
+        p = run_forged_cell(tmp, here, seed=2500000040)
+        check(p.returncode == 0, "the forged cell runs (rehearsed): " + p.stderr[-300:])
+        out = json.loads(p.stdout.strip().splitlines()[-1])
+        notes = out["notes"]
+        check(out["correct"] is True and out["checks"]["flush_off_path"][0] == 0
+              and notes["forged_calls"] > 0,
+              f"its {notes['forged_calls']} forged calls are refused by the index and kept to "
+              f"the ladder ({notes['recovery_flushes_max']} recovery flushes at most)")
+        p = run_forged_cell(tmp, here, seed=2500000041, control="recovery_off_ladder")
+        out = json.loads(p.stdout.strip().splitlines()[-1])
+        # every call on item 7, a bit of s: the host's combined check fails, and no ladder runs
+        check(p.returncode == 0 and out["correct"] is False
+              and out["notes"]["forged_calls"] >= out["checks"]["flush_off_path"][0]
+              == out["failed"] > 0,
+              "a forged call answered off the ladder is off its path: "
+              + str(out["notes"]["first_off_path"]))
+
+
 def test_correct_fails_what_is_wrong():
     p = _run([sys.executable, "-m", "pytest", os.path.join(HERE, "tests"), "-q",
               "-p", "no:cacheprovider"])
@@ -279,7 +319,8 @@ def test_correct_fails_what_is_wrong():
 
 def main() -> int:
     for t in (test_lint, test_arithmetic, test_work, test_reduction, test_fails_off_the_chip,
-              test_add_a_cell_as_data, test_correct_fails_what_is_wrong):
+              test_add_a_cell_as_data, test_add_a_forged_cell_as_data,
+              test_correct_fails_what_is_wrong):
         print(f"-- {t.__name__}")
         t()
     print("selftest: all passed")

@@ -125,6 +125,29 @@ def _chain_faults(cfg: dict) -> list:
     return bad
 
 
+def _forged_faults(cfg: dict | None, mix: dict) -> list:
+    """What a mix's forged items (`tampered_one_in`, `tampered_seats`) and a
+    configuration's `expect_recovery` have to be."""
+    bad = []
+    one_in = mix.get("tampered_one_in", 0)
+    if one_in and mix.get("warmup_calls", 0) < mix.get("ring_commits", 0):
+        bad.append(f"warmup_calls {mix.get('warmup_calls')!r} under ring_commits "
+                   f"{mix.get('ring_commits')!r} with tampered_one_in {one_in!r}: the recovery "
+                   "ladder's programs load in set-up, so every item is called there")
+    if "tampered_seats" in mix:
+        seats = mix["tampered_seats"]
+        if not (isinstance(seats, list) and seats and all(
+                type(s) in (int, float) and 0 <= s < 1 for s in seats)):
+            bad.append(f"tampered_seats {seats!r} is not a list of shares in [0, 1)")
+    if cfg is not None and "expect_recovery" in cfg:
+        er = cfg["expect_recovery"]
+        missing = [k for k in ("backend", "paths", "max_recovery_flushes")
+                   if not (isinstance(er, dict) and er.get(k))]
+        if missing:
+            bad.append(f"expect_recovery has no {', '.join(missing)}")
+    return bad
+
+
 def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
     """What selftest.py holds BENCHMARK.json to; returns the faults found."""
     bad = []
@@ -145,7 +168,7 @@ def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
     for word in bm["command"]:
         if word.startswith("/") or ".." in word.split("/"):
             bad.append(f"command word {word!r} leaves the repo")
-    cfg_names, files, rules, chains = set(), set(), {}, set()
+    cfg_names, files, rules, chains, cfgs = set(), set(), {}, set(), {}
     for c in bm["configs"]:
         if set(c) != {"name", "source", "file", "reduced", "why"}:
             bad.append(f"config {c.get('name')}: keys {sorted(c)}")
@@ -159,6 +182,7 @@ def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
             bad.append(f"config {c['name']}: file {c['file']} missing or outside paths")
         else:
             cfg = load_json(os.path.join(root, c["file"]))
+            cfgs[c["name"]] = cfg
             rules[c["name"]] = cfg.get("verdict_rule")
             if "voting_powers" in cfg and len(cfg["voting_powers"]) != cfg.get("validators"):
                 bad.append(f"config {c['name']}: {len(cfg['voting_powers'])} voting_powers "
@@ -199,6 +223,8 @@ def lint(bm: dict, root: str = ROOT, here: str = HERE) -> list:
             bad.append(f"workload {w['name']}: no traffic file {tp}")
         else:
             mix = load_json(tp)
+            bad += [f"workload {w['name']}: {fault}"
+                    for fault in _forged_faults(cfgs.get(w["config"]), mix)]
             if not os.path.isfile(module_path(here, "entries", mix.get("entry", ""))):
                 bad.append(f"traffic {w['traffic']}: no entry driver {mix.get('entry', '')!r}")
             per_call = mix.get("commits_per_call", 1)

@@ -33,8 +33,12 @@ NEW = ["catchup.gather_ms", "catchup.sign_bytes_ms", "catchup.tally_ms", "lane.w
 # the flush of a run, split as PR 26's readers split a commit.verify call's
 FLUSH = ["catchup.prep.hash_ms", "catchup.prep.scalars_ms", "catchup.prep.sort_ms",
          "catchup.prep.wait_ms", "catchup.prep.first_dispatch_ms", "catchup.flush.record_ms"]
-PR26 = ["entry.gather_ms", "entry.sign_bytes_ms", "flush.record_ms", "prep.first_dispatch_ms",
+COMMIT_READERS = ["entry.gather_ms", "entry.sign_bytes_ms", "flush.record_ms", "prep.first_dispatch_ms",
         "prep.hash_ms", "prep.scalars_ms", "prep.sort_ms", "prep.wait_ms"]
+# every accepted cell reports these, and each lists the five accepted cells
+CELL_WIDE = ["entry.outside_flush_ms", "flush.wall_ms", "planner.padding_pct", "prep.ms_per_flush",
+             "aot.first_call_s", "jit.cold_s", "msm.device_ms_per_flush", "msm_roofline",
+             "device.idle_pct", "gc.pause_ms", "call.unnamed_ms"]
 BM = spec.load_benchmark(ROOT)
 CONFIG = spec.load_json(os.path.join(HERE, "configs", "hub-175.json"))
 TRAFFIC = spec.load_json(os.path.join(HERE, "traffic", "catchup.json"))
@@ -44,22 +48,19 @@ TRAFFIC = spec.load_json(os.path.join(HERE, "traffic", "catchup.json"))
 
 
 def test_the_lint_passes_with_the_new_entries():
+    """Entries are looked up by name, so the next cell's entries move none."""
     assert spec.lint(BM, ROOT, HERE) == []
-    assert [c["name"] for c in BM["configs"]][-1] == "hub-175"
-    assert [w["name"] for w in BM["workloads"]][-1] == CELL
-    entry = BM["configs"][-1]
+    entry = {c["name"]: c for c in BM["configs"]}["hub-175"]
     assert entry["reduced"] == [] and "docs/qa" in entry["source"] and "175" in entry["source"]
-    assert BM["workloads"][-1]["chips"] == 1
+    cell = {w["name"]: w for w in BM["workloads"]}[CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("hub-175", "catchup", 1)
 
 
-def test_the_cell_reports_the_new_metrics_and_the_nine_without_a_list():
+def test_the_cell_reports_the_new_metrics_and_the_eleven_cell_wide():
     cell = spec.Cell(BM, CELL)
     names = [m["name"] for m in cell.per_layer]
-    assert names[-11:] == NEW + FLUSH and not set(names) & set(PR26)
-    assert sorted(names[:-11]) == sorted([
-        "entry.outside_flush_ms", "flush.wall_ms", "planner.padding_pct", "prep.ms_per_flush",
-        "aot.first_call_s", "jit.cold_s", "msm.device_ms_per_flush", "msm_roofline",
-        "device.idle_pct"])
+    assert [n for n in names if n in NEW + FLUSH] == NEW + FLUSH and not set(names) & set(COMMIT_READERS)
+    assert sorted(n for n in names if n not in NEW + FLUSH) == sorted(CELL_WIDE)
     assert [m["name"] for m in cell.end_to_end] == [
         "sigs_per_s", "verify_ms_p50", "verify_ms_p95", "setup_s"]
     by = {m["name"]: m for m in BM["per_layer"]}
@@ -79,11 +80,12 @@ def test_the_accepted_cells_report_what_they_did(accepted):
     """PR 26's eight readers now name the two cells they read in; an accepted
     cell reports the same metrics as before and none of the new ones."""
     by = {m["name"]: m for m in BM["per_layer"]}
-    for name in PR26:
+    for name in COMMIT_READERS:
         assert by[name]["workloads"] == ACCEPTED
     names = {m["name"] for m in spec.Cell(BM, accepted).per_layer}
-    assert set(PR26) <= names and not names & set(NEW + FLUSH)
-    assert len(names) == 17  # since PR 33 the same in both: `prep.hidden_pct` (10k only) is retired
+    assert set(COMMIT_READERS) <= names and not names & set(NEW + FLUSH)
+    # the same in both (`prep.hidden_pct`, 10k only, is retired), and the eleven
+    assert names == set(COMMIT_READERS) | set(CELL_WIDE)
     assert "prep.hidden_pct" not in by
     assert not os.path.exists(os.path.join(HERE, "layer_metrics", "prep.hidden_pct.py"))
 

@@ -4,7 +4,7 @@ root at height 1, 100 rows a header), the shipped rule is the fixture's rule,
 the driver `light_sequence` keeps the protocol of README.md on a rehearsal (4
 validators on the program's host backend), with the program sound, with the
 control and with each of the four planted faults a chain cell can have, and
-each of the cell's eight readers gives a number on a recorded ring and None
+each of the cell's nine readers gives a number on a recorded ring and None
 on an empty one.
 
 Run: python -m pytest benchmark/tests -q   (or python benchmark/selftest.py)"""
@@ -29,11 +29,15 @@ import spec  # noqa: E402
 LIGHT_CELL = "light-seq-100.sequence"
 LIGHT_SPANS = ["light.header_checks_ms", "light.gather_ms", "light.sign_bytes_ms",
                "light.tally_ms", "light.store_ms"]
+# the cell's eight span and counter readers and `light.set_leaf_hit_pct` (test_light_set_leaf.py)
 LIGHT_NEW = LIGHT_SPANS + ["light.flushes_per_call", "light.chunks_per_flush",
-                           "light.prep_hidden_pct"]
-NO_LIST = ["entry.outside_flush_ms", "flush.wall_ms", "planner.padding_pct", "prep.ms_per_flush",
-           "aot.first_call_s", "jit.cold_s", "msm.device_ms_per_flush", "msm_roofline",
-           "device.idle_pct"]
+                           "light.prep_hidden_pct", "light.set_leaf_hit_pct"]
+# and the one that reads the calls' clock readings (call_spans.py; test_trace_readers.py)
+LIGHT_OWN = LIGHT_NEW + ["light.client_ms"]  # every metric that lists the light cell alone
+# every accepted cell reports these, and each lists the five accepted cells
+CELL_WIDE = ["entry.outside_flush_ms", "flush.wall_ms", "planner.padding_pct", "prep.ms_per_flush",
+             "aot.first_call_s", "jit.cold_s", "msm.device_ms_per_flush", "msm_roofline",
+             "device.idle_pct", "gc.pause_ms", "call.unnamed_ms"]
 LIGHT_BM = spec.load_benchmark(ROOT)
 LIGHT_CONFIG = spec.load_json(os.path.join(HERE, "configs", "light-seq-100.json"))
 SEQUENCE_MIX = spec.load_json(os.path.join(HERE, "traffic", "sequence.json"))
@@ -64,10 +68,10 @@ def test_the_lint_passes_with_the_light_cell_and_the_accepted_entries_stand():
     assert [w["name"] for w in LIGHT_BM["workloads"] if w["config"] == "light-seq-100"] == [LIGHT_CELL]
 
 
-def test_the_light_cell_reports_the_eight_new_metrics_and_the_nine_without_a_list():
+def test_the_light_cell_reports_its_own_metrics_and_the_eleven_cell_wide():
     cell = spec.Cell(LIGHT_BM, LIGHT_CELL)
     by = by_name(LIGHT_BM["per_layer"])
-    assert set(LIGHT_NEW) | set(NO_LIST) <= {m["name"] for m in cell.per_layer}
+    assert set(LIGHT_OWN) | set(CELL_WIDE) <= {m["name"] for m in cell.per_layer}
     assert {"sigs_per_s", "verify_ms_p50", "verify_ms_p95", "setup_s"} <= {
         m["name"] for m in cell.end_to_end}
     for name in LIGHT_NEW:
@@ -78,7 +82,7 @@ def test_the_light_cell_reports_the_eight_new_metrics_and_the_nine_without_a_lis
     assert by["light.prep_hidden_pct"]["unit"] == "%"
     # no accepted cell reports a new one
     for other in ("commit-10k.verify-commit", "hub-175.catchup"):
-        assert not {m["name"] for m in spec.Cell(LIGHT_BM, other).per_layer} & set(LIGHT_NEW)
+        assert not {m["name"] for m in spec.Cell(LIGHT_BM, other).per_layer} & set(LIGHT_OWN)
 
 
 def test_the_light_configuration_states_what_the_issue_names():
@@ -333,9 +337,10 @@ def record_light(calls: int) -> dict:
 def test_each_light_reader_reads_a_recorded_ring_and_nothing_from_an_empty_one():
     out = record_light(36)
     assert out["roots"] == 36 and out["events_a_call"] <= 60
-    assert out["empty"] == dict.fromkeys(LIGHT_NEW)
+    assert out["empty"] == dict.fromkeys(LIGHT_OWN)
     got = out["recorded"]
-    assert set(got) == set(LIGHT_NEW)
+    assert set(got) == set(LIGHT_OWN)
+    assert got["light.client_ms"] is None  # calls without their clock readings
     for name in LIGHT_SPANS:
         assert isinstance(got[name], float) and 0 < got[name] < 1000, (name, got[name])
         assert out["other_size"][name] is None      # runs of another size are not the cell's

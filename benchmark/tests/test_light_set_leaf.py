@@ -1,5 +1,5 @@
-"""The per-layer metric `light.set_leaf_hit_pct` (PR 35): its entry is the
-last of `per_layer` and lists the light cell alone; its reader gives None on
+"""The per-layer metric `light.set_leaf_hit_pct`: its entry, looked
+up by name, lists the light cell alone; its reader gives None on
 a ring whose roots carry no `set_leaves` (the parent of the PR that added the
 memo of leaf hashes) and the percentage on a recorded ring that does.
 
@@ -13,18 +13,10 @@ import test_light_seq as seq  # the cell, its recorder of a rehearsed ring, its 
 
 NAME = "light.set_leaf_hit_pct"
 
-# test_light_seq.py holds the readers that list the light cell alone to be its
-# `LIGHT_NEW`, the eight of PR 34, and no PR but a `benchmark` one may edit
-# that file. This is the ninth: named there too, every assertion of that file
-# runs over all nine. (That file run alone, without this one, counts eight
-# and says so.) A `benchmark` PR that writes the name into `LIGHT_NEW`
-# deletes these two lines.
-if NAME not in seq.LIGHT_NEW:
-    seq.LIGHT_NEW.append(NAME)
 
-
-def test_the_metric_is_the_last_entry_and_the_light_cells_alone():
-    entry = seq.LIGHT_BM["per_layer"][-1]
+def test_the_metric_lists_the_light_cell_alone():
+    assert NAME in seq.LIGHT_NEW
+    entry = {m["name"]: m for m in seq.LIGHT_BM["per_layer"]}[NAME]
     assert entry == {"name": NAME, "unit": "%", "better": "higher", "source": "program_counter",
                      "layer": "light client", "moves": "verify_ms_p50",
                      "workloads": [seq.LIGHT_CELL]}

@@ -4,17 +4,8 @@ names, the rule `vote_step` says what the driver has to say, the driver
 `vote_commit` keeps the protocol of README.md on a rehearsal (24 validators on
 the program's host backend) with the program sound and under each of its three
 controls, and each of the cell's nine readers gives a number on a recorded ring
-and None on an empty one.
-
-One outdated pin, held to what it meant (the third of its kind: PERF.md
-section 7): `test_light_set_leaf.py` finds `light.set_leaf_hit_pct` at
-`per_layer[-1]` of `test_light_seq.LIGHT_BM`, where it stood when PR 35 wrote
-it. A PR that adds per-layer metrics appends them, so this file, which the
-loaders import after both light files (`sorted(glob)`, pytest's own order),
-gives `test_light_seq` the benchmark WITHOUT this PR's entries: what PR 35
-left, which still lints, and every assertion of both light files still runs.
-A `benchmark` PR that looks the entry up by name deletes the two lines below
-marked `# the pin`.
+and None on an empty one. Entries are looked up by name: nothing here says
+where in its list an entry stands, so the next cell's entries move none.
 
 Run: python -m pytest benchmark/tests -q   (or python benchmark/selftest.py)"""
 
@@ -43,20 +34,20 @@ LIVE_READINGS = ["votes.add_ms", "votes.memo_ms", "votes.memo_hit_pct", "votes.f
 LIVE_NEW = ["votes.add_ms", "votes.gather_ms", "votes.sign_bytes_ms", "votes.count_ms",
             "votes.make_commit_ms", "votes.memo_ms", "votes.commit_verify_ms", "votes.memo_hit_pct",
             "votes.flushes_per_call"]
+# the three that came with the recorder's new spans (their readers: test_trace_readers.py)
+LIVE_OWN = LIVE_NEW + ["votes.pending_ms", "votes.memo_digest_ms", "votes.provenance_ms"]
 LIVE_BM = spec.load_benchmark(ROOT)
 LIVE_CONFIG = spec.load_json(os.path.join(HERE, "configs", "live-10k.json"))
 VOTE_COMMIT_MIX = spec.load_json(os.path.join(HERE, "traffic", "vote-commit.json"))
 
 
 def without_live(bm: dict) -> dict:
-    """The benchmark as PR 35 left it: this PR's three kinds of entries out."""
+    """The benchmark without the live cell: its configuration, its cell, the
+    metrics that list it alone, and its name in every other list."""
     return dict(bm, configs=[c for c in bm["configs"] if c["name"] != "live-10k"],
                 workloads=[w for w in bm["workloads"] if w["name"] != LIVE_CELL],
-                per_layer=[m for m in bm["per_layer"] if m.get("workloads") != [LIVE_CELL]])
-
-
-test_light_seq.LIGHT_BM = without_live(LIVE_BM)  # the pin
-assert test_light_seq.LIGHT_BM["per_layer"][-1]["name"] == "light.set_leaf_hit_pct"  # the pin
+                per_layer=[dict(m, workloads=[w for w in m["workloads"] if w != LIVE_CELL])
+                           for m in bm["per_layer"] if m.get("workloads") != [LIVE_CELL]])
 
 
 # -- the entries and the files
@@ -69,7 +60,8 @@ def named(entries: list) -> dict:
 def test_the_lint_passes_with_live10k_appended_and_nothing_else_moved():
     assert spec.lint(LIVE_BM, ROOT, HERE) == []
     assert spec.lint(without_live(LIVE_BM), ROOT, HERE) == []
-    config, cell = LIVE_BM["configs"][-1], LIVE_BM["workloads"][-1]
+    config = {c["name"]: c for c in LIVE_BM["configs"]}["live-10k"]
+    cell = {w["name"]: w for w in LIVE_BM["workloads"]}[LIVE_CELL]
     assert (config["name"], config["file"], config["reduced"]) == (
         "live-10k", "benchmark/configs/live-10k.json", [])
     assert config["source"] == LIVE_CONFIG["source"] and len(config["source"]) <= 200
@@ -78,7 +70,7 @@ def test_the_lint_passes_with_live10k_appended_and_nothing_else_moved():
         assert word in config["source"], word
     assert cell == dict(cell, name=LIVE_CELL, config="live-10k", traffic="vote-commit", chips=1)
     assert "every validator and full node" in cell["why"]
-    assert [m["name"] for m in LIVE_BM["per_layer"][-9:]] == LIVE_NEW
+    assert [m["name"] for m in LIVE_BM["per_layer"] if m["workloads"] == [LIVE_CELL]] == LIVE_OWN
     # the accepted entries stand before them, in the order they had
     assert [c["name"] for c in LIVE_BM["configs"][:4]] == [
         "commit-10k", "commit-1024", "hub-175", "light-seq-100"]
@@ -87,10 +79,10 @@ def test_the_lint_passes_with_live10k_appended_and_nothing_else_moved():
         "light-seq-100.sequence"]
 
 
-def test_the_live_cell_reports_its_nine_metrics_and_the_nine_without_a_list():
+def test_the_live_cell_reports_its_nine_metrics_and_the_eleven_cell_wide():
     cell = spec.Cell(LIVE_BM, LIVE_CELL)
     by = named(LIVE_BM["per_layer"])
-    assert {m["name"] for m in cell.per_layer} == set(LIVE_NEW) | set(test_light_seq.NO_LIST)
+    assert {m["name"] for m in cell.per_layer} == set(LIVE_OWN) | set(test_light_seq.CELL_WIDE)
     assert {m["name"] for m in cell.end_to_end} == {"sigs_per_s", "verify_ms_p50", "verify_ms_p95",
                                                     "setup_s"}
     for name in LIVE_NEW:
@@ -107,7 +99,7 @@ def test_the_live_cell_reports_its_nine_metrics_and_the_nine_without_a_list():
     assert (by["votes.memo_hit_pct"]["better"], by["votes.memo_hit_pct"]["unit"]) == ("higher", "%")
     assert all(by[n]["better"] == "lower" for n in LIVE_NEW if n != "votes.memo_hit_pct")
     for other in ("commit-10k.verify-commit", "hub-175.catchup", "light-seq-100.sequence"):
-        assert not {m["name"] for m in spec.Cell(LIVE_BM, other).per_layer} & set(LIVE_NEW)
+        assert not {m["name"] for m in spec.Cell(LIVE_BM, other).per_layer} & set(LIVE_OWN)
 
 
 def test_the_live_configuration_states_what_the_issue_names():
@@ -374,7 +366,7 @@ def test_each_vote_reader_reads_a_recorded_ring_and_nothing_from_an_empty_one():
     out = record_live(36)
     assert out["roots"] == {"votes.flush": 36, "votes.make_commit": 36, "commit.verify": 36}
     assert out["events_a_call"] <= 40
-    assert out["empty"] == dict.fromkeys(LIVE_NEW)
+    assert out["empty"] == dict.fromkeys(LIVE_OWN)
     # four and a half laps over the ring, and after the first call no vote flush hit the memo
     assert out["faults"] == [None] * 36
     assert out["reading"] == {"flushes": 2, "device_flushes": 1, "rows": 24, "rows_valid": 24,
@@ -382,7 +374,11 @@ def test_each_vote_reader_reads_a_recorded_ring_and_nothing_from_an_empty_one():
                               "commit_rows": 24, "commit_memo_hits": 24, "vote_flushes": 1,
                               "lane_flushes": 1, "failed": 0}
     got = out["recorded"]
-    assert set(got) == set(LIVE_NEW)
+    assert set(got) == set(LIVE_OWN)
+    # the queue's wait is read off the ring by the flush's root; the digests and the
+    # scorer are put down to calls by time, and these calls carry no clock readings
+    assert isinstance(got["votes.pending_ms"], float) and 0 < got["votes.pending_ms"] < 1000
+    assert got["votes.memo_digest_ms"] is None and got["votes.provenance_ms"] is None
     for name in LIVE_SPANS + ["votes.add_ms", "votes.memo_ms"]:
         assert isinstance(got[name], float) and 0 < got[name] < 1000, (name, got[name])
     for name in LIVE_SPANS:

@@ -5,7 +5,9 @@ absent, calls of 6 commits, the rule `tally_valid_power`, a driver built on
 reference.verify_rows alone) is laid into a copy of this directory as a later
 PR would add it and run through run.py --rehearse, sound and with each fault
 such a cell can have. And what must not have moved: the data of the accepted
-configurations comes out of a seed byte for byte as before items existed.
+configurations comes out of a seed byte for byte as before items existed, and
+every mix without `tampered_seats` (the fixtures', and mixes that forge) as
+before a mix could seat its forged rows.
 
 Run: python -m pytest benchmark/tests -q   (or python benchmark/selftest.py)"""
 
@@ -344,3 +346,57 @@ def test_the_accepted_configurations_data_is_the_parents_byte_for_byte(name, see
     if name != "hub-175":
         assert "voting_powers" not in config and "commits_per_call" not in traffic
     assert digest(config, traffic, seed) == PINNED[(name, seed)]
+
+
+# taken on commit 3f0c4f0, before a mix could seat its forged rows: the
+# two accepted configurations not pinned above, at one seed (full size, 3-4 s
+# each), both fixtures, and mixes that forge with no `tampered_seats`, which no
+# accepted mix does (an item of one commit, of six, and of a chain's twelve)
+FORGED = {"tampered_one_in": 2}
+PINNED_BEFORE_SEATS = {
+    ("live-10k", "vote-commit", None, 5):
+        "88d301b2f6700e4b1bd023ebe860e80d1abcd19af91ffd9a768dd5b54c1f4d1b",
+    ("light-seq-100", "sequence", None, 5):
+        "a50a2d9f94cc9d6aff83dc17473d6ae6ec4726f79ef643a13f205538f10d3d7b",
+    ("skewed-48", "window-6", None, 5):
+        "c35e1efd824020624cd96c9d347efbb0c35626b0e75ffca25f3cbc852ffb6e73",
+    ("skewed-48", "window-6", None, 2147483911):
+        "c98e2537b0acc3786c4ae2f0c1f1aabc8d5c26e4ce41a0089f4ab11bae4e075b",
+    ("skewed-48", "window-6", None, 3000000402):
+        "8cf25664e6bb3cea5d3a11521a5627a2b7c391739a1da68801ea25faf3846d58",
+    ("chain-16", "sequence-12", None, 5):
+        "2e0aad8a62e7caa1e3b95d60ecac13c4a17aa78b457fa16a4baaf8d46cd63681",
+    ("chain-16", "sequence-12", None, 2147483911):
+        "9378c8025b351d76d76d1fab852833cb55f283b27d2231356ef69350251541d4",
+    ("chain-16", "sequence-12", None, 3000000402):
+        "044021e63b72992f0feebb85c4bf252624a5d0620da33e68fab7714718e5d6b7",
+    ("commit-1024", "verify-commit", "forged", 5):
+        "f21e6106882a569ef4a164751f9e00d3a1738ebf82a52906aab1f7c28a0ebb16",
+    ("commit-1024", "verify-commit", "forged", 2147483911):
+        "880fc81f214f7ad2678f2022f9eab6a80e3fcfcfecc62a060337782c08a354f9",
+    ("commit-1024", "verify-commit", "forged", 3000000402):
+        "28bd4d05087219acd2f594bac32d994a57ecb665d3f4ce787088202395717ae2",
+    ("skewed-48", "window-6", "forged", 5):
+        "5cde494c947ee32d860076154460eba90ab1776b69ca08dd5fab391c456a4651",
+    ("skewed-48", "window-6", "forged", 2147483911):
+        "df18ec772639f5b7b76207335c9f0f7f8fe648267d9f9827e69a0ef9d8d2b851",
+    ("skewed-48", "window-6", "forged", 3000000402):
+        "dbdb8b7a7090e5d1bc101029a9c3aadbae234c1997adcd3f9275f7a7144cab49",
+    ("chain-16", "sequence-12", "forged", 5):
+        "74da29d8a8c8c130bef934430f7420080596114c4e68e32910c0d4258c254573",
+    ("chain-16", "sequence-12", "forged", 2147483911):
+        "5c6fec33a046fbcdce5740601e61e719728f614906db356641162d2e4e88240c",
+    ("chain-16", "sequence-12", "forged", 3000000402):
+        "cd7866986c5f70c23697918a26aa41cf3fa188872be2640d71a5a171752016fa",
+}
+
+
+@pytest.mark.parametrize("name,mix,forged,seed", sorted(PINNED_BEFORE_SEATS, key=str))
+def test_every_mix_without_seats_gives_the_parents_data_byte_for_byte(name, mix, forged, seed):
+    base = FIXTURES if os.path.isfile(os.path.join(FIXTURES, "configs", name + ".json")) else HERE
+    config = spec.load_json(os.path.join(base, "configs", name + ".json"))
+    traffic = spec.load_json(os.path.join(base, "traffic", mix + ".json"))
+    assert "tampered_seats" not in traffic and traffic.get("tampered_one_in", 0) == 0
+    if forged:
+        traffic = dict(traffic, **FORGED)
+    assert digest(config, traffic, seed) == PINNED_BEFORE_SEATS[(name, mix, forged, seed)]

@@ -4,11 +4,6 @@ None with fewer than 30 calls covered or nothing of its own in the ring; and
 each reads a number off a ring the program itself recorded on a rehearsal of
 the live and the light cell (24 and 4 validators on the host backend).
 
-Second half of the pin of `test_accepted_view.py` (imported first): the
-accepted files have loaded, so `spec.load_benchmark` is given back, and their
-recorders of a rehearsed ring, which read every metric that names their cell
-alone, are held to the metrics those files name (the six are read here).
-
 Run: python -m pytest benchmark/tests -q   (or python benchmark/selftest.py)"""
 
 from __future__ import annotations
@@ -28,24 +23,6 @@ sys.path.insert(0, HERE)
 import program_spans  # noqa: E402
 import spec  # noqa: E402
 import test_accepted_view as view  # noqa: E402  (by the name both loaders give it)
-import test_light_seq as light_seq  # noqa: E402
-import test_live10k as live10k  # noqa: E402
-
-spec.load_benchmark = view.FULL_LOAD  # the pin
-
-
-def _named_only(record):
-    def recorded(calls: int) -> dict:
-        out = record(calls)
-        for k in ("empty", "recorded", "other_size", "no_root_stated"):
-            if k in out:
-                out[k] = {n: v for n, v in out[k].items() if n not in view.NEW38}
-        return out
-    return recorded
-
-
-live10k.record_live = _named_only(live10k.record_live)  # the pin
-light_seq.record_light = _named_only(light_seq.record_light)  # the pin
 
 BM = view.FULL_BM
 MS = 10**6  # ns a millisecond
@@ -107,7 +84,7 @@ def test_the_call_readers_on_a_handmade_live_ring(monkeypatch):
     around its digests, scorer 2, the commit made 6, the commit's digests 4;
     the two roots with children name nothing of their own)."""
     events, calls = live_ring(40)
-    got = {n: read(monkeypatch, n, events, calls) for n in view.NEW38 if n != "light.client_ms"}
+    got = {n: read(monkeypatch, n, events, calls) for n in view.RECORDER_SIX if n != "light.client_ms"}
     assert got["call.unnamed_ms"] == pytest.approx(70.0)
     assert got["votes.memo_digest_ms"] == pytest.approx(7.0)
     assert got["votes.provenance_ms"] == pytest.approx(2.0)
@@ -121,7 +98,7 @@ def test_the_call_readers_on_a_handmade_live_ring(monkeypatch):
 
 def test_none_under_thirty_calls_or_with_nothing_of_their_own(monkeypatch):
     few_events, few_calls = live_ring(29)  # 29 flushes, 25 calls covered
-    for name in view.NEW38:
+    for name in view.RECORDER_SIX:
         cell = view.LIGHT_CELL if name == "light.client_ms" else view.LIVE_CELL
         assert read(monkeypatch, name, few_events, few_calls, cell=cell) is None, name
         assert read(monkeypatch, name, [], live_ring(40)[1], cell=cell) is None, name
@@ -207,8 +184,8 @@ def record(cell: str, n: int, names: list) -> dict:
 
 
 def test_the_readers_on_a_ring_the_live_cells_rehearsal_recorded():
-    out = record(view.LIVE_CELL, 24, list(view.NEW38))
-    assert set(out) - {"gc_spans", "wall_ms"} == set(view.NEW38) - {"light.client_ms"}
+    out = record(view.LIVE_CELL, 24, list(view.RECORDER_SIX))
+    assert set(out) - {"gc_spans", "wall_ms"} == set(view.RECORDER_SIX) - {"light.client_ms"}
     for name in ("votes.pending_ms", "votes.memo_digest_ms", "votes.provenance_ms"):
         assert isinstance(out[name], float) and 0 < out[name] < out["wall_ms"], (name, out)
     assert 0 <= out["call.unnamed_ms"] < out["wall_ms"]
@@ -217,7 +194,7 @@ def test_the_readers_on_a_ring_the_live_cells_rehearsal_recorded():
 
 
 def test_the_readers_on_a_ring_the_light_cells_rehearsal_recorded():
-    out = record(view.LIGHT_CELL, 4, list(view.NEW38))
+    out = record(view.LIGHT_CELL, 4, list(view.RECORDER_SIX))
     assert set(out) - {"gc_spans", "wall_ms"} == {"gc.pause_ms", "call.unnamed_ms",
                                                  "light.client_ms"}
     assert isinstance(out["light.client_ms"], float) and 0 < out["light.client_ms"] < out["wall_ms"]
