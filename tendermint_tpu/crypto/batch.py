@@ -813,58 +813,107 @@ def _verify_serial_host(
     return out
 
 
-def _bisect_recover_host(pubkeys, msgs, sigs) -> np.ndarray:
-    """Host-arm twin of _bisect_recover: after the striped host-RLC
-    combined check fails, isolate bad rows with host-RLC sub-checks over
-    pow2 halves and run the serial loop only at small leaves — the CPU
-    fallback under a poisoning flood keeps the same log-cost shape as the
-    device path (docs/ROBUSTNESS.md adversarial flush defense)."""
-    n = len(pubkeys)
+def _ladder_half(m: int, leaf: int, floor: int) -> int:
+    """The recovery ladder's split rule: a failed range of m rows splits at
+    the largest power of two under m; 0 where the range is a leaf instead
+    (at or under `leaf` rows, or too small for two combined checks of at
+    least `floor` rows)."""
+    if m <= leaf or m < 2 * floor:
+        return 0
+    return 1 << ((m - 1).bit_length() - 1)
+
+
+def _ladder_leaf_sizes(n: int, leaf: int, floor: int) -> set:
+    """Every leaf size the split rule reaches from n rows. The split tree is
+    fixed by n, so a sparse bad row lands in a leaf of one of these sizes
+    (after the dense-flood bail a range goes to the leaf at whatever size)."""
+    half = _ladder_half(n, leaf, floor)
+    if not half:
+        return {n}
+    return _ladder_leaf_sizes(half, leaf, floor) | _ladder_leaf_sizes(n - half, leaf, floor)
+
+
+def _ladder_walk(n, combined, leaf_fn, leaf: int, floor: int, backend: str):
+    """The recovery ladder over n rows that hold at least one bad row, one
+    walk for the device's arm (_bisect_recover) and the host's
+    (_bisect_recover_host), which give it their combined check, leaf and
+    thresholds. A range splits by `_ladder_half`; each half gets one
+    combined check (`combined(lo, hi)` -> (the exact mask or None, flushes
+    it took)); a passing half is done, a failing half descends. When the
+    first half passes, the second is KNOWN bad (the parent failed) and
+    descends unchecked. A leaf, and every range after _BISECT_MAX_BAD
+    poisoned leaves (a dense flood: splitting costs more than it saves), is
+    verified row by row (`leaf_fn(lo, hi)` -> mask).
+
+    Spans: `recovery.ladder` (rows, checks, leaves, flushes, rows_checked,
+    leaf_rows, backend), `recovery.check` a combined check (rows, ok),
+    `recovery.leaf` a leaf (rows). The counts are returned with the mask,
+    and added to the flush's detail as `recovery_<count>` (summed over every
+    ladder of one flush: a streamed flush recovers chunk by chunk)."""
     out = np.zeros(n, dtype=bool)
-    leaf = max(_BISECT_LEAF // 4, 1)
     max_bad = _BISECT_MAX_BAD
-    flushes = 0
-    bad_leaves = 0
+    tally = dict.fromkeys(("checks", "leaves", "flushes", "rows_checked", "leaf_rows"), 0)
 
-    def _combined(lo, hi):
-        nonlocal flushes
-        flushes += 1
-        try:
-            return _verify_batch_cpu_rlc(
-                pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi]
-            )
-        except Exception:
-            return None  # broken host RLC degrades to serial leaves
+    def check(lo, hi):
+        with _trace.span("recovery.check", rows=hi - lo) as sp:
+            mask, flushes = combined(lo, hi)
+            sp.set(ok=mask is not None)
+        tally["checks"] += 1
+        tally["flushes"] += flushes
+        tally["rows_checked"] += hi - lo
+        return mask
 
-    def _go(lo, hi):
-        nonlocal flushes, bad_leaves
-        m = hi - lo
-        if m <= leaf or m < 2 * _HOST_RLC_MIN or bad_leaves >= max_bad:
-            flushes += 1
-            bad_leaves += 1
-            out[lo:hi] = _verify_serial_host(
-                pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi]
-            )
+    def go(lo, hi):
+        # invariant: [lo, hi) is known to contain at least one bad row
+        half = _ladder_half(hi - lo, leaf, floor) if tally["leaves"] < max_bad else 0
+        if not half:
+            tally["leaves"] += 1
+            tally["flushes"] += 1
+            tally["leaf_rows"] += hi - lo
+            with _trace.span("recovery.leaf", rows=hi - lo):
+                out[lo:hi] = leaf_fn(lo, hi)
             return
-        half = 1 << ((m - 1).bit_length() - 1)
         mid = lo + half
-        first = _combined(lo, mid)
+        first = check(lo, mid)
         if first is not None:
             out[lo:mid] = first
-            _go(mid, hi)
+            go(mid, hi)  # parent failed, first half clean: second is bad
             return
-        _go(lo, mid)
-        if hi - mid >= _HOST_RLC_MIN and bad_leaves < max_bad:
-            second = _combined(mid, hi)
+        go(lo, mid)
+        if hi - mid >= floor and tally["leaves"] < max_bad:
+            second = check(mid, hi)
             if second is not None:
                 out[mid:hi] = second
                 return
-        _go(mid, hi)
+        go(mid, hi)
 
-    _go(0, n)
-    LAST_FLUSH_DETAIL["recovery_flushes"] = (
-        LAST_FLUSH_DETAIL.get("recovery_flushes", 0) + flushes
-    )
+    with _trace.span("recovery.ladder", rows=n, backend=backend) as sp:
+        go(0, n)
+        sp.set(**tally)
+    for k in ("flushes", "checks", "leaves", "rows_checked"):
+        LAST_FLUSH_DETAIL["recovery_" + k] = LAST_FLUSH_DETAIL.get("recovery_" + k, 0) + tally[k]
+    return out, tally
+
+
+def _bisect_recover_host(pubkeys, msgs, sigs) -> np.ndarray:
+    """Host-arm twin of _bisect_recover: after the striped host-RLC
+    combined check fails, the same ladder walk (_ladder_walk) with host-RLC
+    sub-checks, a quarter of the device's leaf and the serial loop at the
+    leaves — the CPU fallback under a poisoning flood keeps the same
+    log-cost shape as the device path (docs/ROBUSTNESS.md adversarial flush
+    defense)."""
+
+    def combined(lo, hi):
+        try:
+            return _verify_batch_cpu_rlc(pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi]), 1
+        except Exception:
+            return None, 1  # broken host RLC degrades to serial leaves
+
+    def leaf(lo, hi):
+        return _verify_serial_host(pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi])
+
+    out, _ = _ladder_walk(len(pubkeys), combined, leaf, max(_BISECT_LEAF // 4, 1),
+                          _HOST_RLC_MIN, "cpu")
     return out
 
 
@@ -2507,15 +2556,13 @@ def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarr
     sub-range's combined check rides that as one chunk: a whole-flush
     program per pow2 size (six under 10,624 rows, minutes each cold, PR 29)
     would load or compile behind the flush while every lane waits.
-    Either way recovery compiles no new shape for its combined checks.
-    Each half gets one combined check (sharded when meshed);
-    a passing half is done (RLC pass returns the exact precheck mask, the
-    same invariant the fast path rests on), a failing half recurses. When
-    the first half passes, the second is KNOWN bad (the parent failed) and
-    descends without re-checking. Ranges at/below the leaf size — and
-    everything after _BISECT_MAX_BAD poisoned leaves (dense flood:
-    splitting costs more than it saves) — recover per-signature, the
-    byte-identical code path the straight fallback has always used.
+    Either way recovery compiles no new shape for its combined checks; the
+    per-signature leaves are the shapes `prewarm` loads. The walk is
+    _ladder_walk's: each half gets one combined check (sharded when
+    meshed; an RLC pass returns the exact precheck mask, the same invariant
+    the fast path rests on), leaves of at most _BISECT_LEAF rows, or under
+    2 x RLC_MIN, recover per-signature, the byte-identical code path the
+    straight fallback has always used.
 
     Cost for one bad row over C = ceil(n/leaf) chunks: at most
     2·ceil(log2 C)+1 device flushes (<= 2 combined checks per level, one
@@ -2523,63 +2570,27 @@ def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarr
     that n-leaf rows short-circuit through combined checks and the leaf
     flush is tiny, so a poisoned flood degrades the vote path by a log
     factor, not a linear one."""
-    n = len(pubkeys)
-    out = np.zeros(n, dtype=bool)
-    leaf = _BISECT_LEAF
-    max_bad = _BISECT_MAX_BAD
-    flushes = 0
-    bad_leaves = 0
 
-    def _combined(lo, hi):
+    def combined(lo, hi):
         # Mirrors the fast-path rung choice: sharded combined while a mesh
         # stands; if the mesh fell MID-CHECK, retry single-chip rather than
         # mislabel a device loss as a poisoned range.
-        nonlocal flushes
-        flushes += 1
         pk, ms, sg = pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi]
+        flushes = 1
         if _sharded_runner() is not None:
             mask = _verify_batch_rlc_sharded(pk, ms, sg)
             if mask is not None or _sharded_runner() is not None:
-                return mask
+                return mask, flushes
             flushes += 1
         if chunk_bucket:  # a sub-range of an in-budget flush: it fits
-            return _verify_batch_pipelined(pk, ms, sg)
-        return _verify_batch_rlc(pk, ms, sg)
+            return _verify_batch_pipelined(pk, ms, sg), flushes
+        return _verify_batch_rlc(pk, ms, sg), flushes
 
-    def _leaf(lo, hi):
-        nonlocal flushes, bad_leaves
-        flushes += 1
-        bad_leaves += 1
-        out[lo:hi] = _persig_flush(
-            pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi], _sharded_runner()
-        )
+    def leaf(lo, hi):
+        return _persig_flush(pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi], _sharded_runner())
 
-    def _go(lo, hi):
-        # invariant: [lo, hi) is known to contain at least one bad row
-        m = hi - lo
-        if m <= leaf or m < 2 * RLC_MIN or bad_leaves >= max_bad:
-            _leaf(lo, hi)
-            return
-        half = 1 << ((m - 1).bit_length() - 1)  # largest pow2 < m
-        mid = lo + half
-        first = _combined(lo, mid)
-        if first is not None:
-            out[lo:mid] = first
-            _go(mid, hi)  # parent failed, first half clean: second is bad
-            return
-        _go(lo, mid)
-        if hi - mid >= RLC_MIN and bad_leaves < max_bad:
-            second = _combined(mid, hi)
-            if second is not None:
-                out[mid:hi] = second
-                return
-        _go(mid, hi)
-
-    _go(0, n)
-    LAST_FLUSH_DETAIL["recovery_flushes"] = (
-        LAST_FLUSH_DETAIL.get("recovery_flushes", 0) + flushes
-    )
-    if bad_leaves > 1 or flushes > 1:
+    out, tally = _ladder_walk(len(pubkeys), combined, leaf, _BISECT_LEAF, RLC_MIN, "jax")
+    if tally["leaves"] > 1 or tally["flushes"] > 1:
         LAST_JAX_PATH[0] = "rlc-bisect"
     return out
 
@@ -3290,6 +3301,9 @@ def verify_batch(
                 compile_s=compile_s if compile_s > 0 else None,
                 rlc_fallback=detail.get("rlc_fallback", False),
                 recovery_flushes=detail.get("recovery_flushes"),
+                recovery_checks=detail.get("recovery_checks"),
+                recovery_leaves=detail.get("recovery_leaves"),
+                recovery_rows_checked=detail.get("recovery_rows_checked"),
                 quarantined=quarantined,
                 tracer_=_trace.tracer if vb.recording else None,
                 **memo,
@@ -3399,7 +3413,9 @@ def prewarm(
     bucket (the ONE shape every streamed super-batch runs: rlc_partial +
     fold + identity, ops/msm_jax.py) is warmed in the same background
     thread, so the first oversized catch-up flush doesn't eat a multi-minute
-    compile mid-sync.
+    compile mid-sync. On a single-device RLC route the recovery ladder's
+    per-signature leaves for n_vals rows are loaded too, so a node's first
+    forged commit compiles nothing either.
 
     Called from node startup in a BACKGROUND thread (node/node.py) so a node
     cold-starting into a vote storm doesn't stall consensus for the first
@@ -3457,6 +3473,13 @@ def prewarm(
         # ISSUE 19: also warm the SURVIVOR half-mesh chunk bucket, so the
         # first post-device-loss flush pays a warm dispatch, not a compile
         _prewarm_survivor_mesh(pk, msg, sig)
+    if route.path in ("rlc", "rlc-pipelined"):
+        # the recovery ladder's per-signature leaves, one flush a bucket the
+        # split rule's leaves reach for n_vals rows: without them a node's
+        # first forged commit compiles the leaf inside its vote path
+        leaves = _ladder_leaf_sizes(n_vals, _BISECT_LEAF, RLC_MIN)
+        for b in sorted({_bucket(m) for m in leaves}):
+            _persig_flush([pk] * b, [msg] * b, [sig] * b, None)
     if pubkeys:
         # decode the real validator keys so consensus's first flush is a
         # cache hit (this is the exact decode steady state amortizes away)

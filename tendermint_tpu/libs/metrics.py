@@ -706,6 +706,11 @@ class BatchVerifyMetrics:
             "Device/host flushes spent isolating bad rows after a combined-"
             "check failure (RLC bisection sub-checks + per-sig leaves).",
         )
+        self.recovery_rows_checked = reg.counter(
+            f"{ns}_recovery_rows_checked_total",
+            "Rows the recovery ladder's combined sub-checks took, summed "
+            "over its checks.",
+        )
         self.quarantined_rows = reg.counter(
             f"{ns}_quarantined_rows_total",
             "Rows verified while their source was quarantined (routed "

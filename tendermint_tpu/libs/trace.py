@@ -564,6 +564,9 @@ def record_flush(
     memo_inserted: Optional[int] = None,
     memo_s: Optional[float] = None,
     recovery_flushes: Optional[int] = None,
+    recovery_checks: Optional[int] = None,
+    recovery_leaves: Optional[int] = None,
+    recovery_rows_checked: Optional[int] = None,
     quarantined: Optional[int] = None,
     tracer_: Optional[Tracer] = None,
 ) -> None:
@@ -602,6 +605,8 @@ def record_flush(
     # crypto/provenance.py): recovery cost + quarantined-row attribution
     if recovery_flushes:
         m.recovery_flushes.inc(recovery_flushes)
+    if recovery_rows_checked:
+        m.recovery_rows_checked.inc(recovery_rows_checked)
     if quarantined:
         m.quarantined_rows.inc(quarantined)
     # streamed flush planner (crypto/batch.py ISSUE 13): chunk count per
@@ -668,6 +673,12 @@ def record_flush(
         last["memo_ms"] = round((memo_s or 0.0) * 1e3, 4)
     if recovery_flushes is not None:
         last["recovery_flushes"] = recovery_flushes
+    # the ladder's walk (crypto/batch.py _ladder_walk): its combined checks,
+    # its per-signature leaves and the rows the checks took
+    if recovery_checks is not None:
+        last["recovery_checks"] = recovery_checks
+        last["recovery_leaves"] = recovery_leaves or 0
+        last["recovery_rows_checked"] = recovery_rows_checked or 0
     if quarantined is not None:
         last["quarantined"] = quarantined
     with _STATS_LOCK:

@@ -21,7 +21,10 @@ CONTRACT with the device kernels replaced by ed25519_ref host twins
 - a dense flood trips the adaptive bail (batch._BISECT_MAX_BAD) and the
   mask stays exact;
 - TMTPU_BISECT=0 restores the straight-to-per-sig arm (one recovery
-  flush, identical mask).
+  flush, identical mask);
+- both arms walk ONE ladder (_ladder_walk): its `recovery.*` spans and the
+  flush record's recovery_* counts are the split rule's, written out here
+  independently, and its leaf sizes are the ones `prewarm` loads.
 """
 
 import math
@@ -293,6 +296,131 @@ def test_host_naive_arm_counts_its_recovery_flush(bisect_env, monkeypatch):
 
     assert not mask[7] and mask.sum() == 63
     assert batch.LAST_FLUSH_DETAIL.get("recovery_flushes", 0) - detail0 == 1
+
+
+# ---------------------------------------------------------------------------
+# One walk for both arms: its spans and the flush record's counts.
+
+
+def _split_rule_walk(n, bad, leaf, floor):
+    """The ladder's rule for one bad row, written out: a range splits at the
+    largest power of two under its size unless it has at most `leaf` rows or
+    fewer than 2 x `floor`; the first half is checked; a failing first half
+    descends and the second is checked where it has `floor` rows or more
+    (it passes: the one bad row is in the first), else it descends
+    unchecked; a passing first half leaves the second known bad."""
+    got = {"checks": 0, "leaves": 0, "rows_checked": 0, "leaf_rows": 0, "check_spans": [],
+           "leaf_sizes": []}
+
+    def check(lo, hi):
+        ok = not lo <= bad < hi
+        got["checks"] += 1
+        got["rows_checked"] += hi - lo
+        got["check_spans"].append((hi - lo, ok))
+        return ok
+
+    def go(lo, hi):
+        m = hi - lo
+        if m <= leaf or m < 2 * floor:
+            got["leaves"] += 1
+            got["leaf_rows"] += m
+            got["leaf_sizes"].append(m)
+            return
+        mid = lo + (1 << ((m - 1).bit_length() - 1))
+        if check(lo, mid):
+            go(mid, hi)
+            return
+        go(lo, mid)
+        if not (hi - mid >= floor and check(mid, hi)):
+            go(mid, hi)
+
+    go(0, n)
+    got["flushes"] = got["checks"] + got["leaves"]
+    return got
+
+
+def _recovery_events():
+    events = _trace.tracer.dump()
+    return [e for e in events if e["name"].startswith("recovery.")]
+
+
+@pytest.mark.parametrize("arm", ["device", "host"])
+@pytest.mark.parametrize("bad_row", [0, 37, 70, 99], ids=["head", "mid", "third-quarter", "tail"])
+def test_ladder_spans_and_record_follow_the_split_rule(bisect_env, monkeypatch, arm, bad_row):
+    """One bad row in 100, on each arm: the `recovery.ladder` span's counts
+    are the split rule's, with one `recovery.check` span a combined check
+    (its rows, `ok` false on the failing ones) and one `recovery.leaf` a
+    leaf; the flush record's new counts are the span's; the mask is the
+    serial loop's."""
+    monkeypatch.setattr(_trace.tracer, "enabled", True)
+    pks, msgs, sigs = _signed_rows(100)
+    sigs = list(sigs)
+    _flip(sigs, bad_row)
+    if arm == "device":
+        _FlushWitness(monkeypatch)
+        want = _split_rule_walk(100, bad_row, leaf=8, floor=8)
+        run = lambda: batch.verify_batch(pks, msgs, sigs, backend="jax")  # noqa: E731
+    else:
+        monkeypatch.setattr(batch, "_HOST_RLC_MIN", 8)
+        want = _split_rule_walk(100, bad_row, leaf=2, floor=8)
+        run = lambda: batch.verify_batch(pks, msgs, sigs, backend="cpu")  # noqa: E731
+    counters0 = _trace.verify_stats()["counters"]["recovery_flushes"]
+    _trace.tracer.clear()
+
+    mask = run()
+
+    assert mask.tobytes() == batch._verify_serial_host(pks, msgs, sigs).tobytes()
+    events = _recovery_events()
+    (ladder,) = [e for e in events if e["name"] == "recovery.ladder"]
+    checks = [e for e in events if e["name"] == "recovery.check"]
+    leaves = [e for e in events if e["name"] == "recovery.leaf"]
+    a = ladder["attrs"]
+    assert {k: a[k] for k in ("checks", "leaves", "flushes", "rows_checked", "leaf_rows")} == {
+        k: want[k] for k in ("checks", "leaves", "flushes", "rows_checked", "leaf_rows")}
+    assert (a["rows"], a["backend"]) == (100, "jax" if arm == "device" else "cpu")
+    assert [(e["attrs"]["rows"], e["attrs"]["ok"]) for e in checks] == want["check_spans"]
+    assert [e["attrs"]["rows"] for e in leaves] == want["leaf_sizes"]
+    assert all(e["root"] == ladder["root"] and e["parent"] is not None for e in checks + leaves)
+    last = _trace.verify_stats()["last_flush"]
+    assert (last["recovery_checks"], last["recovery_leaves"], last["recovery_rows_checked"],
+            last["recovery_flushes"]) == (a["checks"], a["leaves"], a["rows_checked"], a["flushes"])
+    assert _trace.verify_stats()["counters"]["recovery_flushes"] - counters0 == a["flushes"]
+
+
+def test_rows_checked_counter_grows_by_the_record(bisect_env, monkeypatch):
+    """`tendermint_batch_verify_recovery_rows_checked_total` grows by the
+    record's `recovery_rows_checked`; a clean flush adds nothing."""
+    from tendermint_tpu.libs import metrics as _metrics
+
+    _FlushWitness(monkeypatch)
+    counter = _metrics.batch_metrics().recovery_rows_checked
+    value = lambda: counter._values.get((), 0.0)  # noqa: E731
+    pks, msgs, sigs = _signed_rows(64)
+    before = value()
+    batch.verify_batch(pks, msgs, sigs, backend="jax")
+    assert value() == before
+    sigs = list(sigs)
+    _flip(sigs, 27)
+    batch.verify_batch(pks, msgs, sigs, backend="jax")
+    rows = _trace.verify_stats()["last_flush"]["recovery_rows_checked"]
+    assert rows == _split_rule_walk(64, 27, leaf=8, floor=8)["rows_checked"]
+    assert value() - before == rows
+
+
+@pytest.mark.parametrize("n, leaf, floor, sizes", [
+    (10_000, 256, 512, {512, 784}),
+    (4_096, 256, 512, {512}),
+    (100, 8, 8, {4, 8}),
+    (512, 256, 512, {512}),
+])
+def test_leaf_sizes_are_the_split_trees(n, leaf, floor, sizes):
+    """The leaves the split rule reaches from n rows, whichever row is bad:
+    the sizes `prewarm` loads the per-signature kernel at."""
+    assert batch._ladder_leaf_sizes(n, leaf, floor) == sizes
+    reached = set()
+    for bad in range(0, n, max(1, n // 97)):
+        reached.update(_split_rule_walk(n, bad, leaf, floor)["leaf_sizes"])
+    assert reached == sizes
 
 
 # ---------------------------------------------------------------------------

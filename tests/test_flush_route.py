@@ -4,7 +4,8 @@
       the `path` on the flush record `verify_batch` then writes;
   (b) the scheduler's inline-fallback question agrees with it;
   (c) `prewarm` writes no module global: a flush that arrives during it is
-      routed as at any other time;
+      routed as at any other time; on a device route it loads the recovery
+      ladder's per-signature leaves, and on the host nothing;
   (d) the `TMTPU_*` variables the package reads are a listed set, so the
       next switch is added on purpose.
 
@@ -167,6 +168,7 @@ def test_prewarm_writes_no_global_a_live_flush_keeps_its_route(small_table, monk
     monkeypatch.setattr(batch, "verify_batch_jax", warm)
     monkeypatch.setattr(batch, "_verify_batch_rlc", warm)
     monkeypatch.setattr(batch, "_prewarm_survivor_mesh", lambda *a: None)
+    monkeypatch.setattr(batch, "_persig_flush", lambda pk, *a: np.ones(len(pk), dtype=bool))
     batch.prewarm(4096)
     assert len(seen) == 3  # plain, cached-A, the chunk bucket
     for at_2048, at_10k, cfg in seen:
@@ -179,11 +181,38 @@ def test_prewarm_returns_where_the_route_is_the_host(small_table, monkeypatch):
     called = []
     monkeypatch.setattr(batch, "verify_batch_jax", lambda *a: called.append(a))
     monkeypatch.setattr(batch, "_verify_batch_rlc", lambda *a: called.append(a))
+    monkeypatch.setattr(batch, "_persig_flush", lambda *a: called.append(a))
     batch.prewarm(6)  # auto, under _JAX_MIN_BATCH
     batch.prewarm(24, backend="cpu")
     _breaker_open(monkeypatch)
     batch.prewarm(24)
     assert not called
+
+
+@pytest.mark.parametrize("n_vals, buckets", [(10_000, [512, 1024]), (4096, [512])])
+def test_prewarm_loads_the_ladders_leaves(small_table, monkeypatch, n_vals, buckets):
+    """On a device route `prewarm` asks the per-signature kernel once at
+    each bucket the recovery ladder's leaves reach for n_vals rows (at
+    10,000 rows leaves of 512 and 784 rows), with no mesh runner."""
+    monkeypatch.setattr(batch, "_JAX_MIN_BATCH", 256)
+    monkeypatch.setattr(batch, "RLC_MIN", 512)
+    monkeypatch.setattr(batch, "_BISECT_LEAF", 256)
+    batch._PREP_CFG["stream_floor"] = 2048
+    batch.configure_planner(max_flush_lanes=24576)
+    ones = lambda pk, *a, **kw: np.ones(len(pk), dtype=bool)  # noqa: E731
+    monkeypatch.setattr(batch, "verify_batch_jax", ones)
+    monkeypatch.setattr(batch, "_verify_batch_rlc", ones)
+    monkeypatch.setattr(batch, "_prewarm_survivor_mesh", lambda *a: None)
+    leaves = []
+
+    def persig(pk, msgs, sigs, sharded):
+        leaves.append((len(pk), sharded))
+        return np.ones(len(pk), dtype=bool)
+
+    monkeypatch.setattr(batch, "_persig_flush", persig)
+    assert batch._flush_route(n_vals).path == "rlc-pipelined"
+    batch.prewarm(n_vals)
+    assert leaves == [(b, None) for b in buckets]
 
 
 # Every TMTPU_* variable the package reads. A new one is a new switch: add it
