@@ -833,35 +833,61 @@ def _ladder_leaf_sizes(n: int, leaf: int, floor: int) -> set:
     return _ladder_leaf_sizes(half, leaf, floor) | _ladder_leaf_sizes(n - half, leaf, floor)
 
 
-def _ladder_walk(n, combined, leaf_fn, leaf: int, floor: int, backend: str):
+def _ladder_rows(cols, ranges):
+    """The rows of (lo, hi) `ranges`, in order, of each column in `cols`: a
+    slice for one range, a list for a union."""
+    if len(ranges) == 1:
+        ((lo, hi),) = ranges
+        return tuple(col[lo:hi] for col in cols)
+    return tuple([x for lo, hi in ranges for x in col[lo:hi]] for col in cols)
+
+
+def _ladder_walk(n, combined, leaf_fn, leaf: int, floor: int, backend: str, fits=None):
     """The recovery ladder over n rows that hold at least one bad row, one
     walk for the device's arm (_bisect_recover) and the host's
     (_bisect_recover_host), which give it their combined check, leaf and
-    thresholds. A range splits by `_ladder_half`; each half gets one
-    combined check (`combined(lo, hi)` -> (the exact mask or None, flushes
-    it took)); a passing half is done, a failing half descends. When the
-    first half passes, the second is KNOWN bad (the parent failed) and
-    descends unchecked. A leaf, and every range after _BISECT_MAX_BAD
-    poisoned leaves (a dense flood: splitting costs more than it saves), is
-    verified row by row (`leaf_fn(lo, hi)` -> mask).
+    thresholds. A range splits by `_ladder_half`; a combined check
+    (`combined(ranges)` over the rows of a list of (lo, hi) ranges, in
+    order -> (their exact mask or None, flushes it took)) takes its first
+    half; a passing half is done, a failing half descends. When the first
+    half passes, the second is KNOWN bad (the parent failed) and descends
+    unchecked. When it fails, a second half of `floor` rows or more is put
+    off; once the descent is done, the halves put off are checked as ONE
+    union, where `fits(rows)` allows a check of that many rows (None:
+    always), so a pass clears them all in one flush. A failed union, a
+    union `fits` refuses, and a single half put off check each half alone,
+    and a failing one descends. A ladder makes one union at most; after it
+    each second half is checked at once. A leaf, and every range after
+    _BISECT_MAX_BAD poisoned leaves (a dense flood: splitting costs more
+    than it saves), is verified row by row (`leaf_fn(lo, hi)` -> mask).
 
     Spans: `recovery.ladder` (rows, checks, leaves, flushes, rows_checked,
-    leaf_rows, backend), `recovery.check` a combined check (rows, ok),
-    `recovery.leaf` a leaf (rows). The counts are returned with the mask,
-    and added to the flush's detail as `recovery_<count>` (summed over every
-    ladder of one flush: a streamed flush recovers chunk by chunk)."""
+    leaf_rows, unions, unions_ok, backend), `recovery.check` a combined
+    check (rows, ranges, ok), `recovery.leaf` a leaf (rows). The counts are
+    returned with the mask, and added to the flush's detail as
+    `recovery_<count>` (summed over every ladder of one flush: a streamed
+    flush recovers chunk by chunk)."""
     out = np.zeros(n, dtype=bool)
     max_bad = _BISECT_MAX_BAD
-    tally = dict.fromkeys(("checks", "leaves", "flushes", "rows_checked", "leaf_rows"), 0)
+    tally = dict.fromkeys(("checks", "leaves", "flushes", "rows_checked", "leaf_rows",
+                           "unions", "unions_ok"), 0)
+    put_off = []  # second halves whose first half failed, in row order
 
-    def check(lo, hi):
-        with _trace.span("recovery.check", rows=hi - lo) as sp:
-            mask, flushes = combined(lo, hi)
+    def check(ranges):
+        rows = sum(hi - lo for lo, hi in ranges)
+        with _trace.span("recovery.check", rows=rows, ranges=len(ranges)) as sp:
+            mask, flushes = combined(ranges)
             sp.set(ok=mask is not None)
         tally["checks"] += 1
         tally["flushes"] += flushes
-        tally["rows_checked"] += hi - lo
-        return mask
+        tally["rows_checked"] += rows
+        if mask is None:
+            return False
+        at = 0
+        for lo, hi in ranges:
+            out[lo:hi] = mask[at:at + hi - lo]
+            at += hi - lo
+        return True
 
     def go(lo, hi):
         # invariant: [lo, hi) is known to contain at least one bad row
@@ -874,23 +900,35 @@ def _ladder_walk(n, combined, leaf_fn, leaf: int, floor: int, backend: str):
                 out[lo:hi] = leaf_fn(lo, hi)
             return
         mid = lo + half
-        first = check(lo, mid)
-        if first is not None:
-            out[lo:mid] = first
+        if check([(lo, mid)]):
             go(mid, hi)  # parent failed, first half clean: second is bad
             return
         go(lo, mid)
         if hi - mid >= floor and tally["leaves"] < max_bad:
-            second = check(mid, hi)
-            if second is not None:
-                out[mid:hi] = second
+            if not tally["unions"]:
+                put_off.append((mid, hi))
+                return
+            if check([(mid, hi)]):
                 return
         go(mid, hi)
 
     with _trace.span("recovery.ladder", rows=n, backend=backend) as sp:
         go(0, n)
+        while put_off:
+            ranges = put_off[:]
+            put_off.clear()
+            if len(ranges) > 1 and not tally["unions"] and (
+                fits is None or fits(sum(hi - lo for lo, hi in ranges))
+            ):
+                tally["unions"] += 1
+                if check(ranges):
+                    tally["unions_ok"] += 1
+                    continue
+            for lo, hi in ranges:
+                if tally["leaves"] >= max_bad or not check([(lo, hi)]):
+                    go(lo, hi)
         sp.set(**tally)
-    for k in ("flushes", "checks", "leaves", "rows_checked"):
+    for k in ("flushes", "checks", "leaves", "rows_checked", "unions"):
         LAST_FLUSH_DETAIL["recovery_" + k] = LAST_FLUSH_DETAIL.get("recovery_" + k, 0) + tally[k]
     return out, tally
 
@@ -901,11 +939,11 @@ def _bisect_recover_host(pubkeys, msgs, sigs) -> np.ndarray:
     sub-checks, a quarter of the device's leaf and the serial loop at the
     leaves — the CPU fallback under a poisoning flood keeps the same
     log-cost shape as the device path (docs/ROBUSTNESS.md adversarial flush
-    defense)."""
+    defense). The host compiles nothing, so every union fits."""
 
-    def combined(lo, hi):
+    def combined(ranges):
         try:
-            return _verify_batch_cpu_rlc(pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi]), 1
+            return _verify_batch_cpu_rlc(*_ladder_rows((pubkeys, msgs, sigs), ranges)), 1
         except Exception:
             return None, 1  # broken host RLC degrades to serial leaves
 
@@ -2564,18 +2602,30 @@ def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarr
     2 x RLC_MIN, recover per-signature, the byte-identical code path the
     straight fallback has always used.
 
-    Cost for one bad row over C = ceil(n/leaf) chunks: at most
-    2·ceil(log2 C)+1 device flushes (<= 2 combined checks per level, one
-    per-sig leaf), vs 1 monolithic per-sig flush of n rows — the win is
-    that n-leaf rows short-circuit through combined checks and the leaf
-    flush is tiny, so a poisoned flood degrades the vote path by a log
-    factor, not a linear one."""
+    The second halves the descent put off are checked as one union
+    (_ladder_walk) only on a shape this walk has already run: on the chunk
+    bucket always (its one shape is the failed flush's, and a union of at
+    most n rows fits it); on the per-size and the mesh's programs, whose
+    shape follows the lane bucket of the rows, where that bucket is the
+    failed flush's or an earlier check's. Else each half is checked alone.
 
-    def combined(lo, hi):
+    Cost for one bad row over C = ceil(n/leaf) chunks: at most
+    ceil(log2 C)+2 device flushes (one combined check a level, one union
+    of the halves put off, one per-sig leaf), vs 1 monolithic per-sig
+    flush of n rows — the win is that n-leaf rows short-circuit through
+    combined checks and the leaf flush is tiny, so a poisoned flood
+    degrades the vote path by a log factor, not a linear one. Whatever the
+    bad rows, the one union a ladder makes costs at most one flush more
+    than checking each half alone (a failed union, then each half)."""
+
+    ran = {_lane_bucket(len(pubkeys) + 1)}  # the failed flush's bucket, then each check's
+
+    def combined(ranges):
         # Mirrors the fast-path rung choice: sharded combined while a mesh
         # stands; if the mesh fell MID-CHECK, retry single-chip rather than
         # mislabel a device loss as a poisoned range.
-        pk, ms, sg = pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi]
+        pk, ms, sg = _ladder_rows((pubkeys, msgs, sigs), ranges)
+        ran.add(_lane_bucket(len(pk) + 1))
         flushes = 1
         if _sharded_runner() is not None:
             mask = _verify_batch_rlc_sharded(pk, ms, sg)
@@ -2586,10 +2636,13 @@ def _bisect_recover(pubkeys, msgs, sigs, chunk_bucket: bool = False) -> np.ndarr
             return _verify_batch_pipelined(pk, ms, sg), flushes
         return _verify_batch_rlc(pk, ms, sg), flushes
 
+    def fits(rows):
+        return (chunk_bucket and _sharded_runner() is None) or _lane_bucket(rows + 1) in ran
+
     def leaf(lo, hi):
         return _persig_flush(pubkeys[lo:hi], msgs[lo:hi], sigs[lo:hi], _sharded_runner())
 
-    out, tally = _ladder_walk(len(pubkeys), combined, leaf, _BISECT_LEAF, RLC_MIN, "jax")
+    out, tally = _ladder_walk(len(pubkeys), combined, leaf, _BISECT_LEAF, RLC_MIN, "jax", fits)
     if tally["leaves"] > 1 or tally["flushes"] > 1:
         LAST_JAX_PATH[0] = "rlc-bisect"
     return out
@@ -3304,6 +3357,7 @@ def verify_batch(
                 recovery_checks=detail.get("recovery_checks"),
                 recovery_leaves=detail.get("recovery_leaves"),
                 recovery_rows_checked=detail.get("recovery_rows_checked"),
+                recovery_unions=detail.get("recovery_unions"),
                 quarantined=quarantined,
                 tracer_=_trace.tracer if vb.recording else None,
                 **memo,

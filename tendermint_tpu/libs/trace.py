@@ -567,6 +567,7 @@ def record_flush(
     recovery_checks: Optional[int] = None,
     recovery_leaves: Optional[int] = None,
     recovery_rows_checked: Optional[int] = None,
+    recovery_unions: Optional[int] = None,
     quarantined: Optional[int] = None,
     tracer_: Optional[Tracer] = None,
 ) -> None:
@@ -674,9 +675,11 @@ def record_flush(
     if recovery_flushes is not None:
         last["recovery_flushes"] = recovery_flushes
     # the ladder's walk (crypto/batch.py _ladder_walk): its combined checks,
-    # its per-signature leaves and the rows the checks took
+    # of them the unions of the second halves it put off, its per-signature
+    # leaves and the rows the checks took
     if recovery_checks is not None:
         last["recovery_checks"] = recovery_checks
+        last["recovery_unions"] = recovery_unions or 0
         last["recovery_leaves"] = recovery_leaves or 0
         last["recovery_rows_checked"] = recovery_rows_checked or 0
     if quarantined is not None:
